@@ -214,6 +214,20 @@ def test_sweep_measure_host_sync_fixture():
     assert not any("clean_space" in f.message for f in found)
 
 
+def test_canonicalize_is_stable_across_processes():
+    """The golden hash must not depend on the process: addresses and the
+    hash-order of printed frozensets (PYTHONHASHSEED) are normalized."""
+    from tpu_resnet.analysis.configmatrix import canonicalize
+
+    a = ("shard_map[manual_axes=frozenset({'model', 'data'}) "
+         "fn=<function f at 0x7f01aa>]")
+    b = ("shard_map[manual_axes=frozenset({'data', 'model'}) "
+         "fn=<function f at 0x55e9bc>]")
+    assert canonicalize(a) == canonicalize(b)
+    assert "frozenset({'data', 'model'})" in canonicalize(a)
+    assert canonicalize("frozenset({'x'})") == "frozenset({'x'})"
+
+
 def test_mfu_cost_analysis_in_jit_scope_fixture():
     """obs/mfu.py's compile introspection (.cost_analysis()) is a
     one-time host-side startup cost: the rule flags it inside jit-scope

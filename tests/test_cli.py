@@ -43,6 +43,16 @@ def test_eval_once_cli(run_dir, capsys):
                                        "best_precision.json"))
 
 
+def test_eval_once_on_an_empty_dir_exits_nonzero(tmp_path):
+    """`eval --once` that evaluated nothing (no checkpoint to restore) is
+    a failure the caller can see, not a quiet exit 0."""
+    rc = main(["eval", "--once", "--preset", "smoke",
+               f"train.train_dir={tmp_path}"])
+    assert rc != 0
+    assert not os.path.exists(os.path.join(str(tmp_path), "eval",
+                                           "best_precision.json"))
+
+
 def test_info_cli(capsys):
     assert main(["info", "--preset", "smoke"]) == 0
     out = capsys.readouterr().out

@@ -146,6 +146,51 @@ def test_extract_literal_reduce_scatter_payload_from_operand():
     assert c.payload_bytes == 256 and c.wire_bytes == 224.0
 
 
+def test_tpu_tiled_layouts_do_not_hide_tuple_collectives():
+    """TPU HLO prints tiling inside layout braces (``T(8,128)``): a tuple
+    type then holds parentheses, and a parser that cut it at the first
+    ")" saw no collective at all in a four-chip program (comms.json read
+    collective_count 0 on the chip)."""
+    hlo = ("ENTRY %main (p0: f32[512,128]) -> f32[512,128] {\n"
+           "  %p0 = f32[512,128]{1,0:T(8,128)} parameter(0)\n"
+           "  %p1 = bf16[128]{0:T(256)(128)(2,1)} parameter(1)\n"
+           "  %ar.9 = (f32[512,128]{1,0:T(8,128)}, "
+           "bf16[128]{0:T(256)(128)(2,1)S(1)}) all-reduce(%p0, %p1), "
+           "channel_id=3, replica_groups=[1,4]<=[4], "
+           "use_global_device_ids=true, to_apply=%sum\n"
+           "  ROOT %g = f32[512,128]{1,0:T(8,128)} "
+           "get-tuple-element(%ar.9), index=0\n}\n")
+    (c,) = comms.extract_collectives(hlo, 4, 1)
+    assert c.op == "all-reduce" and c.group_size == 4
+    assert c.payload_bytes == 512 * 128 * 4 + 128 * 2
+    assert c.bucket == "data"
+
+
+def test_combined_all_reduce_splits_into_scattered_and_plain_parts():
+    """XLA's all-reduce combiner merges many gradient reductions into ONE
+    tuple op; under ZeRO-1 on a CPU compile the elements whose consumers
+    keep only a shard are the decomposed reduce-scatter, the others (BN
+    moments) stay plain — judged per element, through its
+    get-tuple-element."""
+    hlo = ("ENTRY %main (p0: f32[64], p1: f32[16]) -> f32[8] {\n"
+           "  %p0 = f32[64]{0} parameter(0)\n"
+           "  %p1 = f32[16]{0} parameter(1)\n"
+           "  %ar.7 = (f32[64]{0}, f32[16]{0}) all-reduce(%p0, %p1), "
+           "replica_groups=[1,8]<=[8], to_apply=%sum\n"
+           "  %gte.0 = f32[64]{0} get-tuple-element(%ar.7), index=0\n"
+           "  %gte.1 = f32[16]{0} get-tuple-element(%ar.7), index=1\n"
+           "  %ds.0 = f32[8]{0} dynamic-slice(%gte.0, %c0), "
+           "dynamic_slice_sizes={8}\n"
+           "  %full = f32[16]{0} multiply(%gte.1, %gte.1)\n"
+           "  ROOT %out = f32[8]{0} add(%ds.0, %ds.0)\n}\n")
+    got = sorted((c.op, c.payload_bytes)
+                 for c in comms.extract_collectives(hlo, 8, 1))
+    assert got == [("all-reduce", 64), ("reduce-scatter", 256)]
+    s = comms.summarize_collectives(hlo, 8, 1)
+    assert s["reduce_scatter_bytes"] == 256
+    assert s["plain_all_reduce_bytes"] == 64
+
+
 def test_summarize_collectives_budget():
     s = comms.summarize_collectives(HLO, 8, 1)
     assert s["mesh"] == "8x1" and s["collective_count"] == 4

@@ -378,15 +378,18 @@ def test_preempt_burst_fires_k_across_restarts(tmp_path, monkeypatch):
 
 # --------------------------------------------------- colocation admission
 def test_colocation_admission_verdicts(monkeypatch):
+    from tpu_resnet.obs import memory as memory_obs
+
     fake_dev = [types.SimpleNamespace(device_kind="faketpu")]
-    monkeypatch.setenv("TPU_RESNET_HBM_BYTES", str(1_000_000))
+    monkeypatch.setattr(memory_obs, "HBM_BYTES_BY_KIND",
+                        (("faketpu", 1_000_000),))
     ok = elastic.colocation_admission(500_000, devices=fake_dev)
     assert ok["admit"] and ok["limit_bytes"] == 1_000_000
     assert ok["headroom_bytes"] == 950_000  # 5% reserve held back
     deny = elastic.colocation_admission(960_000, devices=fake_dev)
     assert not deny["admit"] and "denied" in deny["reason"]
     # No limit from anywhere: admit, but say it was not arbitrated.
-    monkeypatch.delenv("TPU_RESNET_HBM_BYTES")
+    monkeypatch.setattr(memory_obs, "HBM_BYTES_BY_KIND", ())
     open_v = elastic.colocation_admission(10, devices=fake_dev)
     assert open_v["admit"] and "not arbitrated" in open_v["reason"]
 
@@ -546,9 +549,14 @@ def test_colocation_drill_trainer_and_serve_share_fakepod(tmp_path):
                       "data.device_resident=off", "data.transfer_stage=1",
                       "train.global_batch_size=16"]
     env = scrubbed_cpu_env(8)
-    # Arbitration needs a limit the CPU backend cannot report: the
-    # capacity-table override. (Set AFTER the scrub — it strips TPU_*.)
-    env["TPU_RESNET_HBM_BYTES"] = str(1 << 30)
+    # Arbitration needs a limit the CPU backend cannot report, and the
+    # product has no knob that assumes one: the serve children run the
+    # CLI with a 1 GiB "cpu" row patched into the capacity table.
+    serve_cli = [sys.executable, "-c",
+                 "import sys; from tpu_resnet.obs import memory; "
+                 "memory.HBM_BYTES_BY_KIND = (('cpu', 1 << 30),); "
+                 "from tpu_resnet.main import main; "
+                 "sys.exit(main(sys.argv[1:]))"]
 
     # Child output goes to FILES, not pipes: the long-running trainer
     # would fill a 64K pipe and deadlock (the doctor probes' rule).
@@ -581,8 +589,7 @@ def test_colocation_drill_trainer_and_serve_share_fakepod(tmp_path):
         else:
             pytest.fail("trainer wrote no checkpoint within 120s")
 
-        serve_cmd = [sys.executable, "-m", "tpu_resnet", "serve"] \
-            + base_overrides + ["serve.port=0", "serve.max_batch=4",
+        serve_cmd = serve_cli + ["serve"] + base_overrides + ["serve.port=0", "serve.max_batch=4",
                                 "serve.reload_interval_secs=0"]
         # Denied: asks for more than the arbitrated headroom → exit 3.
         denied = subprocess.run(

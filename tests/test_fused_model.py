@@ -202,24 +202,29 @@ def test_imagenet_basic_nets_accept_fused_blocks():
 
 
 def test_auto_batch_tile_plans():
-    """The VMEM tile-plan arithmetic behind the dispatch: CIFAR shapes
-    keep the measured bt=16; ImageNet basic shapes get plans that fit;
-    the 7²x512 stage (weights ~18.9 MB alone) raises so BlockLayer keeps
-    it on XLA."""
+    """The VMEM tile-plan arithmetic behind the dispatch, in Mosaic's
+    (8, 128)-tiled layout: every stage shape gets a plan that divides the
+    batch and fits the budget as laid out on the chip — the 16-channel
+    CIFAR stage occupies 8x its logical bytes, so its tile drops below
+    the 16 cap (bt=16 overflowed VMEM on the chip) — and the 7²x512
+    stage (weights ~18 MB alone) raises so BlockLayer keeps it on XLA."""
+    from tpu_resnet.ops.epilogue import vmem_row_bytes
     from tpu_resnet.ops.fused_block import auto_batch_tile
 
-    # CIFAR stage shapes at b128: unchanged measured default.
-    assert auto_batch_tile((128, 32, 32, 16)) == 16
+    assert vmem_row_bytes(32, 32, 16) == 8 * 32 * 32 * 16 * 4
+    assert vmem_row_bytes(14, 14, 256) == 14 * 16 * 256 * 4
+    assert auto_batch_tile((128, 32, 32, 16)) == 4
     assert auto_batch_tile((128, 16, 16, 32)) == 16
     assert auto_batch_tile((128, 8, 8, 64)) == 16
-    # ImageNet rn18/34 basic stage shapes at b128: a plan exists, divides
-    # the batch, and its forward live set fits the 10 MB budget.
-    for shape in ((128, 56, 56, 64), (128, 28, 28, 128),
+    # CIFAR stages and ImageNet rn18/34 basic stages at b128.
+    for shape in ((128, 32, 32, 16), (128, 16, 16, 32), (128, 8, 8, 64),
+                  (128, 56, 56, 64), (128, 28, 28, 128),
                   (128, 14, 14, 256)):
         bt = auto_batch_tile(shape)
         assert bt >= 1 and 128 % bt == 0
         b, h, w, c = shape
-        live = bt * h * w * c * 4 * 4 + 2 * 9 * c * c * 4
+        live = (bt * 4 * vmem_row_bytes(h, w, c)
+                + 2 * 9 * vmem_row_bytes(1, c, c))
         assert live <= 10 * 2 ** 20, (shape, bt, live)
     with pytest.raises(ValueError, match="XLA"):
         auto_batch_tile((128, 7, 7, 512))
@@ -389,6 +394,28 @@ def test_fused_blocks_rejected_for_wide_resnet():
     cfg.model.fused_blocks = True
     with pytest.raises(ValueError, match="width_multiplier"):
         build_model(cfg)
+
+
+def test_fused_bottleneck_switch_raises_on_a_tpu_backend(monkeypatch):
+    """The bottleneck family does not build on the chip yet (backward
+    kernels refused by Mosaic — ROADMAP C4): on a TPU backend its switch
+    raises with the compiler's message. It never trains on an interpreter
+    or a silent XLA substitute; elsewhere (CPU, interpret mode) it builds,
+    and the basic-block nets are not affected."""
+    from tpu_resnet import ops
+    from tpu_resnet.config import load_config
+    from tpu_resnet.models import build_model
+
+    cfg = load_config("imagenet")
+    cfg.model.fused_blocks = True
+    assert build_model(cfg).fused_blocks        # CPU: interpret-mode path
+    monkeypatch.setattr(ops, "is_tpu_backend", lambda: True)
+    with pytest.raises(NotImplementedError, match="RESOURCE_EXHAUSTED"):
+        build_model(cfg)
+    cfg.model.resnet_size = 18                  # basic blocks: untouched
+    assert build_model(cfg).fused_blocks
+    cfg.model.resnet_size, cfg.model.fused_blocks = 50, False
+    assert not build_model(cfg).fused_blocks
 
 
 def test_direct_constructors_carry_the_same_fused_guards():

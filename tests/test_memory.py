@@ -99,10 +99,9 @@ def test_hbm_bytes_per_chip_table_and_override(monkeypatch):
     assert memory.hbm_bytes_per_chip("TPU v4") == 32 * gib
     assert memory.hbm_bytes_per_chip("cpu") is None
     assert memory.hbm_bytes_per_chip("") is None
+    # no environment variable may assume a capacity for an unknown chip
     monkeypatch.setenv("TPU_RESNET_HBM_BYTES", "1e9")
-    assert memory.hbm_bytes_per_chip("cpu") == int(1e9)
-    monkeypatch.setenv("TPU_RESNET_HBM_BYTES", "bogus")
-    assert memory.hbm_bytes_per_chip("TPU v4") == 32 * gib  # ignored
+    assert memory.hbm_bytes_per_chip("cpu") is None
 
 
 # ----------------------------------------------------------- live gauges
@@ -698,6 +697,30 @@ def test_newest_capture_wins(tmp_path):
     _synthetic_capture(d, name="2026_01_01_00_00_00")
     newer = _synthetic_capture(d, name="2026_01_02_00_00_00")
     assert find_device_trace_files(d) == [newer]
+
+
+def test_ledgers_survive_the_default_streaming_shape(tmp_path):
+    """The streaming defaults are transfer_stage=8 < steps_per_call=10:
+    the loop then dispatches 8-step chunks, and the memory/comms ledgers
+    must account THAT program. (They asked for a 10-step chunk of an
+    8-row superbatch, raised, and were swallowed to a warning — first
+    seen on the chip, where chip_smoke.py requires the ledgers.)"""
+    from tpu_resnet.train import train
+
+    cfg = load_config("smoke")
+    cfg.model.name = "mlp"
+    cfg.train.train_dir = str(tmp_path / "run")
+    cfg.train.train_steps = 8
+    cfg.train.global_batch_size = 16
+    cfg.train.log_every = 8
+    cfg.train.checkpoint_every = 8
+    cfg.data.device_resident = "off"
+    assert cfg.data.transfer_stage == 8 and cfg.train.steps_per_call == 10
+    train(cfg)
+    for name in ("memory.json", "comms.json"):
+        with open(os.path.join(cfg.train.train_dir, name)) as f:
+            (entry,) = json.load(f)["entries"].values()
+        assert entry["program"] == "staged-chunk(steps=8,stage=8)"
 
 
 # ------------------------------------------------------------- bench hook

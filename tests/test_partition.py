@@ -167,6 +167,32 @@ def test_zero1_replicated_step_parity_on_fakepod():
         np.testing.assert_allclose(a, b, rtol=1e-6, atol=1e-6)
 
 
+def test_step_returns_the_state_in_the_layout_it_was_given():
+    """The compiled step's out_shardings pin every state leaf to its
+    input layout, so the next dispatch accepts it by construction. Left
+    to the SPMD partitioner this held on the CPU but not on a four-chip
+    TPU host: zero1 came back with a 16-element replicated leaf sharded
+    over 'data' and the second dispatch raised."""
+    for partition in ("replicated", "zero1"):
+        _, mesh, part, state, fn = _build(partition)
+        bs = parallel.batch_sharding(mesh)
+        gi, gl = pipeline.to_global_arrays(
+            (np.zeros((16, 32, 32, 3), np.uint8),
+             np.zeros((16,), np.int32)), bs)
+        avals = jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                           sharding=x.sharding),
+            (state, gi, gl))
+        out_state_sh, out_metrics_sh = fn.lower(*avals).compile() \
+            .output_shardings
+        for got, leaf in zip(jax.tree_util.tree_leaves(out_state_sh),
+                             jax.tree_util.tree_leaves(state)):
+            assert got.is_equivalent_to(leaf.sharding, leaf.ndim), \
+                (leaf.shape, got, leaf.sharding)
+        assert all(sh.spec == P() for sh in
+                   jax.tree_util.tree_leaves(out_metrics_sh))
+
+
 def test_state_argument_bytes_breakdown():
     """The analytic per-component breakdown the ledger/goldens record:
     zero1 cuts ONLY the optimizer slots; params and BN stats stay

@@ -1,6 +1,5 @@
 """Perf-regression tracker (tools/perfwatch.py): trajectory parsing
-(driver rounds, archived chip artifacts, truncated tails), backend
-cohorting, and noise-band verdicts on seeded regressing/flat/improving
+(driver rounds, archived chip artifacts), backend cohorting, and noise-band verdicts on seeded regressing/flat/improving
 trajectories."""
 
 import importlib.util
@@ -78,11 +77,10 @@ def test_insufficient_data(tmp_path):
     assert perfwatch.main(["--root", root]) == 0
 
 
-# --------------------------------------------------- cohorts + salvage
+# --------------------------------------------------------------- cohorts
 
 def test_cpu_fallback_round_never_judged_against_chip_numbers(tmp_path):
-    """The BENCH_r02/r03 shape: chip rounds then a CPU-fallback round.
-    The latest (cpu) sample has no cpu predecessors — the verdict must
+    """Chip rounds, then a round from a CPU. The latest (cpu) sample has no cpu predecessors — the verdict must
     be insufficient_data, NOT a 99.99% regression vs the TPU median."""
     root = _seed_root(tmp_path, [200.0, 205.0, 210.0])
     with open(os.path.join(root, "BENCH_r04.json"), "w") as f:
@@ -92,30 +90,6 @@ def test_cpu_fallback_round_never_judged_against_chip_numbers(tmp_path):
     m = verdict["metrics"]["cifar_steps_per_sec"]
     assert m["backend"] == "cpu"
     assert m["verdict"] == "insufficient_data"
-
-
-def test_salvage_from_tail_and_truncated_line(tmp_path):
-    """parsed=null rounds recover their record from the stdout tail (the
-    BENCH_r04 failure mode); a tail holding only a truncated JSON line
-    yields no sample but is reported as unparseable."""
-    root = str(tmp_path)
-    good = json.dumps(_bench_record(150.0))
-    with open(os.path.join(root, "BENCH_r01.json"), "w") as f:
-        json.dump({"n": 1, "rc": 124, "parsed": None,
-                   "tail": f"noise\nRESULT_JSON: {good}\nmore noise"}, f)
-    with open(os.path.join(root, "BENCH_r02.json"), "w") as f:
-        json.dump({"n": 2, "rc": 124, "parsed": None,
-                   "tail": good + "\n" + good[:40]}, f)  # torn last line
-    with open(os.path.join(root, "BENCH_r03.json"), "w") as f:
-        json.dump({"n": 3, "rc": 124, "parsed": None,
-                   "tail": "rom an earlier live tunnel window truncated"},
-                  f)
-    samples = perfwatch.load_samples(root)
-    values = [s["value"] for s in samples if s.get("metric") ==
-              "cifar_steps_per_sec"]
-    assert values == [150.0, 150.0]  # r01 prefixed + r02 bare emit line
-    assert any("BENCH_r03" in s.get("source", "") for s in samples
-               if "error" in s)
 
 
 def test_archived_chip_artifact_and_extra_file_ordering(tmp_path):

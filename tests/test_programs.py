@@ -184,6 +184,35 @@ def test_cache_round_trip_and_fast_path(tmp_path):
         out_cold, np.asarray(program2(np.ones((4,), np.float32))))
 
 
+def test_cache_loads_onto_the_devices_it_was_compiled_for(tmp_path):
+    """A program compiled for some of the host's devices (here 2 of the
+    8; a one-chip replica on a four-chip host is the same case) records
+    them, and a load hands exactly those back — jax would otherwise
+    default to every device of the backend and the loaded executable
+    would reject its arguments ("expected 8 shards")."""
+    import jax
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    devs = jax.devices()[2:4]
+    sharding = NamedSharding(Mesh(np.array(devs), ("data",)), P("data"))
+    avals = (jax.ShapeDtypeStruct((4,), "float32", sharding=sharding),)
+    key = "train|toy|mesh2x1|b4"
+    cfg = _cache_cfg(tmp_path)
+    reg = ProgramRegistry(cfg)
+    reg.wrap(key, _toy_program(), avals)
+    (entry,) = os.listdir(cfg.programs.cache_dir)
+    header = reg.cache.read_header(
+        os.path.join(cfg.programs.cache_dir, entry))
+    assert header["device_ids"] == [d.id for d in devs]
+
+    _fresh_process()
+    program, hit = ProgramRegistry(cfg).wrap(key, _toy_program(), avals)
+    assert hit
+    out = program(jax.device_put(np.ones((4,), np.float32), sharding))
+    assert {d.id for d in out.sharding.device_set} == {d.id for d in devs}
+    np.testing.assert_array_equal(np.asarray(out), np.full((4,), 2.0))
+
+
 def test_cache_fingerprint_rejects_drifted_program(tmp_path):
     """Same key, different math: the entry must be evicted and
     recompiled, never served (the PR 1 silently-wrong-executable

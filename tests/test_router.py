@@ -5,8 +5,8 @@ fleet mode, perfwatch ingestion).
 Three layers, mirroring the subsystem's own:
 
 - pure units: circuit-breaker state machine (injectable clock),
-  discovery parsing, scenario qps schedules, loadgen failure-class
-  taxonomy, supervise fleet/stop-code policies — no sockets;
+  discovery parsing, scenario qps schedules, loadgen failure
+  classes, supervise fleet/stop-code policies — no sockets;
 - in-process fleet: real Router + two PredictServers over FakeBackends
   (millisecond startup): spread, passive-failure failover with zero
   client errors, probe-driven exclusion/readmission, deadline budget,

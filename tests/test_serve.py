@@ -517,9 +517,9 @@ def test_loadgen_drives_the_server(fake_server, capsys, tmp_path):
                        "--clients", "4", "--duration", "1.5",
                        "--out", str(out_file)])
     assert rc == 0
-    # the emit must round-trip through bench.py's salvage parser (shared
-    # hardened single-write path — truncated lines are skipped there)
-    from bench import _parse_result
+    # the emit must round-trip through the sweep harness's parser (the
+    # one RESULT_JSON reader — truncated lines are skipped there)
+    from tpu_resnet.tools.sweep import _parse_result
 
     result = _parse_result(capsys.readouterr().out)
     assert result == json.loads(out_file.read_text())

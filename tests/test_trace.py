@@ -171,10 +171,10 @@ def test_trace_export_on_real_train_run(tmp_path, monkeypatch):
     from tpu_resnet.config import load_config
     from tpu_resnet.train import train
 
-    # CPU has no entry in the peak-FLOPs table; the documented override
-    # makes the mfu gauge genuinely nonzero (same trick doctor
-    # --trace-probe uses).
-    monkeypatch.setenv("BENCH_PEAK_FLOPS", "1e12")
+    # CPU has no entry in the peak-FLOPs table (an unknown chip reports
+    # no mfu); a patched table makes the gauge genuinely nonzero here.
+    from tpu_resnet.obs import mfu as mfu_mod
+    monkeypatch.setattr(mfu_mod, "PEAK_FLOPS_BY_KIND", (("cpu", 1e12),))
     cfg = load_config("smoke")
     cfg.model.name = "mlp"
     cfg.data.device_resident = "off"
@@ -243,14 +243,14 @@ def test_trace_export_on_real_train_run(tmp_path, monkeypatch):
 @pytest.mark.slow  # live train subprocess + mid-run scrape (~40s); the
 # exporter/schema/run_id plumbing is covered in the default tier above
 def test_doctor_trace_probe_contract():
-    """doctor --trace-probe: the live mfu gauge and train_step_ms
-    histogram go live mid-run, the SIGTERM preemption contract holds,
+    """doctor --trace-probe: the live model_flops_per_sec gauge and
+    train_step_ms histogram go live mid-run, the SIGTERM preemption contract holds,
     and the exported trace schema-checks with the manifest's run_id."""
     from tpu_resnet.tools.doctor import _check_trace_probe
 
     out = _check_trace_probe()
     assert out["ok"], out
-    assert out["mfu"] > 0
+    assert out["model_flops_per_sec"] > 0
     assert out["step_ms_observations"] > 0
     assert out["trace_events"] > 0
     assert out["run_id"]

@@ -94,7 +94,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import numpy as np  # noqa: E402
 
-from bench import _print_line  # noqa: E402  (hardened single-write emit)
+from tpu_resnet.tools.sweep import _print_line  # noqa: E402  (hardened single-write emit)
 from tpu_resnet.obs.server import parse_prometheus  # noqa: E402
 from tpu_resnet.serve.batcher import percentile  # noqa: E402
 

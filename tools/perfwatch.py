@@ -2,21 +2,18 @@
 """Perf-regression tracker — verdicts over the bench RESULT_JSON
 trajectory.
 
-The repo accumulates one bench artifact per round (``BENCH_r0N.json``
-at the root, written by the driver; ``docs/runs/bench_r*_tpu_v5e.json``
-archived by the battery after validating a live-TPU run). Whether a
-round's number is a win, noise, or a regression was judged by eyeball.
-This tool makes the judgment mechanical and consumable by ``doctor
---perfwatch``:
+Bench artifacts accumulate one per round (``BENCH_r0N.json`` driver
+round files at the root, ``docs/runs/bench_r*_tpu_v5e.json`` archived
+chip runs). Whether a round's number is a win, noise, or a regression
+was judged by eyeball. This tool makes the judgment mechanical and
+consumable by ``doctor --perfwatch``:
 
-- parse every artifact (the ``parsed`` field when the driver captured
-  one, else salvage the last intact JSON line from the recorded stdout
-  ``tail`` — the BENCH_r04 failure mode, rc=124 with parsed=null);
+- parse every artifact (a driver round file's ``parsed`` field, or a
+  raw bench line);
 - extract the tracked metrics (headline CIFAR steps/sec, ImageNet
   steps/sec and MFU) as (round, backend, value) samples;
-- cohort by backend — a CPU-fallback round must never be compared
-  against chip numbers (BENCH_r02/r03 recorded 0.03/0.01 st/s CPU
-  fallbacks while fetch-verified TPU numbers sat in docs/runs/);
+- cohort by backend — a number from one backend is never compared
+  against another's;
 - compare the newest sample of the newest-sampled cohort against the
   median of its predecessors with a configurable noise band.
 
@@ -100,34 +97,11 @@ def _lower_is_better(name: str) -> bool:
                                 SWEEP_LAT_PREFIX, SWEEP_COMM_PREFIX)))
 
 
-def salvage_result(text: str) -> Optional[dict]:
-    """Last intact JSON object line in a stdout tail — accepts both the
-    bare ``_emit`` line and child ``RESULT_JSON:``-prefixed snapshots,
-    skipping truncated lines (the BENCH_r04 capture truncated the only
-    emit mid-string; earlier complete lines, when present, still win)."""
-    for line in reversed(text.splitlines()):
-        line = line.strip()
-        if line.startswith("RESULT_JSON: "):
-            line = line[len("RESULT_JSON: "):]
-        if not (line.startswith("{") and line.endswith("}")):
-            continue
-        try:
-            rec = json.loads(line)
-        except ValueError:
-            continue
-        if isinstance(rec, dict) and ("metric" in rec or "backend" in rec):
-            return rec
-    return None
-
-
 def _record_of(payload: dict) -> Optional[dict]:
-    """A driver round file ({parsed, tail, ...}) or a raw bench snapshot
-    → the bench result record."""
-    if "parsed" in payload or "tail" in payload:
-        rec = payload.get("parsed")
-        if not rec:
-            rec = salvage_result(payload.get("tail") or "")
-        return rec
+    """A driver round file ({parsed, ...}) or a raw bench snapshot → the
+    bench result record (None for a round that parsed nothing)."""
+    if "parsed" in payload:
+        return payload["parsed"]
     return payload if isinstance(payload, dict) else None
 
 
@@ -232,7 +206,7 @@ def judge(samples: List[dict], noise: float = 0.08,
 
 def sweep_record_of(payload) -> Optional[dict]:
     """A sweep trajectory (tools/sweep.py ``--json`` artifact, a raw
-    RESULT_JSON dict, or a driver-style {parsed|tail} wrapper) → the
+    RESULT_JSON dict, or a driver-style {parsed} wrapper) → the
     trajectory record, else None."""
     rec = _record_of(payload) if isinstance(payload, dict) else None
     if isinstance(rec, dict) and isinstance(rec.get("points"), list):

@@ -68,13 +68,19 @@ _FORBIDDEN_DTYPES = (
 )
 
 _ADDR = re.compile(r"0x[0-9a-f]+")
+_FROZENSET = re.compile(r"frozenset\(\{([^{}]*)\}\)")
 
 
 def canonicalize(jaxpr_text: str) -> str:
-    """Jaxpr text with process-varying tokens (object addresses in
-    embedded function reprs) normalized, so the sha256 is stable across
-    processes and machines."""
-    return _ADDR.sub("0xX", jaxpr_text)
+    """Jaxpr text with process-varying tokens normalized, so the sha256
+    is stable across processes and machines: object addresses in embedded
+    function reprs, and the element order of printed frozensets
+    (shard_map's ``manual_axes=frozenset({'data', 'model'})`` prints in
+    string-hash order, which PYTHONHASHSEED reshuffles per process)."""
+    text = _ADDR.sub("0xX", jaxpr_text)
+    return _FROZENSET.sub(
+        lambda m: "frozenset({" + ", ".join(sorted(
+            part.strip() for part in m.group(1).split(","))) + "})", text)
 
 
 def _sha(text: str) -> str:
@@ -294,14 +300,9 @@ MATRIX: Tuple[MatrixEntry, ...] = (
 
 
 def _abstract_mesh(data: int, model: int):
-    """AbstractMesh across the jax API generations (0.4.x takes a tuple
-    of (name, size) pairs; >= 0.5 takes (sizes, names))."""
     from jax.sharding import AbstractMesh
 
-    try:
-        return AbstractMesh((("data", data), ("model", model)))
-    except TypeError:
-        return AbstractMesh((data, model), ("data", "model"))
+    return AbstractMesh((data, model), ("data", "model"))
 
 
 def _state_layout(state_sds) -> List[Tuple[str, str, Tuple[int, ...]]]:

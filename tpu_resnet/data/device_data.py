@@ -166,10 +166,15 @@ def staged_chunk_jit(base_step: Callable, mesh: Mesh, c: int,
         chunk = per_replica_shard_map(
             chunk, mesh,
             in_specs=(P(), P(None, "data"), P(None, "data"), P()))
+    state_sh = state_sharding if state_sharding is not None else repl
+    # The state comes back in the layout it went in with (metrics
+    # replicated): left to the partitioner, a four-chip TPU compile
+    # returned a 16-element zero1 leaf sharded over 'data', and the next
+    # dispatch refused it against in_shardings.
     return jax.jit(
         chunk,
-        in_shardings=(state_sharding if state_sharding is not None
-                      else repl, staged, staged, None),
+        in_shardings=(state_sh, staged, staged, None),
+        out_shardings=(state_sh, repl),
         donate_argnums=(0,) if donate_state else (),
     )
 

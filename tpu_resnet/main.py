@@ -194,7 +194,7 @@ def main(argv=None):
                                 "without a full bench run")
             p.add_argument("--trace-probe", action="store_true",
                            help="live observability drill (~60s tiny CPU "
-                                "run): scrape the live mfu gauge + "
+                                "run): scrape the live model FLOP/s gauge + "
                                 "train_step_ms histogram mid-run, then "
                                 "trace-export and schema-check the "
                                 "merged Chrome trace")
@@ -293,6 +293,13 @@ def main(argv=None):
     from tpu_resnet.config import load_config
     cfg = load_config(args.preset, args.config, args.overrides)
 
+    if args.command in ("train", "train_and_eval", "eval", "serve",
+                        "export", "predict", "info"):
+        # The commands that compile (the router, fleetmon and autopilot
+        # stay jax-free).
+        from tpu_resnet.hostenv import enable_compile_cache
+        enable_compile_cache()
+
     if args.command == "train":
         from tpu_resnet import parallel
         from tpu_resnet.resilience import Preempted
@@ -328,7 +335,13 @@ def main(argv=None):
         parallel.initialize()
         if args.once:
             cfg.train.eval_once = True
-        evaluate(cfg)
+        precision = evaluate(cfg)
+        if args.once and precision is None:
+            # No checkpoint, or none that restored: `--once` that
+            # evaluated nothing is a failure, not a quiet success.
+            logging.getLogger("tpu_resnet").error(
+                "eval --once evaluated nothing in %s", cfg.train.train_dir)
+            return 1
         return 0
 
     if args.command == "info":

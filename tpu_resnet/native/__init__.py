@@ -87,6 +87,21 @@ def jpeg_available() -> bool:
         return False
 
 
+def decode_path() -> str:
+    """Which JPEG decoder ``data.use_native_loader`` gets on this machine:
+    ``turbo`` (libjpeg-turbo partial decode), ``libjpeg`` (plain libjpeg)
+    or ``pil`` (no compiler or no libjpeg: the pure-Python fallback). The
+    build ladder falls down these rungs without a word, so anything that
+    reports a decode rate should say which rung it stood on."""
+    try:
+        lib = _load() if available() else None
+    except ImportError:
+        lib = None
+    level = lib.tr_has_jpeg() if lib is not None \
+        and hasattr(lib, "tr_has_jpeg") else 0
+    return {2: "turbo", 1: "libjpeg"}.get(level, "pil")
+
+
 class loader:
     """Namespace matching the import sites (`from tpu_resnet.native import
     loader`)."""

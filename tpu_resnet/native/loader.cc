@@ -169,8 +169,12 @@ int64_t tr_tfrecord_split(const uint8_t* buf, int64_t n, int64_t* out_spans,
 // threads via ctypes, which releases the GIL — so decode scales across
 // cores where PIL mostly serializes.
 
+// 0 = built without libjpeg, 1 = plain libjpeg, 2 = libjpeg-turbo
+// partial decode (crop/skip).
 int32_t tr_has_jpeg(void) {
-#ifdef TR_WITH_JPEG
+#if defined(TR_WITH_JPEG) && defined(TR_TURBO_CROP)
+  return 2;
+#elif defined(TR_WITH_JPEG)
   return 1;
 #else
   return 0;

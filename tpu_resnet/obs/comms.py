@@ -40,11 +40,15 @@ One subtlety the parser owns so every consumer doesn't have to: XLA's
 CPU pipeline runs the reduce-scatter DECOMPOSER (reduce-scatter becomes
 a full all-reduce whose result is immediately sliced), so a ZeRO-1
 gradient exchange never shows a literal ``reduce-scatter`` op in a CPU
-compile. ``extract_collectives`` re-derives the logical op: an
-all-reduce whose every consumer keeps at most ``1/group_size`` of the
-payload is classified (and costed) as a reduce-scatter. On TPU the
-literal op appears and classifies identically, so goldens and gates
-mean the same thing on both backends.
+compile. ``extract_collectives`` re-derives the LOGICAL op: an
+all-reduced array whose every consumer keeps at most ``1/group_size``
+of it is classified (and costed) as a reduce-scatter; ``raw_op`` keeps
+the opcode actually emitted. A four-chip v5e compile (jax 0.9.0, PR 21)
+showed the same form, not a literal op: the zero1 gradient exchange was
+one combined tuple all-reduce (bf16) whose elements are then sliced —
+so on that backend the ring cost of the re-derived op UNDER-counts the
+wire, which carries the full all-reduce. A literal ``reduce-scatter``
+classifies identically where a compiler emits one.
 
 Like the FLOPs/HBM accountants this pays its cost ONCE per run at first
 dispatch (one extra XLA compile, gated by ``train.comms_ledger``,
@@ -96,10 +100,15 @@ _DTYPE_BYTES = {"pred": 1, "s8": 1, "u8": 1, "s4": 1, "u4": 1,
                 "s64": 8, "u64": 8, "f64": 8, "c64": 8, "c128": 16}
 
 _SHAPE_RE = re.compile(r"([a-z]\w*)\[([0-9,]*)\]")
+# A tuple type may hold parentheses of its own — TPU layouts print their
+# tiling inside the braces, ``f32[512,128]{1,0:T(8,128)}`` — so it runs
+# (non-greedily) up to the ``) opcode(`` that ends it, not to the first ")".
 _INSTR_RE = re.compile(
     r"^\s*(?:ROOT\s+)?%(?P<name>[\w.-]+)\s*=\s*"
-    r"(?P<type>\([^)]*\)|[a-z]\w*\[[0-9,]*\](?:\{[^}]*\})?)\s+"
+    r"(?P<type>\(.*?\)|[a-z]\w*\[[0-9,]*\](?:\{[^}]*\})?)\s+"
     r"(?P<op>[\w-]+)\(")
+_REF_RE = re.compile(r"%([\w.-]+)")
+_GTE_INDEX_RE = re.compile(r"get-tuple-element\(.*\), index=(\d+)\b")
 _EXPLICIT_GROUPS_RE = re.compile(r"replica_groups=\{(\{[0-9, ]*\}"
                                  r"(?:,\{[0-9, ]*\})*)?\}")
 _IOTA_GROUPS_RE = re.compile(r"replica_groups=\[(\d+),(\d+)\]"
@@ -305,18 +314,25 @@ def extract_collectives(hlo_text: str, data_axis: int,
     """Every collective op in ``hlo_text`` (post-SPMD-partitioner HLO —
     collectives only exist after partitioning) with payloads, groups,
     axis buckets and ring-model wire bytes. Async ``-start``/``-done``
-    pairs count once; an all-reduce whose consumers all keep at most
-    ``1/group_size`` of the payload is re-derived as the logical
-    reduce-scatter XLA's CPU decomposer hid (see module docstring)."""
+    pairs count once; an all-reduced array whose consumers all keep at
+    most ``1/group_size`` of it is re-derived as the logical
+    reduce-scatter XLA's CPU decomposer hid (see module docstring) — per
+    element when the combiner merged reductions into one tuple op."""
     n_devices = max(data_axis * model_axis, 1)
     out: List[Collective] = []
     for block in _split_computations(hlo_text):
-        instrs = []  # (name, result_bytes, line)
+        instrs = []  # (name, result_bytes, match, line)
+        users = {}   # operand name -> [(user name, user bytes, user line)]
         for line in block:
             m = _INSTR_RE.match(line)
             if m:
-                instrs.append((m.group("name"),
-                               _type_bytes(m.group("type")), m, line))
+                rec = (m.group("name"), _type_bytes(m.group("type")), m,
+                       line)
+                instrs.append(rec)
+                for ref in set(_REF_RE.findall(line.split(" = ", 1)[-1])):
+                    if ref != rec[0]:
+                        users.setdefault(ref, []).append(
+                            (rec[0], rec[1], line))
         for name, result_bytes, m, line in instrs:
             raw_op = m.group("op")
             base_op = raw_op[:-6] if raw_op.endswith("-start") else raw_op
@@ -343,27 +359,48 @@ def extract_collectives(hlo_text: str, data_axis: int,
                         if depth == 0:
                             payload = _type_bytes(tail[:i]) or result_bytes
                             break
-            op = base_op
-            if base_op == "all-reduce" and not type_text.startswith("("):
-                # Re-derive the decomposed reduce-scatter: every
-                # consumer keeps <= ceil(payload/G) (+ one element of
-                # layout slack) of the reduced result.
-                shard_cap = (payload + group_size - 1) // group_size \
-                    + _DTYPE_BYTES.get(_type_dtype(type_text), 4)
-                ref = re.compile(re.escape("%" + name) + r"(?![\w.-])")
-                consumers = [cb for cn, cb, _, cl in instrs
-                             if cn != name and ref.search(
-                                 cl.split(" = ", 1)[-1])]
-                if consumers and group_size > 1 \
-                        and all(cb <= shard_cap for cb in consumers):
-                    op = "reduce-scatter"
-            out.append(Collective(
-                op=op, raw_op=raw_op, name=name,
-                dtype=_type_dtype(type_text),
-                payload_bytes=payload, group_size=group_size,
-                n_groups=len(groups),
-                bucket=classify_groups(groups, data_axis, model_axis),
-                wire_bytes=_ring_wire_bytes(op, payload, group_size)))
+            bucket = classify_groups(groups, data_axis, model_axis)
+
+            def emit(op, payload):
+                out.append(Collective(
+                    op=op, raw_op=raw_op, name=name,
+                    dtype=_type_dtype(type_text), payload_bytes=payload,
+                    group_size=group_size, n_groups=len(groups),
+                    bucket=bucket,
+                    wire_bytes=_ring_wire_bytes(op, payload, group_size)))
+
+            if base_op != "all-reduce" or group_size <= 1:
+                emit(base_op, payload)
+                continue
+            # Re-derive the decomposed reduce-scatter: a reduced array
+            # whose every consumer keeps <= ceil(bytes/G) (+ one element
+            # of layout slack) of it. XLA's all-reduce combiner may have
+            # merged many reductions into ONE tuple-typed all-reduce, so
+            # the test runs per tuple element (through its
+            # get-tuple-element) and the op splits into its scattered
+            # and its plain part.
+            if type_text.startswith("("):
+                by_index = {}  # tuple index -> users of its get-tuple-element
+                for gn, _, gl in users.get(name, ()):
+                    gte = _GTE_INDEX_RE.search(gl)
+                    if gte:
+                        by_index.setdefault(int(gte.group(1)), []).extend(
+                            users.get(gn, ()))
+                elements = [(_type_bytes(t.group(0)), by_index.get(i, ()))
+                            for i, t in
+                            enumerate(_SHAPE_RE.finditer(type_text))]
+            else:
+                elements = [(payload, users.get(name, ()))]
+            slack = _DTYPE_BYTES.get(_type_dtype(type_text), 4)
+            scattered = sum(
+                nbytes for nbytes, keepers in elements
+                if keepers and all(
+                    cb <= (nbytes + group_size - 1) // group_size + slack
+                    for _, cb, _ in keepers))
+            if scattered:
+                emit("reduce-scatter", scattered)
+            if payload - scattered or not scattered:
+                emit("all-reduce", payload - scattered)
     return out
 
 

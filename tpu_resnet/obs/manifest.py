@@ -85,6 +85,34 @@ def _git_rev() -> Optional[str]:
     return rev if proc.returncode == 0 and rev else None
 
 
+def library_versions() -> dict:
+    """jax / jaxlib / libtpu as installed (libtpu None where there is no
+    TPU runtime) — with the device block, what names the machine a
+    number came from."""
+    import importlib.metadata
+
+    import jax
+    import jaxlib
+
+    try:
+        libtpu = importlib.metadata.version("libtpu")
+    except importlib.metadata.PackageNotFoundError:
+        libtpu = None
+    return {"jax": jax.__version__, "jaxlib": jaxlib.__version__,
+            "libtpu": libtpu}
+
+
+def device_record(devices) -> dict:
+    """The device block of the manifest and of the server's ``/info``:
+    platform, device kinds and count exactly as JAX reports them."""
+    devices = list(devices)
+    return {
+        "count": len(devices),
+        "kinds": sorted({d.device_kind for d in devices}),
+        "platform": devices[0].platform if devices else None,
+    }
+
+
 def build_manifest(cfg, mesh, run_id: Optional[str] = None,
                    extra: Optional[dict] = None) -> dict:
     """Assemble the manifest dict (pure; no filesystem writes).
@@ -95,7 +123,6 @@ def build_manifest(cfg, mesh, run_id: Optional[str] = None,
 
     import tpu_resnet
 
-    devices = list(mesh.devices.flat)
     manifest = {
         "schema": SCHEMA_VERSION,
         "run_id": run_id,
@@ -103,17 +130,13 @@ def build_manifest(cfg, mesh, run_id: Optional[str] = None,
         "config": cfg.to_dict(),
         "mesh": {"shape": dict(mesh.shape),
                  "axis_names": list(mesh.axis_names)},
-        "devices": {
-            "count": len(devices),
-            "kinds": sorted({d.device_kind for d in devices}),
-            "platform": devices[0].platform if devices else None,
-        },
+        "devices": device_record(mesh.devices.flat),
         "processes": {"count": jax.process_count(),
                       "index": jax.process_index()},
         "versions": {
             "tpu_resnet": getattr(tpu_resnet, "__version__", None),
             "python": sys.version.split()[0],
-            "jax": jax.__version__,
+            **library_versions(),
         },
         "git_rev": _git_rev(),
         "hostname": socket.gethostname(),

@@ -83,18 +83,9 @@ BUDGET_COMPONENTS = ("argument_bytes", "output_bytes", "temp_bytes",
                      "alias_bytes", "generated_code_bytes")
 
 
-def hbm_bytes_per_chip(device_kind: str,
-                       env_var: str = "TPU_RESNET_HBM_BYTES"
-                       ) -> Optional[int]:
+def hbm_bytes_per_chip(device_kind: str) -> Optional[int]:
     """HBM capacity in bytes for one chip of ``device_kind``; None when
-    the kind is unknown (CPU, new silicon). ``env_var`` overrides the
-    table — the escape hatch for chips it hasn't learned yet."""
-    env = os.environ.get(env_var)
-    if env:
-        try:
-            return int(float(env))
-        except ValueError:
-            log.warning("ignoring non-numeric %s=%r", env_var, env)
+    the kind is not in the table (CPU, new silicon)."""
     kind = (device_kind or "").lower()
     for sub, cap in HBM_BYTES_BY_KIND:
         if sub in kind:
@@ -212,6 +203,7 @@ def lower_train_step(cfg, mesh, state, base_step,
     import jax
 
     from tpu_resnet import parallel
+    from tpu_resnet.programs.registry import BATCH_DTYPE
     from tpu_resnet.train.step import shard_step
 
     state_sharding = (partitioner.state_shardings(state)
@@ -219,7 +211,6 @@ def lower_train_step(cfg, mesh, state, base_step,
                      else None)
     size = cfg.data.resolved_image_size
     gb = cfg.train.global_batch_size
-    img_dtype = "float32" if cfg.data.dataset == "imagenet" else "uint8"
     if stage_rows > 1:
         # The staged/double-buffered input edge's fused chunk program —
         # built by the ONE canonical constructor the loop itself
@@ -231,7 +222,7 @@ def lower_train_step(cfg, mesh, state, base_step,
                                   per_replica_bn=per_replica_bn,
                                   state_sharding=state_sharding)
         gi = jax.ShapeDtypeStruct((stage_rows, gb, size, size, 3),
-                                  img_dtype)
+                                  BATCH_DTYPE)
         gl = jax.ShapeDtypeStruct((stage_rows, gb), "int32")
         off = jax.ShapeDtypeStruct((), "int32")
         lowered = jitted.lower(state, gi, gl, off)
@@ -239,7 +230,7 @@ def lower_train_step(cfg, mesh, state, base_step,
                    f",stage={stage_rows})")
     else:
         bs = parallel.batch_sharding(mesh)
-        images = jax.ShapeDtypeStruct((gb, size, size, 3), img_dtype,
+        images = jax.ShapeDtypeStruct((gb, size, size, 3), BATCH_DTYPE,
                                       sharding=bs)
         labels = jax.ShapeDtypeStruct((gb,), "int32", sharding=bs)
         probe = shard_step(base_step, mesh, per_replica_bn=per_replica_bn,

@@ -55,20 +55,10 @@ PEAK_FLOPS_BY_KIND = (
 )
 
 
-def peak_flops_per_chip(device_kind: str,
-                        env_var: str = "TPU_RESNET_PEAK_FLOPS"
-                        ) -> Optional[float]:
+def peak_flops_per_chip(device_kind: str) -> Optional[float]:
     """Peak dense FLOP/s for one chip of ``device_kind``; None when the
-    kind is unknown (CPU, new silicon). ``env_var`` (and the bench
-    harness's historical ``BENCH_PEAK_FLOPS``) overrides the table —
-    the escape hatch for chips the table hasn't learned yet."""
-    for var in (env_var, "BENCH_PEAK_FLOPS"):
-        env = os.environ.get(var)
-        if env:
-            try:
-                return float(env)
-            except ValueError:
-                log.warning("ignoring non-numeric %s=%r", var, env)
+    kind is not in the table (CPU, new silicon) — an unknown chip gets no
+    assumed peak."""
     kind = (device_kind or "").lower()
     for sub, peak in PEAK_FLOPS_BY_KIND:
         if sub in kind:
@@ -78,17 +68,10 @@ def peak_flops_per_chip(device_kind: str,
 
 def program_flops(cost) -> Optional[float]:
     """FLOPs from an XLA cost analysis — ``lowered.cost_analysis()`` or
-    ``compiled.cost_analysis()`` (older jax returns a one-element list).
-    None when the backend doesn't report them (some PJRT plugins)."""
-    try:
-        if isinstance(cost, list):
-            cost = cost[0] if cost else None
-        flops = (cost or {}).get("flops")
-        if flops and flops > 0:
-            return float(flops)
-    except Exception:  # noqa: BLE001 - accounting must never crash a run
-        pass
-    return None
+    ``compiled.cost_analysis()``. None when the backend doesn't report
+    them."""
+    flops = (cost or {}).get("flops")
+    return float(flops) if flops and flops > 0 else None
 
 
 def lowered_flops(jit_fn, *args) -> Optional[float]:
@@ -100,7 +83,8 @@ def lowered_flops(jit_fn, *args) -> Optional[float]:
     try:
         return program_flops(jit_fn.lower(*args).cost_analysis())
     except Exception as e:  # noqa: BLE001 - never sink the caller
-        log.debug("lowered cost analysis unavailable: %s", e)
+        log.warning("lowered cost analysis unavailable (%s: %s)",
+                    type(e).__name__, e)
         return None
 
 
@@ -218,6 +202,7 @@ def account_train_step(cfg, mesh, state, base_step,
     import jax
 
     from tpu_resnet import parallel
+    from tpu_resnet.programs.registry import BATCH_DTYPE
     from tpu_resnet.train.step import shard_step
 
     registry = registry or FlopsRegistry()
@@ -225,10 +210,7 @@ def account_train_step(cfg, mesh, state, base_step,
     bs = parallel.batch_sharding(mesh)
     size = cfg.data.resolved_image_size
     gb = cfg.train.global_batch_size
-    # ImageNet streams pre-processed floats; every other dataset feeds
-    # raw uint8 and augments on device — match what the step compiles on.
-    img_dtype = "float32" if cfg.data.dataset == "imagenet" else "uint8"
-    images = jax.ShapeDtypeStruct((gb, size, size, 3), img_dtype,
+    images = jax.ShapeDtypeStruct((gb, size, size, 3), BATCH_DTYPE,
                                   sharding=bs)
     labels = jax.ShapeDtypeStruct((gb,), "int32", sharding=bs)
     probe = shard_step(base_step, mesh, donate_state=False,

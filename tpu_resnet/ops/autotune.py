@@ -68,7 +68,6 @@ class Decision:
     xla_us: float
     speedup: float          # xla_us / pallas_us; > 1 means Pallas wins
     use_pallas: bool
-    error: Optional[str] = None   # Pallas candidate failed to compile/run
 
     def to_dict(self) -> dict:
         return dataclasses.asdict(self)
@@ -123,8 +122,8 @@ def _record(d: Decision) -> Decision:
 def _timed_us(fn: Callable, args: tuple, iters: int) -> float:
     """Mean per-iteration wall micros of ``fn(*args)`` with the whole
     loop fused into ONE dispatch (lax.scan) and the result fetched to the
-    host (`bench._fetch_sync` discipline: block_until_ready was observed
-    lying on a degrading remote backend). The first array argument is
+    host, which cannot complete before every iteration it depends on. The
+    first array argument is
     perturbed by the running accumulator so XLA can neither hoist the
     loop-invariant body nor overlap iterations."""
     import time
@@ -164,21 +163,14 @@ def probe(op: str, key: str, pallas_fn: Callable, xla_fn: Callable,
     ``pallas_fn``/``xla_fn`` map ``*args`` to any pytree of arrays (time
     a grad if the hot path is a grad — the caller chooses what to
     measure). Re-probing a cached (op, key) is a no-op unless ``force``.
-    A Pallas candidate that fails to compile or run records a fallback
-    decision (use_pallas=False) with the error — a broken kernel must
-    degrade to XLA, never kill the caller's setup path."""
+    A Pallas candidate that fails to compile or run RAISES: a kernel
+    Mosaic refuses is a defect to see and repair, and "use XLA" is a
+    verdict only a measurement may reach."""
     existing = decision(op, key)
     if existing is not None and not force:
         return existing
     xla_us = _timed_us(xla_fn, args, iters)
-    try:
-        pallas_us = _timed_us(pallas_fn, args, iters)
-    except Exception as e:  # noqa: BLE001 - fallback is the contract
-        log.warning("autotune %s[%s]: Pallas candidate failed (%s: %s) — "
-                    "falling back to XLA", op, key, type(e).__name__, e)
-        return _record(Decision(op, key, float("inf"), round(xla_us, 3),
-                                0.0, False,
-                                error=f"{type(e).__name__}: {e}"[:300]))
+    pallas_us = _timed_us(pallas_fn, args, iters)
     speedup = xla_us / pallas_us if pallas_us > 0 else 0.0
     d = _record(Decision(op, key, round(pallas_us, 3), round(xla_us, 3),
                          round(speedup, 4), speedup >= threshold))
@@ -224,7 +216,7 @@ def load(path: str) -> int:
         try:
             _record(Decision(op, key, float(rec["pallas_us"]),
                              float(rec["xla_us"]), float(rec["speedup"]),
-                             bool(rec["use_pallas"]), rec.get("error")))
+                             bool(rec["use_pallas"])))
             n += 1
         except (KeyError, TypeError, ValueError):
             continue

@@ -36,13 +36,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # TPU-only module; absent on pure-CPU installs of older jaxlibs
-    from jax.experimental.pallas import tpu as pltpu
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
+from jax.experimental.pallas import tpu as pltpu
 
 from tpu_resnet.ops import autotune
 from tpu_resnet.ops.softmax_xent import is_tpu_backend
@@ -75,13 +69,21 @@ def _acc_out(first, refs, vals):
             ref[...] += v
 
 
+def vmem_row_bytes(h: int, w: int, c: int, itemsize: int = 4) -> int:
+    """VMEM bytes of one [h, w, c] batch row as Mosaic lays it out: the
+    two minor dims tile to (8, 128), so a 16-channel CIFAR stage occupies
+    eight times its logical size (measured on the chip: the 32x32x16
+    epilogue overflowed VMEM under a plan that counted logical bytes)."""
+    return h * (-(-w // 8) * 8) * (-(-c // 128) * 128) * itemsize
+
+
 def auto_batch_tile(shape, budget_bytes: int = 8 * 2 ** 20) -> int:
     """Largest batch divisor whose forward live set (~3 fp32 slabs: x,
     activation, out/residual) fits the VMEM plan budget. Epilogues are
     elementwise so any divisor is correct; bigger tiles amortize grid
     overhead."""
     b, h, w, c = shape
-    per_row = h * w * c * 4 * 3
+    per_row = vmem_row_bytes(h, w, c) * 3
     bt = max(1, min(b, budget_bytes // max(per_row, 1)))
     while b % bt:
         bt -= 1
@@ -100,7 +102,7 @@ def _plumbing(x, batch_tile, interpret):
     tile = pl.BlockSpec((bt, h, w, c), lambda i: (i, 0, 0, 0))
     full = lambda *shape: pl.BlockSpec(shape, lambda i: (0,) * len(shape))
     kwargs = {}
-    if _VMEM is not None and not interpret:
+    if not interpret:
         kwargs["compiler_params"] = pltpu.CompilerParams(
             dimension_semantics=("arbitrary",))
     return interpret, grid, tile, full, kwargs
@@ -351,8 +353,8 @@ def probe_model_epilogues(cfg, local_batch: int, iters: int = 30):
     ``model.fused_epilogue="auto"`` setup pass). Only OP_SBR is probed —
     the model's BN sites dispatch nothing else; the add variant is
     library/A-B surface (probe_epilogue include_add). Returns the
-    decision list; per-shape failures fall back to XLA inside
-    autotune.probe."""
+    decision list; a shape whose kernel fails to compile raises
+    (autotune.probe)."""
     dtype = jnp.dtype(cfg.model.compute_dtype)
     out = []
     for shape in model_epilogue_shapes(cfg, local_batch):

@@ -52,13 +52,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # TPU-only module; absent on pure-CPU installs of older jaxlibs
-    from jax.experimental.pallas import tpu as pltpu
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
+from jax.experimental.pallas import tpu as pltpu
 
 from tpu_resnet.ops.softmax_xent import is_tpu_backend
 
@@ -68,6 +62,7 @@ from tpu_resnet.ops.softmax_xent import is_tpu_backend
 from tpu_resnet.ops.epilogue import _acc_out  # noqa: F401  (re-exported:
 from tpu_resnet.ops.epilogue import (         # fused_bottleneck imports
     scale_bias_relu_math as _scale_bias_relu)  # both from this module)
+from tpu_resnet.ops.epilogue import vmem_row_bytes
 
 
 def _conv3x3_taps(h_pad, w, bt, h, wdt, c):
@@ -114,8 +109,10 @@ def auto_batch_tile(shape, cap: int = 16,
     budget, or raises if even one batch row cannot fit (f=512 ImageNet
     blocks: weights alone are ~18.9 MB — callers keep those on XLA)."""
     b, h, w, c = shape
-    weight_bytes = 2 * 9 * c * c * 4
-    per_row = h * w * c * 4 * 4
+    # Sized as Mosaic lays arrays out (minor dims tiled to (8, 128)), not
+    # by logical bytes: at 16 channels the two differ eightfold.
+    weight_bytes = 2 * 9 * vmem_row_bytes(1, c, c)
+    per_row = vmem_row_bytes(h, w, c) * 4
     avail = budget_bytes - weight_bytes
     if avail < per_row:
         raise ValueError(
@@ -164,7 +161,7 @@ def _plumbing(x, batch_tile, interpret):
     tile = pl.BlockSpec((bt, h, wdt, c), lambda i: (i, 0, 0, 0))
     full = lambda *shape: pl.BlockSpec(shape, lambda i: (0,) * len(shape))
     kwargs = {}
-    if _VMEM is not None and not interpret:
+    if not interpret:
         kwargs["compiler_params"] = pltpu.CompilerParams(
             dimension_semantics=("arbitrary",))
     return interpret, bt, grid, tile, full, kwargs
@@ -230,7 +227,9 @@ def block_fwd_reference(x, w1, w2, s1, b1, s2, b2):
 
 def _transpose_weights(w):
     """Weights of the transposed SAME 3×3 conv: spatial flip + IO-channel
-    swap, so convT(d, w) == _conv3x3_taps(d_pad, _transpose_weights(w))."""
+    swap, so convT(d, w) == _conv3x3_taps(d_pad, _transpose_weights(w)).
+    Applied OUTSIDE the kernels (the flip is a ``rev``, which Mosaic does
+    not lower): the backward kernels take the result as an input."""
     return w[::-1, ::-1].transpose(0, 1, 3, 2)
 
 
@@ -249,8 +248,8 @@ def _wgrad_taps(r_pad, d, bt, h, wdt, c):
     return jnp.stack(rows)  # [3,3,C,C]
 
 
-def _block_bwd_kernel(x_ref, gy_ref, w1_ref, w2_ref, s1_ref, b1_ref,
-                      s2_ref, b2_ref,
+def _block_bwd_kernel(x_ref, gy_ref, w1_ref, w2_ref, w1t_ref, w2t_ref,
+                      s1_ref, b1_ref, s2_ref, b2_ref,
                       dx_ref, dw1_ref, dw2_ref, ds1_ref, db1_ref,
                       ds2_ref, db2_ref):
     bt, h, wdt, c = x_ref.shape
@@ -259,6 +258,8 @@ def _block_bwd_kernel(x_ref, gy_ref, w1_ref, w2_ref, s1_ref, b1_ref,
     gy = gy_ref[...].astype(jnp.float32)
     w1 = w1_ref[...].astype(jnp.float32)
     w2 = w2_ref[...].astype(jnp.float32)
+    w1t = w1t_ref[...].astype(jnp.float32)
+    w2t = w2t_ref[...].astype(jnp.float32)
     s1, b1 = s1_ref[...], b1_ref[...]
     s2, b2 = s2_ref[...], b2_ref[...]
 
@@ -273,11 +274,11 @@ def _block_bwd_kernel(x_ref, gy_ref, w1_ref, w2_ref, s1_ref, b1_ref,
 
     # Backward chain (convT = taps over the flipped/IO-swapped weights).
     gyp = jnp.pad(gy, ((0, 0), (1, 1), (1, 1), (0, 0)))
-    dr2 = _conv3x3_taps(gyp, _transpose_weights(w2), bt, h, wdt, c)
+    dr2 = _conv3x3_taps(gyp, w2t, bt, h, wdt, c)
     da2 = jnp.where(a2 > 0, dr2, 0.0)
     dc1 = da2 * s2
     dc1p = jnp.pad(dc1, ((0, 0), (1, 1), (1, 1), (0, 0)))
-    dr1 = _conv3x3_taps(dc1p, _transpose_weights(w1), bt, h, wdt, c)
+    dr1 = _conv3x3_taps(dc1p, w1t, bt, h, wdt, c)
     da1 = jnp.where(a1 > 0, dr1, 0.0)
     dx_ref[...] = (gy + da1 * s1).astype(dx_ref.dtype)
 
@@ -302,8 +303,8 @@ def _block_bwd_call(x, gy, w1, w2, s1, b1, s2, b2, *, batch_tile: int,
     return pl.pallas_call(
         _block_bwd_kernel,
         grid=grid,
-        in_specs=[tile, tile, full(3, 3, c, c), full(3, 3, c, c),
-                  full(c), full(c), full(c), full(c)],
+        in_specs=[tile, tile] + [full(3, 3, c, c)] * 4
+                 + [full(c), full(c), full(c), full(c)],
         out_specs=[tile, full(3, 3, c, c), full(3, 3, c, c),
                    full(c), full(c), full(c), full(c)],
         out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype),
@@ -315,7 +316,8 @@ def _block_bwd_call(x, gy, w1, w2, s1, b1, s2, b2, *, batch_tile: int,
                    jax.ShapeDtypeStruct((c,), f32)],
         interpret=interpret,
         **kwargs,
-    )(x, gy, w1, w2, s1, b1, s2, b2)
+    )(x, gy, w1, w2, _transpose_weights(w1), _transpose_weights(w2),
+      s1, b1, s2, b2)
 
 
 # --------------------------------------------------------------------------
@@ -364,28 +366,31 @@ def _train_bwd_calls(x, gy, w1, w2, g1, b1, g2, b2, moments, eps, *,
     b, h, wdt, c = x.shape
     n = float(b * h * wdt)
     f32 = jnp.float32
-    # x, gy, w1, w2, then the 8 [C] vectors g1,b1,g2,b2,m1,i1,m2,i2
-    base_in = ([tile, tile, full(3, 3, c, c), full(3, 3, c, c)]
-               + [full(c)] * 8)
+    # x, gy, w1, w2, their convT forms w1t, w2t, then the 8 [C] vectors
+    # g1,b1,g2,b2,m1,i1,m2,i2
+    base_in = [tile, tile] + [full(3, 3, c, c)] * 4 + [full(c)] * 8
+    base_ops = (x, gy, w1, w2, _transpose_weights(w1),
+                _transpose_weights(w2), g1, b1, g2, b2, m1, i1, m2, i2)
     wshape = jax.ShapeDtypeStruct((3, 3, c, c), f32)
     cshape = jax.ShapeDtypeStruct((c,), f32)
 
     def load(refs):
-        (x_ref, gy_ref, w1_ref, w2_ref, g1_ref, b1_ref, g2_ref, b2_ref,
-         m1_ref, i1_ref, m2_ref, i2_ref) = refs
+        (x_ref, gy_ref, w1_ref, w2_ref, w1t_ref, w2t_ref, g1_ref, b1_ref,
+         g2_ref, b2_ref, m1_ref, i1_ref, m2_ref, i2_ref) = refs
         return (x_ref[...].astype(f32), gy_ref[...].astype(f32),
                 w1_ref[...].astype(f32), w2_ref[...].astype(f32),
+                w1t_ref[...].astype(f32), w2t_ref[...].astype(f32),
                 g1_ref[...], b1_ref[...], g2_ref[...], b2_ref[...],
                 m1_ref[...], i1_ref[...], m2_ref[...], i2_ref[...])
 
     def pass1(*refs):
         (t1_ref, t2_ref, dw2_ref) = refs[-3:]
-        xv, gyv, w1v, w2v, g1v, b1v, g2v, b2v, m1v, i1v, m2v, i2v = \
-            load(refs[:-3])
+        (xv, gyv, w1v, w2v, w1tv, w2tv, g1v, b1v, g2v, b2v, m1v, i1v, m2v,
+         i2v) = load(refs[:-3])
         _, _, _, z2, z2hat, r2p = _recompute_train(
             xv, w1v, g1v, b1v, g2v, b2v, m1v, i1v, m2v, i2v, bt, h, wdt, c)
         gyp = jnp.pad(gyv, ((0, 0), (1, 1), (1, 1), (0, 0)))
-        dr2 = _conv3x3_taps(gyp, _transpose_weights(w2v), bt, h, wdt, c)
+        dr2 = _conv3x3_taps(gyp, w2tv, bt, h, wdt, c)
         dz2 = jnp.where(z2 > 0, dr2, 0.0)
         _acc_out(pl.program_id(0) == 0, (t1_ref, t2_ref, dw2_ref),
                  (jnp.sum(dz2, axis=(0, 1, 2)),
@@ -397,26 +402,27 @@ def _train_bwd_calls(x, gy, w1, w2, g1, b1, g2, b2, moments, eps, *,
         out_specs=[full(c), full(c), full(3, 3, c, c)],
         out_shape=[cshape, cshape, wshape],
         interpret=interpret, **kwargs,
-    )(x, gy, w1, w2, g1, b1, g2, b2, m1, i1, m2, i2)
+    )(*base_ops)
 
-    def _dc1(z2, z2hat, gyv, w2v, g2v, i2v, t1v, t2v):
+    def _dc1(z2, z2hat, gyv, w2tv, g2v, i2v, t1v, t2v):
         dr2 = _conv3x3_taps(
             jnp.pad(gyv, ((0, 0), (1, 1), (1, 1), (0, 0))),
-            _transpose_weights(w2v), bt, h, wdt, c)
+            w2tv, bt, h, wdt, c)
         dz2 = jnp.where(z2 > 0, dr2, 0.0)
         return g2v * i2v * (dz2 - t1v / n - z2hat * (t2v / n))
 
     def pass2(*refs):
         (u1_ref, u2_ref, dw1_ref) = refs[-3:]
         t1_ref, t2_ref = refs[-5:-3]
-        xv, gyv, w1v, w2v, g1v, b1v, g2v, b2v, m1v, i1v, m2v, i2v = \
-            load(refs[:-5])
+        (xv, gyv, w1v, w2v, w1tv, w2tv, g1v, b1v, g2v, b2v, m1v, i1v, m2v,
+         i2v) = load(refs[:-5])
         z1, z1hat, r1p, z2, z2hat, _ = _recompute_train(
             xv, w1v, g1v, b1v, g2v, b2v, m1v, i1v, m2v, i2v, bt, h, wdt, c)
-        dc1 = _dc1(z2, z2hat, gyv, w2v, g2v, i2v, t1_ref[...], t2_ref[...])
+        dc1 = _dc1(z2, z2hat, gyv, w2tv, g2v, i2v, t1_ref[...],
+                   t2_ref[...])
         dr1 = _conv3x3_taps(
             jnp.pad(dc1, ((0, 0), (1, 1), (1, 1), (0, 0))),
-            _transpose_weights(w1v), bt, h, wdt, c)
+            w1tv, bt, h, wdt, c)
         dz1 = jnp.where(z1 > 0, dr1, 0.0)
         _acc_out(pl.program_id(0) == 0, (u1_ref, u2_ref, dw1_ref),
                  (jnp.sum(dz1, axis=(0, 1, 2)),
@@ -428,19 +434,20 @@ def _train_bwd_calls(x, gy, w1, w2, g1, b1, g2, b2, moments, eps, *,
         out_specs=[full(c), full(c), full(3, 3, c, c)],
         out_shape=[cshape, cshape, wshape],
         interpret=interpret, **kwargs,
-    )(x, gy, w1, w2, g1, b1, g2, b2, m1, i1, m2, i2, t1, t2)
+    )(*base_ops, t1, t2)
 
     def pass3(*refs):
         dx_ref = refs[-1]
         t1_ref, t2_ref, u1_ref, u2_ref = refs[-5:-1]
-        xv, gyv, w1v, w2v, g1v, b1v, g2v, b2v, m1v, i1v, m2v, i2v = \
-            load(refs[:-5])
+        (xv, gyv, w1v, w2v, w1tv, w2tv, g1v, b1v, g2v, b2v, m1v, i1v, m2v,
+         i2v) = load(refs[:-5])
         z1, z1hat, _, z2, z2hat, _ = _recompute_train(
             xv, w1v, g1v, b1v, g2v, b2v, m1v, i1v, m2v, i2v, bt, h, wdt, c)
-        dc1 = _dc1(z2, z2hat, gyv, w2v, g2v, i2v, t1_ref[...], t2_ref[...])
+        dc1 = _dc1(z2, z2hat, gyv, w2tv, g2v, i2v, t1_ref[...],
+                   t2_ref[...])
         dr1 = _conv3x3_taps(
             jnp.pad(dc1, ((0, 0), (1, 1), (1, 1), (0, 0))),
-            _transpose_weights(w1v), bt, h, wdt, c)
+            w1tv, bt, h, wdt, c)
         dz1 = jnp.where(z1 > 0, dr1, 0.0)
         dx = gyv + g1v * i1v[None, None, None, :] * (
             dz1 - u1_ref[...] / n - z1hat * (u2_ref[...] / n))
@@ -452,7 +459,7 @@ def _train_bwd_calls(x, gy, w1, w2, g1, b1, g2, b2, moments, eps, *,
         out_specs=tile,
         out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
         interpret=interpret, **kwargs,
-    )(x, gy, w1, w2, g1, b1, g2, b2, m1, i1, m2, i2, t1, t2, u1, u2)
+    )(*base_ops, t1, t2, u1, u2)
 
     # dγ2 = T2, dβ2 = T1, dγ1 = U2, dβ1 = U1 — the correction sums.
     return dx, dw1, dw2, u2, u1, t2, t1

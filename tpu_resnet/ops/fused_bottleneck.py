@@ -42,17 +42,11 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from tpu_resnet.ops.fused_block import (_acc_out, _conv3x3_taps,
                                         _transpose_weights, _wgrad_taps,
                                         is_tpu_backend)
-
-try:  # TPU-only module; absent on pure-CPU installs of older jaxlibs
-    from jax.experimental.pallas import tpu as pltpu
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
 
 # (batch_tile, row_tile) by bottleneck width f — sized so the backward's
 # recomputed chain + gradient chain + weight-grad accumulators stay under
@@ -182,7 +176,7 @@ def _plumb(x, batch_tile, row_tile, interpret, f):
     bt, ht = _tiles_for(f, b, h, batch_tile, row_tile)
     grid = (b // bt, h // ht)
     kwargs = {}
-    if _VMEM is not None and not interpret:
+    if not interpret:
         kwargs["compiler_params"] = pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"))
     full = lambda *shape: pl.BlockSpec(
@@ -238,9 +232,15 @@ def bottleneck_fwd_reference(x, w1, w2, w3, s1, b1, s2, b2, s3, b3):
 # Backward: one kernel, chain recomputed in VMEM from a 2-row x halo
 # --------------------------------------------------------------------------
 
+def _transposed(w1, w2, w3):
+    """(w1ᵀ, convT form of w2, w3ᵀ) — computed by XLA outside the
+    kernels and handed to the backward kernels as inputs."""
+    return w1.T, _transpose_weights(w2), w3.T
+
+
 def _bwd_kernel(height, x_c_ref, x_t_ref, x_b_ref, gy_c_ref, gy_t_ref,
-                gy_b_ref, w1_ref, w2_ref, w3_ref, s1_ref, b1_ref, s2_ref,
-                b2_ref, s3_ref, b3_ref, dx_ref, dw1_ref, dw2_ref, dw3_ref,
+                gy_b_ref, w1_ref, w2_ref, w3_ref, w1t_ref, w2t_ref, w3t_ref,
+                s1_ref, b1_ref, s2_ref, b2_ref, s3_ref, b3_ref, dx_ref, dw1_ref, dw2_ref, dw3_ref,
                 ds1_ref, db1_ref, ds2_ref, db2_ref, ds3_ref, db3_ref):
     bt, ht, wdt, c4 = x_c_ref.shape
     bi, hi = pl.program_id(0), pl.program_id(1)
@@ -248,6 +248,11 @@ def _bwd_kernel(height, x_c_ref, x_t_ref, x_b_ref, gy_c_ref, gy_t_ref,
     w1 = w1_ref[...].astype(jnp.float32)
     w2 = w2_ref[...].astype(jnp.float32)
     w3 = w3_ref[...].astype(jnp.float32)
+    # Transposed forms arrive as inputs (_transposed): the 3×3 flip is a
+    # ``rev``, which Mosaic does not lower.
+    w1t = w1t_ref[...].astype(jnp.float32)
+    w2t = w2t_ref[...].astype(jnp.float32)
+    w3t = w3t_ref[...].astype(jnp.float32)
     s1, b1 = s1_ref[...], b1_ref[...]
     s2, b2 = s2_ref[...], b2_ref[...]
     s3, b3 = s3_ref[...], b3_ref[...]
@@ -271,7 +276,7 @@ def _bwd_kernel(height, x_c_ref, x_t_ref, x_b_ref, gy_c_ref, gy_t_ref,
     p3_ext = jnp.maximum(m3_ext, 0.0)
 
     # dmid on the ±1 band (gy halo is zero-masked outside the image).
-    dp3 = jnp.dot(gy_ext.reshape(bt * (ht + 2) * wdt, c4), w3.T,
+    dp3 = jnp.dot(gy_ext.reshape(bt * (ht + 2) * wdt, c4), w3t,
                   preferred_element_type=jnp.float32).reshape(
                       bt, ht + 2, wdt, f)
     dm3 = jnp.where(m3_ext > 0, dp3, 0.0)
@@ -279,13 +284,13 @@ def _bwd_kernel(height, x_c_ref, x_t_ref, x_b_ref, gy_c_ref, gy_t_ref,
 
     # dp2 at center rows via the transposed 3×3 over the dmid band.
     dmid_p = jnp.pad(dmid_ext, ((0, 0), (0, 0), (1, 1), (0, 0)))
-    dp2 = _conv3x3_taps(dmid_p, _transpose_weights(w2), bt, ht, wdt, f)
+    dp2 = _conv3x3_taps(dmid_p, w2t, bt, ht, wdt, f)
     m2_c = m2[:, 2:2 + ht]
     dm2 = jnp.where(m2_c > 0, dp2, 0.0)
     dc1 = dm2 * s2
 
     # dx at center rows.
-    dp1 = jnp.dot(dc1.reshape(bt * ht * wdt, f), w1.T,
+    dp1 = jnp.dot(dc1.reshape(bt * ht * wdt, f), w1t,
                   preferred_element_type=jnp.float32).reshape(
                       bt, ht, wdt, c4)
     m1_c = m1[:, 2:2 + ht]
@@ -341,6 +346,7 @@ def _bwd_call(x, gy, w1, w2, w3, s1, b1, s2, b2, s3, b3, *,
         grid=grid,
         in_specs=[center, x_top2, x_bot2, center, gy_top, gy_bot,
                   full(c4, f), full(3, 3, f, f), full(f, c4),
+                  full(f, c4), full(3, 3, f, f), full(c4, f),
                   full(c4), full(c4), full(f), full(f), full(f), full(f)],
         out_specs=[center,
                    full(c4, f), full(3, 3, f, f), full(f, c4),
@@ -357,7 +363,8 @@ def _bwd_call(x, gy, w1, w2, w3, s1, b1, s2, b2, s3, b3, *,
                    jax.ShapeDtypeStruct((f,), f32)],
         interpret=interpret,
         **kwargs,
-    )(x, x, x, gy, gy, gy, w1, w2, w3, s1, b1, s2, b2, s3, b3)
+    )(x, x, x, gy, gy, gy, w1, w2, w3, *_transposed(w1, w2, w3),
+      s1, b1, s2, b2, s3, b3)
     return outs
 
 
@@ -602,17 +609,20 @@ def _train_bwd_calls(x, gy, w1, w2, w3, g1, be1, g2, be2, g3, be3,
     center, gy_top, gy_bot = _specs(bt, ht, wdt, c4, n_h)
     x_top2, x_bot2 = _specs2(bt, ht, wdt, c4, n_h)
 
-    # x (center, ±2 halo), gy (center, ±1 halo), 3 weights, 12 BN vectors
+    # x (center, ±2 halo), gy (center, ±1 halo), 3 weights and their
+    # transposed forms, 12 BN vectors
     base_in = ([center, x_top2, x_bot2, center, gy_top, gy_bot,
-                full(c4, f), full(3, 3, f, f), full(f, c4)]
+                full(c4, f), full(3, 3, f, f), full(f, c4),
+                full(f, c4), full(3, 3, f, f), full(c4, f)]
                + [full(c4)] * 4 + [full(f)] * 8)
-    base_ops = (x, x, x, gy, gy, gy, w1, w2, w3,
+    base_ops = (x, x, x, gy, gy, gy, w1, w2, w3, *_transposed(w1, w2, w3),
                 g1, be1, mu1, i1, g2, be2, mu2, i2, g3, be3, mu3, i3)
     fshape = jax.ShapeDtypeStruct((f,), f32)
     c4shape = jax.ShapeDtypeStruct((c4,), f32)
 
     def load(refs):
         (x_c, x_t, x_b, gy_c, gy_t, gy_b, w1_r, w2_r, w3_r,
+         w1t_r, w2t_r, w3t_r,
          g1_r, be1_r, mu1_r, i1_r, g2_r, be2_r, mu2_r, i2_r,
          g3_r, be3_r, mu3_r, i3_r) = refs
         hi = pl.program_id(1)
@@ -628,14 +638,14 @@ def _train_bwd_calls(x, gy, w1, w2, w3, g1, be1, g2, be2, g3, be3,
             w2_r[...].astype(f32), g1_r[...], be1_r[...], mu1_r[...],
             i1_r[...], g2_r[...], be2_r[...], mu2_r[...], i2_r[...],
             g3_r[...], be3_r[...], mu3_r[...], i3_r[...])
-        return (x_ext, gy_ext, rows1, w1_r[...].astype(f32),
-                w2_r[...].astype(f32), w3_r[...].astype(f32),
+        return (x_ext, gy_ext, rows1, w1t_r[...].astype(f32),
+                w2t_r[...].astype(f32), w3t_r[...].astype(f32),
                 g1_r[...], i1_r[...], g2_r[...], i2_r[...],
                 g3_r[...], i3_r[...], chain)
 
-    def _dm3_ext(gy_ext, m3_ext, w3v):
+    def _dm3_ext(gy_ext, m3_ext, w3tv):
         bte, hext, _, _ = gy_ext.shape
-        dp3 = jnp.dot(gy_ext.reshape(bte * hext * wdt, c4), w3v.T,
+        dp3 = jnp.dot(gy_ext.reshape(bte * hext * wdt, c4), w3tv,
                       preferred_element_type=f32).reshape(
                           bte, hext, wdt, f)
         return jnp.where(m3_ext > 0, dp3, 0.0)
@@ -643,10 +653,10 @@ def _train_bwd_calls(x, gy, w1, w2, w3, g1, be1, g2, be2, g3, be3,
     # -- pass 1: T3 sums + dw3 (all from center rows) ----------------------
     def pass1(*refs):
         t3a_ref, t3b_ref, dw3_ref = refs[-3:]
-        (x_ext, gy_ext, rows1, w1v, w2v, w3v, g1v, i1v, g2v, i2v, g3v,
+        (x_ext, gy_ext, rows1, w1tv, w2tv, w3tv, g1v, i1v, g2v, i2v, g3v,
          i3v, chain) = load(refs[:-3])
         (_, _, _, _, _, _, _, _, mhat_ext, m3_ext, p3_ext) = chain
-        dm3 = _dm3_ext(gy_ext, m3_ext, w3v)
+        dm3 = _dm3_ext(gy_ext, m3_ext, w3tv)
         dm3_c = dm3[:, 1:1 + ht]
         mhat_c = mhat_ext[:, 1:1 + ht]
         p3_c = p3_ext[:, 1:1 + ht]
@@ -666,9 +676,9 @@ def _train_bwd_calls(x, gy, w1, w2, w3, g1, be1, g2, be2, g3, be3,
         interpret=interpret, **kwargs,
     )(*base_ops)
 
-    def _dmid_ext(gy_ext, m3_ext, mhat_ext, rows1, w3v, g3v, i3v,
+    def _dmid_ext(gy_ext, m3_ext, mhat_ext, rows1, w3tv, g3v, i3v,
                   t3av, t3bv):
-        dm3 = _dm3_ext(gy_ext, m3_ext, w3v)
+        dm3 = _dm3_ext(gy_ext, m3_ext, w3tv)
         dmid = g3v * i3v * (dm3 - t3av / n - mhat_ext * (t3bv / n))
         # The correction sums are nonzero even where dm3 is zero — the
         # out-of-image halo rows must be re-masked or they pollute dp2.
@@ -678,14 +688,13 @@ def _train_bwd_calls(x, gy, w1, w2, w3, g1, be1, g2, be2, g3, be3,
     def pass2(*refs):
         t2a_ref, t2b_ref, dw2_ref = refs[-3:]
         t3a_r, t3b_r = refs[-5:-3]
-        (x_ext, gy_ext, rows1, w1v, w2v, w3v, g1v, i1v, g2v, i2v, g3v,
+        (x_ext, gy_ext, rows1, w1tv, w2tv, w3tv, g1v, i1v, g2v, i2v, g3v,
          i3v, chain) = load(refs[:-5])
         (_, _, _, c1, chat, m2, p2, _, mhat_ext, m3_ext, _) = chain
-        dmid = _dmid_ext(gy_ext, m3_ext, mhat_ext, rows1, w3v, g3v, i3v,
+        dmid = _dmid_ext(gy_ext, m3_ext, mhat_ext, rows1, w3tv, g3v, i3v,
                          t3a_r[...], t3b_r[...])
         dmid_p = jnp.pad(dmid, ((0, 0), (0, 0), (1, 1), (0, 0)))
-        dp2 = _conv3x3_taps(dmid_p, _transpose_weights(w2v), bt, ht,
-                            wdt, f)
+        dp2 = _conv3x3_taps(dmid_p, w2tv, bt, ht, wdt, f)
         m2_c = m2[:, 2:2 + ht]
         chat_c = chat[:, 2:2 + ht]
         dm2 = jnp.where(m2_c > 0, dp2, 0.0)
@@ -706,19 +715,18 @@ def _train_bwd_calls(x, gy, w1, w2, w3, g1, be1, g2, be2, g3, be3,
         interpret=interpret, **kwargs,
     )(*base_ops, t3a, t3b)
 
-    def _dm1_c(x_ext, gy_ext, rows1, chain, w1v, w2v, w3v, g2v, i2v,
+    def _dm1_c(x_ext, gy_ext, rows1, chain, w1tv, w2tv, w3tv, g2v, i2v,
                g3v, i3v, t3av, t3bv, t2av, t2bv):
         (x1hat, m1, p1, c1, chat, m2, p2, _, mhat_ext, m3_ext, _) = chain
-        dmid = _dmid_ext(gy_ext, m3_ext, mhat_ext, rows1, w3v, g3v, i3v,
+        dmid = _dmid_ext(gy_ext, m3_ext, mhat_ext, rows1, w3tv, g3v, i3v,
                          t3av, t3bv)
         dmid_p = jnp.pad(dmid, ((0, 0), (0, 0), (1, 1), (0, 0)))
-        dp2 = _conv3x3_taps(dmid_p, _transpose_weights(w2v), bt, ht,
-                            wdt, f)
+        dp2 = _conv3x3_taps(dmid_p, w2tv, bt, ht, wdt, f)
         m2_c = m2[:, 2:2 + ht]
         chat_c = chat[:, 2:2 + ht]
         dm2 = jnp.where(m2_c > 0, dp2, 0.0)
         dc1 = g2v * i2v * (dm2 - t2av / n - chat_c * (t2bv / n))
-        dp1 = jnp.dot(dc1.reshape(bt * ht * wdt, f), w1v.T,
+        dp1 = jnp.dot(dc1.reshape(bt * ht * wdt, f), w1tv,
                       preferred_element_type=f32).reshape(
                           bt, ht, wdt, c4)
         m1_c = m1[:, 2:2 + ht]
@@ -729,10 +737,10 @@ def _train_bwd_calls(x, gy, w1, w2, w3, g1, be1, g2, be2, g3, be3,
     def pass3(*refs):
         t1a_ref, t1b_ref, dw1_ref = refs[-3:]
         t3a_r, t3b_r, t2a_r, t2b_r = refs[-7:-3]
-        (x_ext, gy_ext, rows1, w1v, w2v, w3v, g1v, i1v, g2v, i2v, g3v,
+        (x_ext, gy_ext, rows1, w1tv, w2tv, w3tv, g1v, i1v, g2v, i2v, g3v,
          i3v, chain) = load(refs[:-7])
         dm1, dc1, x1hat_c, p1_c = _dm1_c(
-            x_ext, gy_ext, rows1, chain, w1v, w2v, w3v, g2v, i2v, g3v,
+            x_ext, gy_ext, rows1, chain, w1tv, w2tv, w3tv, g2v, i2v, g3v,
             i3v, t3a_r[...], t3b_r[...], t2a_r[...], t2b_r[...])
         dw1 = jnp.dot(p1_c.reshape(bt * ht * wdt, c4).T,
                       dc1.reshape(bt * ht * wdt, f),
@@ -754,10 +762,10 @@ def _train_bwd_calls(x, gy, w1, w2, w3, g1, be1, g2, be2, g3, be3,
     def pass4(*refs):
         dx_ref = refs[-1]
         t3a_r, t3b_r, t2a_r, t2b_r, t1a_r, t1b_r = refs[-7:-1]
-        (x_ext, gy_ext, rows1, w1v, w2v, w3v, g1v, i1v, g2v, i2v, g3v,
+        (x_ext, gy_ext, rows1, w1tv, w2tv, w3tv, g1v, i1v, g2v, i2v, g3v,
          i3v, chain) = load(refs[:-7])
         dm1, _, x1hat_c, _ = _dm1_c(
-            x_ext, gy_ext, rows1, chain, w1v, w2v, w3v, g2v, i2v, g3v,
+            x_ext, gy_ext, rows1, chain, w1tv, w2tv, w3tv, g2v, i2v, g3v,
             i3v, t3a_r[...], t3b_r[...], t2a_r[...], t2b_r[...])
         gy_c = gy_ext[:, 1:1 + ht]
         dx = gy_c + g1v * i1v * (

@@ -39,36 +39,22 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # TPU-only module; absent on pure-CPU installs of older jaxlibs
-    from jax.experimental.pallas import tpu as pltpu
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
+from jax.experimental.pallas import tpu as pltpu
 
 _LANE = 128
 _NEG = -1e30
 
 
 def is_tpu_backend() -> bool:
-    """True when the default backend drives TPU chips — including PJRT
-    plugins that register under a non-'tpu' platform name (e.g. tunneled
-    plugins) but expose a 'TPU vX' device_kind."""
-    try:
-        d = jax.devices()[0]
-        return d.platform == "tpu" or "tpu" in d.device_kind.lower()
-    except Exception:
-        return False
-
-
-_is_tpu = is_tpu_backend
+    """True when the default backend is ``tpu``. Decides, everywhere in
+    ops/, between compiling a kernel with Mosaic and running it under the
+    Pallas interpreter — so it must never guess: a backend that fails to
+    initialize raises here instead of quietly selecting the interpreter."""
+    return jax.default_backend() == "tpu"
 
 
 def _block_spec(shape):
-    if _VMEM is None:
-        return pl.BlockSpec(shape, lambda i: (i, 0))
-    return pl.BlockSpec(shape, lambda i: (i, 0), memory_space=_VMEM)
+    return pl.BlockSpec(shape, lambda i: (i, 0), memory_space=pltpu.VMEM)
 
 
 def _fwd_kernel(logits_ref, labels_ref, loss_ref):
@@ -171,7 +157,7 @@ def softmax_xent_per_example(logits: jnp.ndarray, labels: jnp.ndarray,
     multiple of 128 (with -1e30) and B to ``batch_tile`` (masked out).
     """
     if interpret is None:
-        interpret = not _is_tpu()
+        interpret = not is_tpu_backend()
     b, c = logits.shape
     c_pad = (-c) % _LANE
     b_tile = min(batch_tile, max(8, b),
@@ -253,18 +239,14 @@ def make_pallas_xent(mesh=None):
 
     from jax.sharding import PartitionSpec as P
 
-    from tpu_resnet.parallel import get_shard_map
-
-    shard_map, kwargs = get_shard_map()
-
     def mesh_xent(logits, labels, _mesh=mesh):
         # check_vma off: pallas_call's out_shape carries no vma annotation;
         # the body is per-example (no collectives), so the output's
         # data-axis variance is by construction.
-        per_ex = shard_map(
+        per_ex = jax.shard_map(
             softmax_xent_per_example, mesh=_mesh,
             in_specs=(P("data"), P("data")), out_specs=P("data"),
-            **kwargs,
+            check_vma=False,
         )(logits, labels)
         return jnp.mean(per_ex)
 

@@ -3,7 +3,6 @@ from tpu_resnet.parallel.mesh import (
     check_divisible,
     create_mesh,
     fit_mesh,
-    get_shard_map,
     local_batch_size,
     replicated,
     staged_batch_sharding,
@@ -21,7 +20,6 @@ __all__ = [
     "check_divisible",
     "create_mesh",
     "fit_mesh",
-    "get_shard_map",
     "local_batch_size",
     "replicated",
     "staged_batch_sharding",

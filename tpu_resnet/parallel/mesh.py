@@ -113,20 +113,3 @@ def check_divisible(global_batch: int, mesh: Mesh) -> None:
     if global_batch % n_data:
         raise ValueError(
             f"global batch {global_batch} not divisible by data axis {n_data}")
-
-
-def get_shard_map():
-    """(shard_map, replication-check-off kwargs) for the installed jax.
-
-    jax >= 0.7 exports ``shard_map`` at top level and spells the
-    replication check ``check_vma``; 0.4.x keeps it in
-    ``jax.experimental.shard_map`` as ``check_rep``. Every shard_map call
-    site that disables the check goes through here so the next API shift
-    is a one-file fix.
-    """
-    try:
-        from jax import shard_map
-        return shard_map, {"check_vma": False}
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
-        return shard_map, {"check_rep": False}

@@ -219,20 +219,12 @@ def fingerprint_lowered(lowered) -> str:
     from tpu_resnet.analysis.configmatrix import canonicalize
 
     parts = [canonicalize(lowered.as_text())]
-    try:
-        info = lowered.args_info
-        parts.append(repr([bool(i.donated)
-                           for i in jax.tree_util.tree_leaves(info)]))
-    except Exception:  # noqa: BLE001 - older jax without args_info
-        parts.append("no-args-info")
-    for attr in ("in_avals", "out_info"):
-        try:
-            tree = getattr(lowered, attr)
-            parts.append(repr([(tuple(x.shape), str(x.dtype),
-                                str(getattr(x, "sharding", None)))
-                               for x in jax.tree_util.tree_leaves(tree)]))
-        except Exception:  # noqa: BLE001 - attr varies across jax APIs
-            parts.append(f"no-{attr}")
+    parts.append(repr([bool(i.donated) for i in
+                       jax.tree_util.tree_leaves(lowered.args_info)]))
+    for tree in (lowered.in_avals, lowered.out_info):
+        parts.append(repr([(tuple(x.shape), str(x.dtype),
+                            str(getattr(x, "sharding", None)))
+                           for x in jax.tree_util.tree_leaves(tree)]))
     return hashlib.sha256("\n".join(parts).encode()).hexdigest()
 
 
@@ -346,6 +338,12 @@ class ExecutableCache:
         header = dict(env, format=_FORMAT, key=key,
                       fingerprint=fingerprint,
                       precondition=precondition,
+                      # The devices the program was compiled for: a load
+                      # must hand exactly these back, or jax defaults to
+                      # every device of the backend and a 1-device program
+                      # on an N-device host rejects its arguments.
+                      device_ids=[int(d.id) for d in compiled
+                                  .runtime_executable().local_devices()],
                       payload_sha256=hashlib.sha256(payload).hexdigest(),
                       payload_bytes=len(payload),
                       created_unix=round(time.time(), 3))
@@ -414,7 +412,8 @@ class ExecutableCache:
             return None
         return path, header, payload
 
-    def _deserialize(self, key: str, path: str, payload: bytes):
+    def _deserialize(self, key: str, path: str, header: dict,
+                     payload: bytes):
         with _loaded_lock:
             if path in _loaded_once:
                 # PR 1 hazard: this jaxlib segfaults on the SECOND
@@ -424,12 +423,15 @@ class ExecutableCache:
                          "deserialization (PR 1 hazard)", key)
                 return None
             _loaded_once.add(path)
+        import jax
         from jax.experimental import serialize_executable
 
         try:
+            by_id = {int(d.id): d for d in jax.devices()}
             ser, in_tree, out_tree = pickle.loads(payload)
             return serialize_executable.deserialize_and_load(
-                ser, in_tree, out_tree)
+                ser, in_tree, out_tree,
+                execution_devices=[by_id[i] for i in header["device_ids"]])
         except Exception as e:  # noqa: BLE001 - never crash on a cache
             self._evict(path, f"deserialization failed "
                               f"({type(e).__name__}: {e})")
@@ -448,7 +450,7 @@ class ExecutableCache:
         path, header, payload = found
         if not precondition or header.get("precondition") != precondition:
             return None  # not evicted: load_verified decides its fate
-        return self._deserialize(key, path, payload)
+        return self._deserialize(key, path, header, payload)
 
     def load_verified(self, key: str, fingerprint: str,
                       precondition: str = ""):
@@ -469,36 +471,32 @@ class ExecutableCache:
         if precondition and header.get("precondition") != precondition:
             header["precondition"] = precondition
             self._write(path, header, payload)
-        return self._deserialize(key, path, payload)
+        return self._deserialize(key, path, header, payload)
 
 
 # =============================================================== programs
 class _Program:
     """A registry-built program: the AOT executable (cached or freshly
-    compiled) with the plain jitted function as a lazy fallback — a call
-    whose concrete arguments don't match the compiled signature (an
-    unexpected batch shape, a layout surprise) pays one normal jit
-    compile instead of crashing, and can never produce a wrong result."""
+    compiled), with the plain jitted function for calls whose argument
+    SHAPES differ from the compiled signature (jax raises TypeError
+    before anything runs) — those pay one normal jit compile. Every
+    other rejection (devices, shardings, a loaded executable that
+    cannot run) raises: it means the cache handed back a program that
+    does not fit this process, which must be seen, not papered over."""
 
     def __init__(self, compiled, jitted, key: str):
         self._compiled = compiled
         self._jitted = jitted
         self.key = key
-        self._fell_back = False
 
     def __call__(self, *args):
-        if self._compiled is not None:
-            try:
-                return self._compiled(*args)
-            except (TypeError, ValueError) as e:
-                if not self._fell_back:
-                    self._fell_back = True
-                    log.warning(
-                        "program %s: AOT executable rejected the call "
-                        "(%s: %s) — falling back to jit dispatch",
-                        self.key, type(e).__name__, e)
-                self._compiled = None
-        return self._jitted(*args)
+        try:
+            return self._compiled(*args)
+        except TypeError as e:
+            log.warning("program %s: call does not match the compiled "
+                        "signature (%s) — jit dispatch for this call",
+                        self.key, e)
+            return self._jitted(*args)
 
 
 class ProgramRegistry:
@@ -586,10 +584,7 @@ class ProgramRegistry:
         at build time instead."""
         import jax
 
-        try:
-            info = lowered.args_info
-        except Exception:  # noqa: BLE001 - older jax without args_info
-            return
+        info = lowered.args_info
         args = info[0] if isinstance(info, tuple) and len(info) == 2 \
             and isinstance(info[1], dict) else info
         for i, arg in enumerate(args):
@@ -656,9 +651,9 @@ class ProgramRegistry:
         """Route one program through the registry: identity when the
         cache is off; else AOT-compile (or cache-load) over ``avals``
         and return a :class:`_Program`. Returns ``(program,
-        cache_hit)``. Any failure in the AOT/cache path degrades to the
-        plain jit object — the registry must never be the reason a run
-        dies.
+        cache_hit)``. A cache entry that cannot be read or loaded is
+        evicted and recompiled (a logged miss); a lowering or compile
+        error raises, exactly as it would under jit dispatch.
 
         Load order: precondition fast path (no re-trace) →
         fingerprint-verified path (fresh lowering; re-blesses or evicts
@@ -668,38 +663,26 @@ class ProgramRegistry:
         if self.cache is None:
             return jitted, False
         t0 = time.time()
-        try:
-            pre = self._precondition(avals)
-            if os.environ.get(CACHE_VERIFY_ENV, "0") != "1":
-                loaded = self.cache.load_fast(key, pre)
-                if loaded is not None:
-                    self._count(True)
-                    self._span(key, t0, hit=True, verified="precondition")
-                    return _Program(loaded, jitted, key), True
-            lowered = jitted.lower(*avals)
-            fp = fingerprint_lowered(lowered)
-            loaded = self.cache.load_verified(key, fp, precondition=pre)
+        pre = self._precondition(avals)
+        if os.environ.get(CACHE_VERIFY_ENV, "0") != "1":
+            loaded = self.cache.load_fast(key, pre)
             if loaded is not None:
                 self._count(True)
-                self._span(key, t0, hit=True, verified="fingerprint")
+                self._span(key, t0, hit=True, verified="precondition")
                 return _Program(loaded, jitted, key), True
-            compiled = lowered.compile()
-            self.assert_donation(lowered, key, donated_args)
-            self.cache.store(key, fp, pre, compiled)
-            self._count(False)
-            self._span(key, t0, hit=False)
-            return _Program(compiled, jitted, key), False
-        except DonationContractError:
-            raise  # a real program bug, never a cache degrade
-        except Exception as e:  # noqa: BLE001 - cache must degrade: a
-            # registry-side aval/sharding mistake (lower/compile raising
-            # ValueError included) must not kill a run that works with
-            # the cache off
-            log.warning("program registry: AOT/cache path failed for %s "
-                        "(%s: %s) — using plain jit dispatch",
-                        key, type(e).__name__, e)
-            self._count(False)
-            return jitted, False
+        lowered = jitted.lower(*avals)
+        fp = fingerprint_lowered(lowered)
+        loaded = self.cache.load_verified(key, fp, precondition=pre)
+        if loaded is not None:
+            self._count(True)
+            self._span(key, t0, hit=True, verified="fingerprint")
+            return _Program(loaded, jitted, key), True
+        compiled = lowered.compile()
+        self.assert_donation(lowered, key, donated_args)
+        self.cache.store(key, fp, pre, compiled)
+        self._count(False)
+        self._span(key, t0, hit=False)
+        return _Program(compiled, jitted, key), False
 
     def _span(self, key: str, t0: float, hit: bool,
               verified: str = "") -> None:
@@ -725,10 +708,9 @@ def state_avals(state):
                                        sharding=x.sharding), state)
 
 
-def _batch_dtype(cfg) -> str:
-    # ImageNet streams pre-processed floats; every other dataset feeds
-    # raw uint8 and augments on device — the account_train_step rule.
-    return "float32" if cfg.data.dataset == "imagenet" else "uint8"
+# Every dataset's host pipeline delivers raw uint8 images; the float
+# conversion and the augmentation are on-device, inside the step.
+BATCH_DTYPE = "uint8"
 
 
 def wrap_train_step(registry: ProgramRegistry, step_fn, avals,
@@ -749,7 +731,7 @@ def wrap_train_step(registry: ProgramRegistry, step_fn, avals,
         registry.key("train") + ("" if donate_state else "|nodon"),
         step_fn,
         (avals,
-         jax.ShapeDtypeStruct((gb, size, size, 3), _batch_dtype(cfg),
+         jax.ShapeDtypeStruct((gb, size, size, 3), BATCH_DTYPE,
                               sharding=bsh),
          jax.ShapeDtypeStruct((gb,), "int32", sharding=bsh)),
         donated_args=(0,) if donate_state else ())
@@ -774,7 +756,7 @@ def staged_chunk_hook(registry: ProgramRegistry, avals, rows: int,
     size = cfg.data.resolved_image_size
     ssh = parallel.staged_batch_sharding(registry.mesh)
     gi = jax.ShapeDtypeStruct((rows, gb, size, size, 3),
-                              _batch_dtype(cfg), sharding=ssh)
+                              BATCH_DTYPE, sharding=ssh)
     gl = jax.ShapeDtypeStruct((rows, gb), "int32", sharding=ssh)
     off = jax.ShapeDtypeStruct((), "int32")
     base_key = registry.key("chunk") + ("" if donate_state else "|nodon")

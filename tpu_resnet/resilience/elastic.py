@@ -252,8 +252,7 @@ def colocation_admission(required_bytes: int, devices=None,
     1. live ``device.memory_stats()`` (``obs.memory.sample_device_memory``)
        — in-use and limit come from the device itself;
     2. no stats (CPU rehearsal, older plugins): the per-chip capacity
-       table / ``TPU_RESNET_HBM_BYTES`` override supplies the limit and
-       in-use is taken as 0;
+       table supplies the limit and in-use is taken as 0;
     3. no limit from anywhere: admit with an explicit "not arbitrated"
        reason — an un-gauged host must not hard-deny capacity it cannot
        measure, but the verdict says so.
@@ -280,8 +279,7 @@ def colocation_admission(required_bytes: int, devices=None,
     if not limit:
         verdict.update(admit=True,
                        reason="no device memory limit known — admission "
-                              "not arbitrated (set TPU_RESNET_HBM_BYTES "
-                              "to arbitrate on this backend)")
+                              "not arbitrated on this backend")
         return verdict
     headroom = int(limit * (1.0 - reserve_frac)) - in_use
     verdict["headroom_bytes"] = headroom

@@ -44,7 +44,8 @@ import numpy as np
 
 from tpu_resnet.config import RunConfig
 from tpu_resnet.obs import memory as memory_obs
-from tpu_resnet.obs.manifest import read_run_id
+from tpu_resnet.obs.manifest import (device_record, library_versions,
+                                     read_run_id)
 from tpu_resnet.obs.server import (SERVE_GAUGES, SERVE_HISTOGRAMS,
                                    TelemetryRegistry)
 from tpu_resnet.obs.spans import SpanTracer, TailSampler
@@ -180,6 +181,13 @@ class PredictServer:
         self._closed = False
         self._oom_reported = False
         self._weight_bytes = 0  # published at start(); backend-derived
+        # What this replica runs on, as JAX reports it — a latency read
+        # off this server is only a device number if /info says so.
+        # Resolved once: the router's probe loop reads /info.
+        import jax
+
+        self._runs_on = {"devices": device_record(jax.devices()),
+                         "versions": library_versions()}
 
     def note_oom(self, error, phase: str = "infer") -> None:
         """OOM forensics for the serving process (obs/memory.py): the
@@ -465,6 +473,7 @@ class PredictServer:
             "image_shape": list(self.image_shape),
             "num_classes": int(self.backend.num_classes),
             "buckets": list(self.buckets),
+            **self._runs_on,
             # Arm identity (the router A/B scenario and fleetmon label
             # arms from here — no out-of-band config): numeric compute
             # dtype, quant mode, and the calibration digest the

@@ -23,10 +23,7 @@ def forward_cost_analysis(model, image_size: int, batch: int = 1):
 
     lowered = jax.jit(fwd).lower(variables, x)
     compiled = lowered.compile()
-    cost = compiled.cost_analysis()
-    if isinstance(cost, list):  # older jax returns [dict]
-        cost = cost[0]
-    return cost or {}
+    return compiled.cost_analysis() or {}
 
 
 def layer_params(params) -> list:

@@ -308,22 +308,17 @@ def train(cfg: RunConfig, mesh=None, metrics: Optional[MetricsWriter] = None,
         # enables Pallas only where it measured a win; the xent "auto"
         # probe runs inside make_train_step. Host code before the first
         # dispatch — it rides in the compile window, never a throughput
-        # interval. Failures degrade to the XLA paths, never kill
-        # training.
+        # interval. A kernel that fails to compile raises out of here:
+        # only a measured loss sends a site to XLA.
         from tpu_resnet import ops
         if cfg.model.fused_epilogue == "auto" and ops.is_tpu_backend():
             t_probe = time.time()
-            try:
-                kernel_batch = (cfg.train.global_batch_size
-                                // mesh.shape["data"] if per_replica_bn
-                                else cfg.train.global_batch_size)
-                ops.probe_model_epilogues(cfg, kernel_batch)
-                spans.record("autotune_probe", t_probe, time.time(),
-                             op="epilogue")
-            except Exception as e:  # noqa: BLE001 - probe must not kill
-                log.warning("epilogue autotune probe failed (%s: %s) — "
-                            "all epilogue sites stay on XLA",
-                            type(e).__name__, e)
+            kernel_batch = (cfg.train.global_batch_size
+                            // mesh.shape["data"] if per_replica_bn
+                            else cfg.train.global_batch_size)
+            ops.probe_model_epilogues(cfg, kernel_batch)
+            spans.record("autotune_probe", t_probe, time.time(),
+                         op="epilogue")
         # The xent kernel always sees the PER-DEVICE batch (shard_mapped
         # over 'data' under auto-jit, the local shard under per-replica
         # BN, the full batch only on one device) — probe at that shape,
@@ -511,6 +506,14 @@ def train(cfg: RunConfig, mesh=None, metrics: Optional[MetricsWriter] = None,
                 spans.record("compile", now - compile_s, now,
                              seconds=round(compile_s, 3), step=start_step)
                 telemetry.set("compile_seconds", compile_s)
+                # What the memory and comms ledgers account: the program
+                # this run's input edge dispatches in steady state — on
+                # the staged streaming path a chunk of steps_per_call
+                # steps clipped to the superbatch.
+                staged_run = not resident and stage > 1
+                staged_chunk_steps = (
+                    min(stage, max(1, cfg.train.steps_per_call))
+                    if staged_run else 1)
                 if cfg.train.mfu_accounting:
                     # One abstract trace + HLO cost pass (no second XLA
                     # compile); charged to the compile window, not to any
@@ -546,14 +549,12 @@ def train(cfg: RunConfig, mesh=None, metrics: Optional[MetricsWriter] = None,
                         # step (the resident path's epoch-buffer chunk
                         # is approximated by its single-step twin —
                         # labeled so on the entry).
-                        staged_run = not resident and stage > 1
                         entry = obs.memory.account_train_step(
                             cfg, mesh, state, base_step,
                             per_replica_bn=per_replica_bn,
                             partitioner=partitioner,
                             stage_rows=stage if staged_run else 1,
-                            chunk_steps=(max(1, cfg.train.steps_per_call)
-                                         if staged_run else 1),
+                            chunk_steps=staged_chunk_steps,
                             variant=("single-step (resident epoch-buffer "
                                      "program approximated)" if resident
                                      else "single-step"),
@@ -584,14 +585,12 @@ def train(cfg: RunConfig, mesh=None, metrics: Optional[MetricsWriter] = None,
                     # absent.
                     t_comm = time.time()
                     try:
-                        staged_run = not resident and stage > 1
                         entry = obs.comms.account_train_step(
                             cfg, mesh, state, base_step,
                             per_replica_bn=per_replica_bn,
                             partitioner=partitioner,
                             stage_rows=stage if staged_run else 1,
-                            chunk_steps=(max(1, cfg.train.steps_per_call)
-                                         if staged_run else 1),
+                            chunk_steps=staged_chunk_steps,
                             variant=("single-step (resident epoch-buffer "
                                      "program approximated)" if resident
                                      else "single-step"),

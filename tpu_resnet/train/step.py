@@ -245,11 +245,8 @@ def per_replica_shard_map(fn, mesh: Mesh, in_specs):
     Outputs (state, metrics) are replicated by construction — every shard
     applies the same pmean-ed grads/stats — hence ``out_specs=P()`` with
     VMA checking off (the explicit pmeans are the replication proof)."""
-    from tpu_resnet.parallel import get_shard_map
-
-    shard_map, kwargs = get_shard_map()
-    return shard_map(fn, mesh=mesh, in_specs=in_specs,
-                     out_specs=(P(), P()), **kwargs)
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=(P(), P()), check_vma=False)
 
 
 def shard_step(step_fn, mesh: Mesh, donate_state: bool = True,
@@ -274,9 +271,12 @@ def shard_step(step_fn, mesh: Mesh, donate_state: bool = True,
     if per_replica_bn:
         step_fn = per_replica_shard_map(
             step_fn, mesh, in_specs=(P(), P("data"), P("data")))
+    state_sh = state_sharding if state_sharding is not None else repl
+    # out_shardings pins the state to the layout it went in with (see
+    # device_data.staged_chunk_jit): the next dispatch takes it back.
     return jax.jit(
         step_fn,
-        in_shardings=(state_sharding if state_sharding is not None
-                      else repl, data, data),
+        in_shardings=(state_sh, data, data),
+        out_shardings=(state_sh, repl),
         donate_argnums=(0,) if donate_state else (),
     )
