@@ -55,10 +55,29 @@ def test_mfu_math():
     assert mfu.mfu(197e12, "TPU v5e", 2) == pytest.approx(0.5)
     assert mfu.mfu(1e12, "cpu", 8) is None      # unknown chip
     assert mfu.mfu(None, "TPU v5e", 1) is None  # unknown flops
+    # a multiply-add is two FLOPs: 3 x 2 x 4.09 G an image
     assert mfu.analytic_resnet50_flops(128) == pytest.approx(
-        3 * 4.09e9 * 128)
+        3 * 2 * 4.09e9 * 128)
     assert mfu.analytic_resnet50_flops(128, image=112) == pytest.approx(
-        3 * 4.09e9 * 128 / 4)
+        3 * 2 * 4.09e9 * 128 / 4)
+
+
+def test_analytic_flops_agree_with_the_count_from_shapes():
+    """The loop's fallback against the benchmark's count of the same model
+    from its shapes (23.69 GFLOP an image): within a few percent, not the
+    factor of two a multiply-add counted as one FLOP made."""
+    import json
+    import os
+
+    from benchmarks.lib import flops
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "configs",
+                           "imagenet_rn50.json")) as f:
+        arch = json.load(f)["model"]
+    counted = flops.train_flops_per_image(arch)
+    assert counted == pytest.approx(23.69e9, rel=1e-3)
+    assert mfu.analytic_resnet50_flops(1) == pytest.approx(counted, rel=0.05)
 
 
 # -------------------------------------------------------- registry keys

@@ -29,24 +29,120 @@ from tpu_resnet.obs.spans import load_spans
 
 # ------------------------------------------------------------- breakdown
 
+class _Slow:
+    """A pytree leaf whose ``block_until_ready`` takes a while: stands in
+    for a chunk the device has not finished."""
+
+    def __init__(self, seconds):
+        self.seconds = seconds
+
+    def block_until_ready(self):
+        time.sleep(self.seconds)
+        return self
+
+
 def test_breakdown_interval_decomposition():
     bd = obs.StepBreakdown()
     with bd.data_wait():
         time.sleep(0.03)
-    with bd.dispatch():
+    with bd.dispatch(step=0, steps=10):
         time.sleep(0.01)
-    bd.add_device_sample(0.5, steps=10)
+    waited = bd.sample_device({"loss": _Slow(0.05)}, steps=10, step=10)
     out = bd.interval()
     assert out["data_wait_sec"] >= 0.02
     assert 0.0 < out["data_wait_frac"] <= 1.0
     assert out["dispatch_sec"] >= 0.005
-    assert out["device_sync_sec"] == 0.5
-    assert out["device_step_sec_sampled"] == pytest.approx(0.05)
+    assert out["device_sync_sec"] == pytest.approx(waited, abs=1e-6)
+    assert waited >= 0.05
+    assert "device_step_sec_sampled" not in out  # gone: step_device_ms
     assert "compile_seconds" not in out  # never known in this run
-    # interval() drains: the next interval starts from zero
+    # The new keys: the three parts add up to the interval's wall time,
+    # which ends where the device wait ended.
+    (iv,) = [s for s in bd.spans if s[0] == "train.interval"]
+    wall = (iv[2] - iv[1]) / 1e9
+    assert iv[2] == [s for s in bd.spans
+                     if s[0] == "train.device_wait"][0][2]
+    assert (out["loop_host_sec"] + out["data_wait_sec"]
+            + out["device_sync_sec"]) == pytest.approx(wall, abs=5e-6)
+    assert out["loop_host_sec"] >= 0.01  # the dispatch is the loop's own
+    assert out["boundary_stall_sec"] == 0.0  # no drain came before
+    # Counters with no reader are not drained (PERF.md section 3): what
+    # the interval dispatched stands on its span.
+    assert iv[6] == 10
+    assert not {"synced_at_ns", "dispatches", "steps_dispatched",
+                "boundaries", "checkpoints", "compiles"} & out.keys()
+    # interval() drains: the next interval starts from zero, at the sync
+    time.sleep(0.02)
+    with bd.dispatch(step=10, steps=10):
+        pass
     out2 = bd.interval()
     assert out2["data_wait_sec"] == 0.0
     assert "device_sync_sec" not in out2
+    assert out2["dispatch_sec"] >= 0.0
+    # ... and the stall the sync caused is reported with the interval in
+    # which it ended: from the drain's end to the next dispatch's return.
+    assert out2["boundary_stall_sec"] >= 0.02
+
+
+def test_breakdown_ring_is_bounded_and_spans_nest():
+    bd = obs.StepBreakdown(ring=16)
+    for step in range(40):
+        with bd.data_wait(step):
+            pass
+        with bd.dispatch(step, 1):
+            with bd.phase("train.epoch_shuffle", step):
+                pass
+    assert len(bd.spans) == 16
+    bd.sample_device({"loss": np.zeros(())}, steps=40, step=40)
+    spans = list(bd.spans)
+    by_id = {s[3]: s for s in spans}
+    interval = spans[-1]
+    assert interval[0] == "train.interval" and interval[4] is None
+    assert interval[5] == 40 and interval[6] == 40  # step, steps
+    for name, start, end, sid, parent, step, steps, _ in spans[:-1]:
+        assert start <= end
+        if name == "train.epoch_shuffle":  # beneath its dispatch
+            p = by_id[parent]
+            assert p[0] == "train.dispatch" and p[1] <= start and end <= p[2]
+        else:  # every phase of the interval names it
+            assert parent == interval[3]
+            assert interval[1] <= start and end <= interval[2]
+    # one clock: the monotonic one
+    assert abs(spans[-1][2] - time.monotonic_ns()) < 5e9
+
+
+def test_breakdown_compile_listener_feeds_the_current_recorder(tmp_path):
+    import jax
+    import jax.numpy as jnp
+
+    a, b, c = jnp.ones(7), jnp.ones(5), jnp.ones(3)  # compiled up front
+    bd = obs.StepBreakdown()
+    with bd.dispatch(step=7, steps=3):
+        jax.jit(lambda x: x * 3 + 1)(a).block_until_ready()
+    assert bd.compile_load_sec > 0
+    out = bd.interval()
+    assert out["compile_load_sec"] == round(bd.compile_load_sec, 4) > 0
+    tracer = obs.SpanTracer(str(tmp_path))
+    bd.flush(tracer, ring=True)
+    bd.flush(tracer, ring=True)  # everything was taken out: no doubles
+    bd.close()
+    other = obs.StepBreakdown()  # the listener now feeds this one
+    jax.jit(lambda x: x * 5 - 2)(b).block_until_ready()
+    other.close()
+    heard = other.compile_load_sec
+    jax.jit(lambda x: x * 7 - 3)(c).block_until_ready()
+    tracer.close()
+    assert other.interval()["compile_load_sec"] == round(heard, 4) > 0
+    spans = load_spans(str(tmp_path / "events.jsonl"))
+    assert len({s["id"] for s in spans}) == len(spans)
+    compiles = [s for s in spans if s["span"] == "compile"]
+    (dispatch,) = [s for s in spans if s["span"] == "train.dispatch"]
+    assert compiles and all(
+        c["step"] == 7 and c["steps"] == 3 and c["parent"] == dispatch["id"]
+        and c["during"] == "train.dispatch" and c["cache_hit"] is False
+        and c["seconds"] > 0 and c["program"] for c in compiles)
+    assert all("mono_ns" in s for s in spans)
+    assert dispatch["mono_ns"] <= compiles[0]["mono_ns"]
 
 
 def test_breakdown_compile_excludes_data_wait():
@@ -73,13 +169,21 @@ def test_span_tracer_records_and_loads(tmp_path):
     tr = obs.SpanTracer(str(tmp_path))
     with tr.span("eval_pass", step=5) as attrs:
         attrs["precision"] = 0.5
+    first_id = attrs["id"]
     tr.event("marker", step=7)
+    tr.record("child", time.time(), time.time(), parent=first_id)
     tr.close()
     tr.close()  # idempotent
     tr.record("after_close", 0.0, 1.0)  # no-op, not a crash
     spans = load_spans(str(tmp_path / "events.jsonl"))
-    assert [s["span"] for s in spans] == ["eval_pass", "marker"]
+    assert [s["span"] for s in spans] == ["eval_pass", "marker", "child"]
     assert spans[0]["precision"] == 0.5
+    # id, parent and the monotonic twin of ``start``
+    assert len({s["id"] for s in spans}) == 3
+    assert "parent" not in spans[0] and spans[2]["parent"] == spans[0]["id"]
+    for s in spans:
+        assert abs((s["mono_ns"] - time.monotonic_ns()) / 1e9
+                   - (s["start"] - time.time())) < 0.05
     assert spans[0]["end"] >= spans[0]["start"]
     assert spans[0]["duration_sec"] >= 0
     assert spans[1]["duration_sec"] == 0  # instantaneous marker

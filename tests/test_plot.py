@@ -22,7 +22,7 @@ def test_plot_and_csv(tmp_path):
          "steps_per_sec": 0.3, "images_per_sec_per_chip": 2.5,
          # step-time breakdown channel (tpu_resnet/obs/breakdown.py)
          "data_wait_frac": 0.1 + s / 1000, "compile_seconds": 3.2,
-         "device_step_sec_sampled": 0.05}
+         "loop_host_sec": 0.05}
         for s in (20, 40, 60, 80, 100)])
     _write_jsonl(str(run / "eval" / "metrics.jsonl"), [
         {"step": 50, "Precision": 0.4, "Best_Precision": 0.4,

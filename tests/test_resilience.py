@@ -135,6 +135,38 @@ def test_watchdog_fires_dumps_stacks_and_recovers(tmp_path):
     assert kinds == ["watchdog_stall", "watchdog_recovered"]
 
 
+def test_watchdog_hang_dump_writes_the_loops_phase_ring(tmp_path):
+    """A stall calls ``on_stall`` once: the train loop hands it the
+    recorder's flush, so a hang leaves the last phases in events.jsonl
+    (and the closer chain, coming later, writes none of them twice)."""
+    from tpu_resnet.obs.spans import load_spans
+
+    tr = obs.SpanTracer(str(tmp_path))
+    bd = obs.StepBreakdown()
+    with bd.data_wait(3):
+        pass
+    with bd.dispatch(3, 2):
+        pass
+    wd = HangWatchdog(0.1, str(tmp_path), spans=tr, poll_sec=0.03,
+                      on_stall=lambda: bd.flush(tr, ring=True))
+    wd.start()
+    try:
+        wd.progress(5)
+        deadline = time.time() + 5
+        while wd.stalls == 0 and time.time() < deadline:
+            time.sleep(0.02)
+        assert wd.stalls == 1
+    finally:
+        wd.close()
+    bd.close()
+    bd.flush(tr, ring=True)  # the closer chain: nothing is left to write
+    tr.close()
+    kinds = [s["span"] for s in load_spans(str(tmp_path / "events.jsonl"))]
+    assert kinds.count("train.data_wait") == 1
+    assert kinds.count("train.dispatch") == 1
+    assert "watchdog_stall" in kinds
+
+
 def test_watchdog_maybe_start_disabled():
     assert HangWatchdog.maybe_start(0, "/nonexistent") is None
     assert HangWatchdog.maybe_start(-1, "/nonexistent") is None

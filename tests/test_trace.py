@@ -4,9 +4,11 @@ re-export — on synthetic artifacts and on a real tiny train run."""
 
 import json
 import os
+import time
 
 import pytest
 
+from tpu_resnet.obs.spans import load_spans
 from tpu_resnet.obs.trace import (
     SERVE_EVENTS_FILE,
     build_trace,
@@ -366,3 +368,178 @@ def test_request_lanes_and_fleet_lane(run_dir):
     assert any(e["name"] == "fleet_burn_alert" for e in events)
     # deterministic re-export with request lanes present
     assert build_trace(run_dir) == trace
+
+
+# ------------------------------------------------- the loop's span tree
+# One tiny train() (tpu_resnet/obs/breakdown.py is its recorder) read by
+# several tests: chunks of 4, 2, 4 and 2 steps against log boundaries at 6
+# and 12, so that the 2-step program compiles in the middle of the run.
+
+@pytest.fixture(scope="module")
+def span_run(tmp_path_factory):
+    from tpu_resnet.config import load_config
+    from tpu_resnet.obs.spans import SpanTracer
+    from tpu_resnet.train import train
+
+    cfg = load_config("smoke")
+    cfg.model.name = "mlp"
+    cfg.data.device_resident = "on"
+    cfg.train.train_dir = str(tmp_path_factory.mktemp("span_run"))
+    cfg.train.train_steps = 12
+    cfg.train.checkpoint_every = 12
+    cfg.train.log_every = 6
+    cfg.train.summary_every = 6
+    cfg.train.image_summary_every = 0
+    cfg.train.steps_per_call = 4
+    cfg.train.global_batch_size = 16
+    writes = []  # the monotonic instant of every line events.jsonl got
+    record = SpanTracer.record
+
+    def noting(self, kind, *args, **kwargs):
+        writes.append((time.monotonic_ns(), kind))
+        return record(self, kind, *args, **kwargs)
+
+    SpanTracer.record = noting
+    try:
+        train(cfg)
+    finally:
+        SpanTracer.record = record
+    spans = load_spans(os.path.join(cfg.train.train_dir, "events.jsonl"))
+    with open(os.path.join(cfg.train.train_dir, "metrics.jsonl")) as f:
+        records = [json.loads(line) for line in f]
+    return {"dir": cfg.train.train_dir, "spans": spans, "records": records,
+            "writes": writes}
+
+
+def _end_ns(span):
+    return span["mono_ns"] + round(span["duration_sec"] * 1e9)
+
+
+def test_startup_is_a_span_tree(span_run):
+    spans = span_run["spans"]
+    assert all("mono_ns" in s and "id" in s for s in spans)
+    assert len({s["id"] for s in spans}) == len(spans)
+    (startup,) = [s for s in spans if s["span"] == "train.startup"]
+    assert "parent" not in startup
+    children = {s["span"]: s for s in spans
+                if s.get("parent") == startup["id"]}
+    assert {"train.init_state", "train.autotune_probe", "train.load_split",
+            "train.dataset_to_device", "train.first_dispatch"} <= \
+        set(children)
+    for child in children.values():
+        assert startup["mono_ns"] <= child["mono_ns"]
+        assert _end_ns(child) <= _end_ns(startup) + 1000
+    first = children["train.first_dispatch"]
+    assert _end_ns(first) == pytest.approx(_end_ns(startup), abs=1000)
+    (legacy,) = [s for s in spans if s["span"] == "compile"
+                 and "program" not in s]  # the first-dispatch wall time
+    assert legacy["parent"] == first["id"] and legacy["step"] == 0
+    under = [s for s in spans if s.get("parent") == first["id"]]
+    assert {"train.dispatch", "train.device_wait", "compile"} <= \
+        {s["span"] for s in under}
+    # the run constant on every record is this span's length
+    for rec in span_run["records"]:
+        assert rec["startup_sec"] == pytest.approx(startup["duration_sec"],
+                                                   abs=1e-3)
+
+
+def test_one_compile_span_per_program_with_its_step(span_run):
+    spans = span_run["spans"]
+    compiles = [s for s in spans if s["span"] == "compile"
+                and "program" in s]
+    (legacy,) = [s for s in spans if s["span"] == "compile"
+                 and "program" not in s]
+    chunks = [s for s in compiles if s["program"] == "jit(chunk)"]
+    # the 4-step program in the first dispatch, beneath its compile span;
+    # the 2-step program at step 4, in the middle of training
+    assert [(c["step"], c["steps"]) for c in chunks] == [(0, 4), (4, 2)]
+    assert chunks[0]["parent"] == legacy["id"]
+    assert chunks[1]["during"] == "train.dispatch"
+    (dispatch,) = [s for s in spans if s["id"] == chunks[1]["parent"]]
+    assert dispatch["span"] == "train.dispatch" and dispatch["step"] == 4
+    assert all(c["cache_hit"] is False and c["seconds"] > 0
+               for c in compiles)
+    # every compile is in the running total the records carry, the one
+    # at step 4 from the first on (what the save at step 12 compiles
+    # comes after the last record)
+    intervals = [s for s in spans if s["span"] == "train.interval"]
+    for rec, interval in zip(span_run["records"], intervals):
+        before = [c for c in compiles if c["mono_ns"] < _end_ns(interval)]
+        assert chunks[1] in before
+        assert rec["compile_load_sec"] == pytest.approx(
+            sum(c["seconds"] for c in before), abs=1e-3 * len(before))
+        assert rec["compile_load_sec"] < rec["startup_sec"] + 60
+
+
+def test_phase_spans_nest_and_add_up(span_run):
+    spans, records = span_run["spans"], span_run["records"]
+    intervals = [s for s in spans if s["span"] == "train.interval"]
+    assert [s["step"] for s in intervals] == [6, 12]
+    assert [s["from_step"] for s in intervals] == [4, 6]
+    for interval, rec in zip(intervals, records):
+        kids = sorted((s for s in spans
+                       if s.get("parent") == interval["id"]),
+                      key=lambda s: s["mono_ns"])
+        assert {"train.dispatch", "train.device_wait"} <= \
+            {s["span"] for s in kids}
+        assert all(s["span"].startswith("train.") or s["span"] == "compile"
+                   for s in kids)
+        phases = [s for s in kids if s["span"] != "compile"]
+        assert phases[-1]["span"] == "train.device_wait"  # it ends it
+        edge = interval["mono_ns"]
+        for s in phases:  # in order, inside the parent, never overlapping
+            assert s["mono_ns"] >= edge - 1000
+            edge = _end_ns(s)
+        assert edge <= _end_ns(interval) + 1000
+        assert rec["step"] == interval["step"]
+        # the three parts of the interval add up to it within a millisecond
+        assert (rec["loop_host_sec"] + rec["data_wait_sec"]
+                + rec["device_sync_sec"]) == pytest.approx(
+                    interval["duration_sec"], abs=1e-3)
+        assert rec["dispatch_sec"] <= rec["loop_host_sec"]
+        assert rec["boundary_stall_sec"] > 0
+        # counters nothing reads are not on the record (PERF.md section 3)
+        assert not {"synced_at_ns", "dispatches", "steps_dispatched",
+                    "boundaries", "checkpoints", "compiles"} & rec.keys()
+    assert [s["steps"] for s in intervals] == [2, 6]
+    assert [sum(s["span"] == "train.dispatch" and s["parent"] == i["id"]
+                for s in spans) for i in intervals] == [1, 2]
+    # the boundary's own work belongs to the interval it opens
+    second = intervals[1]["id"]
+    assert {"train.log_fetch", "train.log_write"} <= {
+        s["span"] for s in spans if s.get("parent") == second}
+    # a checkpoint is a phase too, the last boundary's after its sync
+    assert any(s["span"] == "train.checkpoint" and s["step"] == 12
+               for s in spans)
+
+
+def test_nothing_is_written_between_two_dispatches(span_run):
+    spans, writes = span_run["spans"], span_run["writes"]
+    dispatches = sorted((s for s in spans if s["span"] == "train.dispatch"),
+                        key=lambda s: s["mono_ns"])
+    assert [d["step"] for d in dispatches] == [0, 4, 6, 10]
+    # steps 6..10 and 10..12 are dispatched back to back: no boundary and
+    # no checkpoint lies between, so no line may have been written there
+    lo, hi = _end_ns(dispatches[2]), dispatches[3]["mono_ns"]
+    assert hi > lo
+    assert [kind for t, kind in writes if lo < t < hi] == []
+    # the iteration phases reach the file only in the closer chain
+    last_dispatch = _end_ns(dispatches[3])
+    assert all(t > last_dispatch for t, kind in writes
+               if kind in ("train.dispatch", "train.interval",
+                           "train.data_wait", "train.device_wait"))
+
+
+def test_trace_export_draws_the_loop_phases(span_run):
+    from tpu_resnet.obs.trace import _TID_PHASES
+
+    _, trace = export_trace(span_run["dir"])
+    assert validate_trace(trace) == []
+    lane = [e for e in trace["traceEvents"]
+            if e.get("tid") == _TID_PHASES and e["ph"] in ("X", "i")]
+    assert {"train.startup", "train.interval", "train.dispatch",
+            "train.device_wait", "train.log_write"} <= \
+        {e["name"] for e in lane}
+    assert all(e["name"].startswith("train.") for e in lane)
+    assert any(e["ph"] == "M" and e["args"]["name"] == "loop-phases"
+               for e in trace["traceEvents"])

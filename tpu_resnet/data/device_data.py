@@ -81,7 +81,7 @@ class DeviceDataset:
         self.batch = batch
         self.steps_per_epoch = n // batch
         self.seed = seed
-        self._epoch = None
+        self.epoch = None  # the epoch the buffer holds
 
         repl = NamedSharding(mesh, P())
         # Epoch buffer: (steps_per_epoch, batch, ...) with the *batch* axis
@@ -93,11 +93,12 @@ class DeviceDataset:
         spe, b = self.steps_per_epoch, batch
 
         def shuffle(flat_i, flat_l, epoch):
-            rng = jax.random.fold_in(jax.random.PRNGKey(seed), epoch)
-            order = jax.random.permutation(rng, n)[: spe * b]
-            ib = jnp.take(flat_i, order, axis=0).reshape(
-                (spe, b) + flat_i.shape[1:])
-            lb = jnp.take(flat_l, order, axis=0).reshape((spe, b))
+            with jax.named_scope("epoch_shuffle"):
+                rng = jax.random.fold_in(jax.random.PRNGKey(seed), epoch)
+                order = jax.random.permutation(rng, n)[: spe * b]
+                ib = jnp.take(flat_i, order, axis=0).reshape(
+                    (spe, b) + flat_i.shape[1:])
+                lb = jnp.take(flat_l, order, axis=0).reshape((spe, b))
             return ib, lb
 
         self._shuffle = jax.jit(
@@ -115,10 +116,10 @@ class DeviceDataset:
     def ensure_epoch(self, epoch: int) -> None:
         """(Re)build the shuffled epoch buffer if ``epoch`` changed — one
         on-device permutation per epoch (~ms), zero host traffic."""
-        if epoch != self._epoch:
+        if epoch != self.epoch:
             self.images, self.labels = self._shuffle(
                 self._flat_images, self._flat_labels, epoch)
-            self._epoch = epoch
+            self.epoch = epoch
 
 
 def make_chunk_fn(base_step: Callable, c: int):
@@ -130,8 +131,9 @@ def make_chunk_fn(base_step: Callable, c: int):
     (tpu_resnet/analysis/configmatrix.py ``staged-chunk`` entries)."""
 
     def chunk(state, gi, gl, off):
-        imgs = jax.lax.dynamic_slice_in_dim(gi, off, c, axis=0)
-        labs = jax.lax.dynamic_slice_in_dim(gl, off, c, axis=0)
+        with jax.named_scope("batch_cut"):
+            imgs = jax.lax.dynamic_slice_in_dim(gi, off, c, axis=0)
+            labs = jax.lax.dynamic_slice_in_dim(gl, off, c, axis=0)
         if c == 1:
             return base_step(state, imgs[0], labs[0])
 

@@ -9,13 +9,16 @@ training report (arXiv:2204.06514) both treat per-step timing
 decomposition and pod-level health as *prerequisites* for scaling; this
 package provides them as first-class artifacts of every run:
 
-``breakdown``   StepBreakdown — where step time goes between log
-                boundaries: ``data_wait`` (blocked in ``next(data_iter)``),
-                ``dispatch`` (enqueueing the jitted chunk) and a sampled
-                device backlog, plus one-shot ``compile_seconds``.
+``breakdown``   StepBreakdown — the train loop's span recorder: every
+                phase of an iteration (``train.data_wait``,
+                ``train.dispatch``, ``train.device_wait``, the log
+                boundary, checkpoints), start-up and every compile as
+                spans with ``id`` and ``parent`` on the monotonic clock,
+                and per-interval sums for ``metrics.jsonl``.
 ``spans``       SpanTracer — structured event spans (run, compile,
                 checkpoint save/restore, eval pass, profiler trace
-                window) appended to ``events.jsonl``.
+                window) appended to ``events.jsonl``, wall-clocked and
+                with ``mono_ns`` beside.
 ``manifest``    ``manifest.json`` — resolved config, mesh topology,
                 device kinds, process count, package version, git rev —
                 written once at startup by the primary process.
@@ -68,7 +71,7 @@ from tpu_resnet.obs.server import (
     read_telemetry_port,
     scrape,
 )
-from tpu_resnet.obs.spans import SpanTracer
+from tpu_resnet.obs.spans import SpanTracer, next_span_id
 
 __all__ = [
     "Histogram",
@@ -82,6 +85,7 @@ __all__ = [
     "histogram_quantile",
     "memory",
     "mfu",
+    "next_span_id",
     "parse_histograms",
     "parse_prometheus",
     "read_run_id",

@@ -1,128 +1,378 @@
-"""Step-time breakdown — where wall time goes between log boundaries.
+"""Step-time breakdown — the train loop's span recorder.
 
 The reference could only *infer* step timing from LoggingTensorHook
 timestamps (reference resnet_cifar_train.py:282-287); whether a run was
-input-bound, dispatch-bound or device-bound was guesswork. The tracker
-decomposes every logged interval into the three host-observable places
-time is spent:
+input-bound, dispatch-bound or device-bound was guesswork. The recorder
+keeps what the loop thread was doing as spans on ONE clock,
+``time.monotonic_ns()`` — the clock ``tools/profiling.StepTracer`` and the
+benchmark mark their captures with — so any span can be laid on any
+capture by one subtraction.
 
-``data_wait``      blocked in ``next(data_iter)`` — the input edge can't
-                   keep up (the reference bounded this with 16 queue
-                   threads and never measured it, cifar_input.py:99-100).
-``dispatch``       enqueueing the jitted chunk (host→device command path;
-                   dominated by tracing only on the first call).
-``device_sync``    a *sampled* block at the interval boundary: time the
-                   host waits for the device to drain the chunks it
-                   dispatched. With async dispatch this is the device-
-                   compute backlog — ≈0 when the host is the bottleneck,
-                   ≈ device step time × interval steps when the device is.
+Every phase of a loop iteration is a span ``(name, start_ns, end_ns, id,
+parent, step, steps, attrs)`` appended to a bounded in-memory ring; the
+dispatch path never touches a file. The ring is written to
+``events.jsonl`` by the loop's closer chain and by the watchdog's hang
+dump. The parent of an iteration's phases is the ``train.interval`` span
+that runs from one synced log boundary to the next (it is cut where the
+boundary's ``block_until_ready`` returns, the one instant the device is
+known to be drained):
 
-Sampling happens only at the loop's existing log/summary boundaries (the
-chunk clipper already ends a fused dispatch exactly there), so the
-breakdown never changes fusion behavior. The first dispatch — which pays
-XLA tracing + compilation — is reported separately as ``compile_seconds``
-and excluded from the first interval so throughput numbers are never
-polluted by compile time.
+``train.data_wait``    blocked in ``next(data_iter)`` — the input edge
+                       can't keep up.
+``train.dispatch``     enqueueing the jitted chunk (host→device command
+                       path), with ``train.epoch_shuffle`` beneath it when
+                       the resident buffer is rebuilt.
+``train.device_wait``  the boundary's ``block_until_ready``: the device-
+                       compute backlog — ≈0 when the host is the
+                       bottleneck, ≈ device step time × interval steps
+                       when the device is. It ends the interval.
+``train.log_fetch``    ``device_get`` of the chunk's metrics.
+``train.log_write``    from the fetch's end to the end of
+                       ``metrics.write``.
+``train.checkpoint``   the call into ``ckpt.save`` and the commit wait.
+
+A span's self time is its length less its children's; the interval's self
+time is the loop's own bookkeeping. The same context managers enter
+``jax.profiler.TraceAnnotation`` (and ``StepTraceAnnotation`` around each
+dispatch), a flag test while no profiler session is active, so a capture
+shows the phases on its host plane.
+
+Start-up phases and compiles are spans too, but durable ones: they go to a
+pending list (``keep=True``) that the loop writes out at its log
+boundaries, never to the ring a long run overwrites. A process-wide
+``jax.monitoring`` listener turns every backend compile or cache load into
+a ``compile`` span that names the step, the program and the phase it fell
+in; a recompile in the middle of a run is one line of ``events.jsonl``.
+
+``interval()`` drains the sums of the interval that just closed into the
+run's ``metrics.jsonl`` record. The first dispatch — which pays XLA
+tracing + compilation — is reported separately as ``compile_seconds`` and
+excluded from the first interval so throughput numbers are never polluted
+by compile time.
 """
 
 from __future__ import annotations
 
+import collections
+import threading
 import time
 from contextlib import contextmanager
-from typing import Dict, Optional
+from typing import Dict, List, Optional, Tuple
+
+from tpu_resnet.obs.spans import next_span_id
+
+# name, start_ns, end_ns, id, parent, step, steps, attrs
+Span = Tuple[str, int, int, int, Optional[int], Optional[int], int,
+             Optional[dict]]
+
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+
+# The recorder the process-wide jax.monitoring listeners feed. train() runs
+# many times in one test process and jax keeps a listener for good, so the
+# listeners are registered once and look the recorder up here.
+_current: Optional["StepBreakdown"] = None
+_listening = False
+
+
+def _on_event(event: str, **_) -> None:
+    rec = _current
+    if rec is not None and event == _CACHE_HIT_EVENT:
+        rec._on_cache_hit()
+
+
+def _on_duration(event: str, duration: float, **kw) -> None:
+    rec = _current
+    if rec is not None and event == _COMPILE_EVENT:
+        rec._on_compile(duration, kw.get("fun_name"))
+
+
+class _Open:
+    """A phase that has begun: what ``end`` needs to finish its span."""
+
+    __slots__ = ("name", "id", "parent", "step", "steps", "keep", "attrs",
+                 "start_ns", "annotation")
 
 
 class StepBreakdown:
-    """Accumulates per-interval timings; ``interval()`` drains them as a
-    metrics dict merged into the run's ``metrics.jsonl`` records."""
+    """Records the loop thread's phases as spans and drains per-interval
+    sums; see the module docstring. ``ring`` bounds the spans kept in
+    memory."""
 
-    def __init__(self):
+    def __init__(self, ring: int = 4096):
+        import jax
+
+        global _current, _listening
+        self._annotate = jax.profiler.TraceAnnotation
+        self._annotate_step = jax.profiler.StepTraceAnnotation
+        self._block = jax.block_until_ready
+        self.spans: collections.deque = collections.deque(maxlen=ring)
         self.compile_seconds: Optional[float] = None
-        self._data_wait = 0.0
-        self._dispatch = 0.0
-        self._sync: Optional[float] = None       # last boundary sample
-        self._sync_steps = 0
-        self._interval_start = time.perf_counter()
+        self.startup_sec: Optional[float] = None
+        self.compile_load_sec = 0.0
+        self.compile_parent: Optional[int] = None
+        self._pending: List[Span] = []
+        self._stack: List[_Open] = []
+        self._thread = threading.get_ident()
+        self._cache_hit = False
+        self._closed: Optional[Dict[str, float]] = None
+        self._open_interval(time.monotonic_ns(), None)
+        self._stall_from: Optional[int] = None
+        if not _listening:
+            jax.monitoring.register_event_listener(_on_event)
+            jax.monitoring.register_event_duration_secs_listener(
+                _on_duration)
+            _listening = True
+        _current = self
 
-    # ------------------------------------------------------------ timers
+    # ------------------------------------------------------------- spans
+    def begin(self, name: str, step: Optional[int] = None, steps: int = 0,
+              keep: bool = False, **attrs) -> _Open:
+        """Open a phase beneath the innermost open one (else the current
+        interval). ``keep`` makes the span durable: it goes to the pending
+        list instead of the ring."""
+        op = _Open()
+        op.name, op.id, op.step, op.steps = name, next_span_id(), step, steps
+        if self._stack:
+            op.parent = self._stack[-1].id
+        else:  # a durable span is no part of an interval
+            op.parent = None if keep else self._interval_id
+        op.keep, op.attrs = keep, attrs or None
+        op.annotation = (self._annotate(name) if step is None
+                         else self._annotate(name, step=step))
+        op.annotation.__enter__()
+        self._stack.append(op)
+        op.start_ns = time.monotonic_ns()
+        return op
+
+    def end(self, op: _Open, **attrs) -> int:
+        """Close ``op``, and first anything still open inside it; returns
+        the instant on the monotonic clock."""
+        end_ns = time.monotonic_ns()
+        if attrs:
+            op.attrs = {**(op.attrs or {}), **attrs}
+        while self._stack:
+            top = self._stack.pop()
+            top.annotation.__exit__(None, None, None)
+            (self._pending if top.keep else self.spans).append((
+                top.name, top.start_ns, end_ns, top.id, top.parent,
+                top.step, top.steps, top.attrs))
+            if top is op:
+                break
+        return end_ns
+
     @contextmanager
-    def data_wait(self):
+    def phase(self, name: str, step: Optional[int] = None, steps: int = 0,
+              keep: bool = False, **attrs):
+        """Time a block as a span; yields the open phase (its ``id`` is
+        the ``parent`` of what a caller records beside the recorder)."""
+        op = self.begin(name, step, steps, keep, **attrs)
+        try:
+            yield op
+        finally:
+            self.end(op)
+
+    @contextmanager
+    def data_wait(self, step: Optional[int] = None):
         """Time a blocking ``next(data_iter)``."""
-        t0 = time.perf_counter()
+        op = self.begin("train.data_wait", step)
         try:
             yield
         finally:
-            self._data_wait += time.perf_counter() - t0
+            self._data_wait_ns += self.end(op) - op.start_ns
 
     @contextmanager
-    def dispatch(self):
-        """Time the (normally async) dispatch of a chunk."""
-        t0 = time.perf_counter()
+    def dispatch(self, step: Optional[int] = None, steps: int = 0):
+        """Time the (normally async) dispatch of a chunk of ``steps``
+        steps that begins at ``step``."""
+        step_annotation = self._annotate_step(
+            "train_chunk", step_num=0 if step is None else step)
+        step_annotation.__enter__()
+        op = self.begin("train.dispatch", step, steps)
         try:
             yield
         finally:
-            self._dispatch += time.perf_counter() - t0
+            end_ns = self.end(op)
+            step_annotation.__exit__(None, None, None)
+            self._dispatch_ns += end_ns - op.start_ns
+            self._steps_dispatched += steps
+            if self._stall_from is not None:
+                # The device had nothing queued since the last drain; this
+                # return is the first moment it has work again.
+                self._stall_ns += end_ns - self._stall_from
+                self._stall_from = None
+
+    def sample_device(self, sync, steps: int,
+                      step: Optional[int] = None) -> float:
+        """Block on the newest chunk's result at a log boundary
+        (``train.device_wait``) and close the interval where the wait
+        ends. ``steps`` is the number of steps dispatched since the last
+        full sync. Returns the wait in seconds."""
+        op = self.begin("train.device_wait", step, steps)
+        self._block(sync)
+        end_ns = self.end(op)
+        self._device_wait_ns = waited_ns = end_ns - op.start_ns
+        self._closed = self._close_interval(end_ns, step)
+        self._stall_from = end_ns
+        return waited_ns / 1e9
 
     def first_dispatch_done(self, sync) -> float:
         """Call right after the first dispatch of the run returns: blocks
         until the chunk is ready and records ``compile_seconds`` — the
         first-dispatch wall time (jit trace + XLA compile + the first
-        chunk's device run). Resets the interval clock so the first logged
-        interval excludes compile entirely (the throughput meter is
-        re-primed at the same point)."""
-        import jax
-
-        jax.block_until_ready(sync)
-        # Everything since construction minus time blocked on input: the
-        # dispatch call (trace + compile) plus the first chunk's device run.
-        self.compile_seconds = (time.perf_counter() - self._interval_start
-                                - self._data_wait)
+        chunk's device run). Start-up ends here: every phase still open is
+        closed, the outermost one's length is ``startup_sec``, and the
+        first real interval opens, so the first logged interval excludes
+        compile entirely (the throughput meter is re-primed at the same
+        point)."""
+        op = self.begin("train.device_wait")
+        self._block(sync)
+        end_ns = self.end(op)
+        # Everything since the interval clock was last reset minus time
+        # blocked on input: the dispatch call (trace + compile) plus the
+        # first chunk's device run.
+        self.compile_seconds = (end_ns - self._interval_start
+                                - self._data_wait_ns) / 1e9
+        self.compile_parent = None
+        if self._stack:
+            root = self._stack[0]
+            self.startup_sec = (self.end(root) - root.start_ns) / 1e9
         self.reset_interval()
         return self.compile_seconds
 
-    def add_device_sample(self, seconds: float, steps: int) -> None:
-        """Record an externally-timed boundary sync (bench harness path)."""
-        self._sync = seconds
-        self._sync_steps = max(1, steps)
+    # ---------------------------------------------------------- compiles
+    def _on_cache_hit(self) -> None:
+        if threading.get_ident() == self._thread:
+            self._cache_hit = True
 
-    def sample_device(self, sync, steps: int) -> float:
-        """Block on the newest chunk's result at an interval boundary and
-        record the wait — the sampled device-compute backlog. ``steps`` is
-        the number of steps dispatched since the last full sync."""
-        import jax
+    def _on_compile(self, seconds: float, program: Optional[str]) -> None:
+        """One backend compile or persistent-cache load on the loop's
+        thread (other threads' — an eval sidecar's — are theirs)."""
+        if threading.get_ident() != self._thread:
+            return
+        end_ns = time.monotonic_ns()
+        hit, self._cache_hit = self._cache_hit, False
+        top = self._stack[-1] if self._stack else None
+        parent = self.compile_parent
+        if parent is None:
+            parent = top.id if top is not None else self._interval_id
+        self.compile_load_sec += seconds
+        self._pending.append((
+            "compile", end_ns - int(seconds * 1e9), end_ns, next_span_id(),
+            parent, top.step if top is not None else None,
+            top.steps if top is not None else 0,
+            {"seconds": round(seconds, 4), "program": program,
+             "cache_hit": hit,
+             "during": top.name if top is not None else None}))
 
-        t0 = time.perf_counter()
-        jax.block_until_ready(sync)
-        dt = time.perf_counter() - t0
-        self.add_device_sample(dt, steps)
-        return dt
+    # ----------------------------------------------------------- writing
+    def flush(self, tracer, ring: bool = False) -> None:
+        """Write the durable spans (start-up phases, compiles) to
+        ``tracer`` (an ``obs.SpanTracer``) and, with ``ring``, the phase
+        ring too. Each span is taken out as it is written, so the closer
+        chain and the watchdog's hang dump never write one twice."""
+        pending, self._pending = self._pending, []
+        for span in pending:
+            _write(tracer, span)
+        while ring and self.spans:
+            try:
+                span = self.spans.popleft()
+            except IndexError:  # the other writer took the last one
+                break
+            _write(tracer, span)
+
+    def close(self) -> None:
+        """End what is still open (a start-up that raised) and stop
+        receiving compile events (idempotent)."""
+        global _current
+        if self._stack:
+            self.end(self._stack[0])
+        if _current is self:
+            _current = None
 
     # ---------------------------------------------------------- reporting
-    def reset_interval(self) -> None:
-        self._data_wait = 0.0
-        self._dispatch = 0.0
-        self._sync = None
-        self._sync_steps = 0
-        self._interval_start = time.perf_counter()
+    def _open_interval(self, now_ns: int, step: Optional[int]) -> None:
+        # An interval opened inside a phase (start-up) is no interval of
+        # the loop: it gets no span, and its phases hang under that phase.
+        self._interval_id = None if self._stack else next_span_id()
+        self._interval_start = now_ns
+        self._interval_step = step
+        self._data_wait_ns = self._dispatch_ns = self._stall_ns = 0
+        self._device_wait_ns: Optional[int] = None
+        self._steps_dispatched = 0
+
+    def _close_interval(self, end_ns: int,
+                        step: Optional[int]) -> Dict[str, float]:
+        """The sums of the interval that ends at ``end_ns``, its
+        ``train.interval`` span into the ring, and the next one opened."""
+        wall_ns = max(end_ns - self._interval_start, 1)
+        wait_ns = self._device_wait_ns or 0
+        out = {
+            "data_wait_sec": round(self._data_wait_ns / 1e9, 6),
+            "data_wait_frac": round(
+                min(self._data_wait_ns / wall_ns, 1.0), 6),
+            "dispatch_sec": round(self._dispatch_ns / 1e9, 6),
+            "boundary_stall_sec": round(self._stall_ns / 1e9, 6),
+            "loop_host_sec": round(
+                (wall_ns - self._data_wait_ns - wait_ns) / 1e9, 6),
+        }
+        if self._device_wait_ns is not None:
+            out["device_sync_sec"] = round(wait_ns / 1e9, 6)
+        self._interval_span(end_ns, step)
+        self._open_interval(end_ns, step)
+        return out
+
+    def _interval_span(self, end_ns: int, step: Optional[int],
+                       **attrs) -> None:
+        if self._interval_id is not None:
+            self.spans.append((
+                "train.interval", self._interval_start, end_ns,
+                self._interval_id, None, step, self._steps_dispatched,
+                dict(attrs, from_step=self._interval_step)))
+
+    def reset_interval(self, step: Optional[int] = None) -> None:
+        """Drop what the open interval has summed and start one now (after
+        the first dispatch, a ledger's compile, a rollback). The dropped
+        stretch keeps its ``train.interval`` span, marked ``reset``, where
+        it had one, so its phases keep their parent."""
+        now_ns = time.monotonic_ns()
+        if self._data_wait_ns or self._dispatch_ns:
+            self._interval_span(now_ns, step, reset=True)
+        self._open_interval(now_ns, step)
+        self._closed = None
+        self._stall_from = now_ns
 
     def interval(self) -> Dict[str, float]:
-        """Drain the interval accumulators into a metrics dict.
+        """The sums of the interval the last ``sample_device`` closed (or,
+        where none was taken, of the one open now, which this closes).
 
-        Always contains ``data_wait_sec``/``data_wait_frac``/
-        ``dispatch_sec``; ``device_sync_sec``/``device_step_sec_sampled``
-        when a boundary sample was taken; ``compile_seconds`` (a run
-        constant — the first-dispatch wall time) once it is known."""
-        wall = max(time.perf_counter() - self._interval_start, 1e-9)
-        out = {
-            "data_wait_sec": round(self._data_wait, 6),
-            "data_wait_frac": round(min(self._data_wait / wall, 1.0), 6),
-            "dispatch_sec": round(self._dispatch, 6),
-        }
-        if self._sync is not None:
-            out["device_sync_sec"] = round(self._sync, 6)
-            out["device_step_sec_sampled"] = round(
-                self._sync / self._sync_steps, 6)
+        Always ``data_wait_sec``/``data_wait_frac``/``dispatch_sec``,
+        ``boundary_stall_sec`` (from the previous drain's end to the
+        return of the first dispatch after it: the idle the loop's own
+        sync causes) and ``loop_host_sec`` (wall time less ``data_wait``
+        and ``device_wait``: what the loop thread itself held);
+        ``device_sync_sec`` when a boundary sample was taken;
+        the run constants ``compile_seconds`` (first-dispatch wall time),
+        ``startup_sec`` and ``compile_load_sec`` (every backend compile or
+        cache load so far) once known."""
+        out, self._closed = self._closed, None
+        if out is None:
+            out = self._close_interval(time.monotonic_ns(), None)
         if self.compile_seconds is not None:
             out["compile_seconds"] = round(self.compile_seconds, 4)
-        self.reset_interval()
+        if self.startup_sec is not None:
+            out["startup_sec"] = round(self.startup_sec, 4)
+        out["compile_load_sec"] = round(self.compile_load_sec, 4)
         return out
+
+
+def _write(tracer, span: Span) -> None:
+    name, start_ns, end_ns, sid, parent, step, steps, attrs = span
+    fields = dict(attrs or {}, id=sid)
+    if parent is not None:
+        fields["parent"] = parent
+    if step is not None:
+        fields["step"] = step
+    if steps:
+        fields["steps"] = steps
+    tracer.record_ns(name, start_ns, end_ns, **fields)

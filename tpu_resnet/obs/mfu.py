@@ -89,10 +89,13 @@ def lowered_flops(jit_fn, *args) -> Optional[float]:
 
 
 def analytic_resnet50_flops(batch: int, image: int = 224) -> float:
-    """Analytic fallback: ResNet-50 forward ≈ 4.09 GFLOPs per 224² image
-    (He et al.); training ≈ 3× forward (fwd + 2×bwd). Scaled by pixel
-    area for other resolutions. GLOBAL per-step FLOPs for ``batch``."""
-    return 3 * 4.09e9 * batch * (image / 224.0) ** 2
+    """Analytic fallback: ResNet-50 forward ≈ 4.09 G multiply-adds per
+    224² image (He et al.), two FLOPs each; training ≈ 3× forward (fwd +
+    2×bwd): 24.5 GFLOP an image, 3.6% over the count from shapes that
+    leaves out the taps on the padding (benchmarks/lib/flops.py: 23.69).
+    Scaled by pixel area for other resolutions. GLOBAL per-step FLOPs for
+    ``batch``."""
+    return 3 * 2 * 4.09e9 * batch * (image / 224.0) ** 2
 
 
 def mfu(model_flops_per_sec: Optional[float], device_kind: str,

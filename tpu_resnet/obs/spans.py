@@ -6,13 +6,17 @@ existed only as interleaved log lines across per-task files (SURVEY.md
 tracer appends one JSON object per span to ``<dir>/events.jsonl``:
 
     {"span": "checkpoint_save", "start": <wall>, "end": <wall>,
-     "duration_sec": 0.041, "step": 3000, "async": true}
+     "duration_sec": 0.041, "mono_ns": <monotonic>, "id": 17,
+     "parent": 3, "step": 3000, "async": true}
 
 ``start``/``end`` are wall-clock (``time.time()``) so spans from
-different hosts/processes can be laid on one timeline. Span kinds written
-by the framework: ``run`` (whole training loop), ``compile`` (first
-dispatch), ``checkpoint_save`` / ``checkpoint_restore``, ``eval_pass``,
-``profiler_trace`` (the jax.profiler window). The writer is append-only,
+different hosts/processes can be laid on one timeline. ``mono_ns`` is the
+same instant as ``start`` on ``time.monotonic_ns()``, the one clock every
+in-process recorder shares (obs/breakdown.py, tools/profiling.py, the
+benchmark's capture marks): ``mono_ns - session_zero_mono_ns`` of a
+``profiler_trace`` span puts any span on that capture's timeline. ``id``
+is unique in the process; ``parent`` names the span that encloses this
+one. docs/OBSERVABILITY.md lists the kinds. The writer is append-only,
 line-buffered, idempotent on double-``close()`` and a no-op after close —
 shutdown races (daemon threads, atexit, sidecars) can never turn
 telemetry into a crash.
@@ -20,6 +24,7 @@ telemetry into a crash.
 
 from __future__ import annotations
 
+import itertools
 import json
 import logging
 import os
@@ -29,6 +34,15 @@ from contextlib import contextmanager
 from typing import Optional
 
 log = logging.getLogger("tpu_resnet")
+
+_ids = itertools.count(1)
+
+
+def next_span_id() -> int:
+    """A span ``id`` unique in this process (``itertools.count`` is
+    atomic under the interpreter lock). Allocated ahead of ``record`` by
+    a caller whose children must name it as ``parent`` before it ends."""
+    return next(_ids)
 
 
 class SpanTracer:
@@ -43,6 +57,9 @@ class SpanTracer:
         self.enabled = enabled
         self.run_id = run_id
         self._pid = os.getpid()
+        # One reading of both clocks: every record is converted through
+        # it, so two records of one tracer keep their distance on either.
+        self._wall0, self._mono0 = time.time(), time.monotonic_ns()
         self._f = None
         if not enabled:
             return
@@ -50,18 +67,33 @@ class SpanTracer:
         self._f = open(os.path.join(directory, filename), "a", buffering=1)
 
     def record(self, kind: str, start: float, end: float, **attrs) -> None:
-        """Append one finished span. Safe after ``close()`` (no-op)."""
+        """Append one finished span given in wall-clock seconds. Safe
+        after ``close()`` (no-op). ``id`` and ``mono_ns`` are filled in
+        where the caller passes none; ``parent`` only where it does."""
         if self._f is None:
             return
         rec = {"span": kind, "start": round(start, 6), "end": round(end, 6),
-               "duration_sec": round(end - start, 6), "pid": self._pid}
+               "duration_sec": round(end - start, 6), "pid": self._pid,
+               "mono_ns": self._mono0 + int((start - self._wall0) * 1e9)}
         if self.run_id is not None:
             rec["run_id"] = self.run_id
         rec.update(attrs)
+        if rec.get("id") is None:
+            rec["id"] = next_span_id()
+        if rec.get("parent") is None:
+            rec.pop("parent", None)
         try:
             self._f.write(json.dumps(rec) + "\n")
         except ValueError:  # closed underneath us in a shutdown race
             self._f = None
+
+    def record_ns(self, kind: str, start_ns: int, end_ns: int,
+                  **attrs) -> None:
+        """Append a span taken on ``time.monotonic_ns()`` (the breakdown's
+        ring, a compile): ``mono_ns`` is exact, wall times are derived."""
+        start = self._wall0 + (start_ns - self._mono0) / 1e9
+        self.record(kind, start, start + (end_ns - start_ns) / 1e9,
+                    mono_ns=int(start_ns), **attrs)
 
     def event(self, kind: str, **attrs) -> None:
         """Instantaneous marker (zero-duration span)."""
@@ -71,16 +103,19 @@ class SpanTracer:
     @contextmanager
     def span(self, kind: str, **attrs):
         """Time a block as a span. Yields the attrs dict so the body can
-        attach results (e.g. ``a["precision"] = p``); an exception is
-        recorded on the span and re-raised."""
-        t0 = time.time()
+        attach results (e.g. ``a["precision"] = p``) and read the span's
+        ``id`` for its children; an exception is recorded on the span and
+        re-raised."""
+        t0, t0_ns = time.time(), time.monotonic_ns()
+        attrs.setdefault("id", next_span_id())
         try:
             yield attrs
         except BaseException as e:
             attrs.setdefault("error", f"{type(e).__name__}: {e}"[:200])
             raise
         finally:
-            self.record(kind, t0, time.time(), **attrs)
+            self.record(kind, t0, t0 + (time.monotonic_ns() - t0_ns) / 1e9,
+                        mono_ns=t0_ns, **attrs)
 
     def close(self) -> None:
         if self._f is not None:

@@ -20,7 +20,12 @@ Lanes (Chrome trace "processes"/"threads"):
   threads derived from ``metrics.jsonl`` — the step-time breakdown
   (data_wait_frac, steps_per_sec, mfu, model_flops_per_sec) and the
   data-engine ring (occupancy, decode rate). Logged intervals render as
-  ``train_interval`` slices carrying the full breakdown in args.
+  ``train_interval`` slices carrying the full breakdown in args. The
+  loop's own spans (``train.*``: start-up and its phases, and the phases
+  of the last iterations the recorder's ring held: ``train.interval`` with
+  ``train.data_wait`` / ``train.dispatch`` / ``train.device_wait`` /
+  ``train.log_fetch`` / ``train.log_write`` / ``train.checkpoint``
+  beneath) render nested on a ``loop-phases`` thread of the same lane.
 - **eval sidecar** (``eval/events.jsonl``): eval_pass/restore spans. An
   in-process sidecar (train_and_eval) shares the trainer's pid and shows
   up as another thread of the same process — which is the truth.
@@ -53,11 +58,12 @@ Lanes (Chrome trace "processes"/"threads"):
   a step window (tools/profiling.py StepTracer,
   ``train.profile_steps``) merged in as per-device lanes. The profiler's
   own Chrome-trace export (``profile/plugins/profile/<ts>/*.trace.json
-  [.gz]``) uses a timebase relative to its session start; the exporter
-  re-anchors it on the wall clock of the trainer's ``profiler_trace``
-  span — the host span that wrapped the capture — so XLA device/compile
-  activity lands in true host time next to the dispatch spans that
-  caused it, closing the host↔device attribution gap. Python-tracer
+  [.gz]``) counts from the ENTRY into ``start_trace``; the exporter
+  re-anchors it on the ``start`` of the trainer's ``profiler_trace``
+  span, which StepTracer stamps at that entry (not at the return, which
+  comes ``start_trace_sec`` later) — so XLA device/compile activity lands
+  in true host time next to the dispatch spans that caused it, closing
+  the host↔device attribution gap. Python-tracer
   events (``$``-prefixed) are dropped: the host-side story already lives
   on the trainer lane as spans.
 
@@ -104,6 +110,10 @@ _H2D_SPAN = "h2d_transfer"
 # at log boundaries, rendered as their own lane so HBM pressure lines up
 # against the spans (compile, checkpoint, eval) that move it.
 _TID_MEMORY = 5
+# The loop's own spans (obs/breakdown.py): start-up and the recorder's
+# ring of iteration phases, nested by containment on their own thread.
+_TID_PHASES = 6
+_PHASE_PREFIX = "train."
 # Merged jax.profiler lanes keep their own pid space well away from the
 # host lanes (real host pids are ~1e3-1e6; profiler pids are small ints
 # that would collide with the synthetic fallbacks).
@@ -136,7 +146,8 @@ _COUNTER_KEYS = (
 _INTERVAL_ARG_KEYS = (
     "loss", "precision", "learning_rate", "steps_per_sec",
     "images_per_sec", "data_wait_sec", "data_wait_frac", "dispatch_sec",
-    "device_sync_sec", "device_step_sec_sampled", "compile_seconds",
+    "device_sync_sec", "boundary_stall_sec", "loop_host_sec",
+    "compile_seconds", "startup_sec", "compile_load_sec",
     "model_flops_per_sec", "mfu", "train_step_ms_p50", "train_step_ms_p95",
     "train_step_ms_p99", "data_ring_occupancy",
     "data_decode_images_per_sec", "h2d_bytes_per_sec",
@@ -162,8 +173,11 @@ def _span_events(spans: List[dict], source: str, base: float,
         if end < start:
             continue
         name = str(s.get("span", "span"))
-        tid = (_TID_H2D if source == "train" and name == _H2D_SPAN
-               else _TID_SPANS[source])
+        tid = _TID_SPANS[source]
+        if source == "train" and name == _H2D_SPAN:
+            tid = _TID_H2D
+        elif source == "train" and name.startswith(_PHASE_PREFIX):
+            tid = _TID_PHASES
         # Fleet sources (serve replicas sharing one serve_events.jsonl,
         # the router): each writer pid keeps its OWN lane so a rolling
         # drain renders as N replica lanes + a router lane, not one
@@ -350,10 +364,11 @@ def _device_trace_events(train_dir: str, train_spans: List[dict],
     """Merge the newest profiler capture as per-device lanes. Returns
     ``(events, info)`` where ``info`` lands in trace metadata.
 
-    Timebase: profiler ``ts`` is microseconds since its session start.
-    The trainer's ``profiler_trace`` span wraps exactly that session
-    (StepTracer records it start_trace→stop_trace), so its wall-clock
-    ``start`` re-anchors the capture; without the span (a capture taken
+    Timebase: profiler ``ts`` is microseconds since the entry into
+    ``start_trace``. The trainer's ``profiler_trace`` span starts at that
+    entry (StepTracer stamps it before the call, and reports how long the
+    call took as ``start_trace_sec``), so its wall-clock ``start``
+    re-anchors the capture; without the span (a capture taken
     out-of-band) the file's mtime end-anchors it — stable for fixed
     inputs, so exports stay deterministic either way."""
     files = find_device_trace_files(train_dir)
@@ -537,6 +552,10 @@ def build_trace(train_dir: str, device_trace: bool = False) -> dict:
                                   for s in spans):
             events.append(_meta("thread_name", pid, _TID_H2D,
                                 "h2d-transfer"))
+        if src == "train" and any(str(s.get("span", "")).startswith(
+                _PHASE_PREFIX) for s in spans):
+            events.append(_meta("thread_name", pid, _TID_PHASES,
+                                "loop-phases"))
         events.extend(_span_events(spans, src, base, pid_of))
     if metrics:
         pid = pid_of["train"]

@@ -94,6 +94,7 @@ def _pallas_per_example(logits, labels, batch_tile, interpret):
         out_specs=_block_spec((batch_tile, _LANE)),
         out_shape=jax.ShapeDtypeStruct((b, _LANE), jnp.float32),
         interpret=interpret,
+        name="softmax_xent_fwd",  # the kernel's name in a capture
     )(logits, labels)
     return out[:, 0]
 
@@ -113,6 +114,7 @@ def _pallas_bwd(logits, labels, g, batch_tile, interpret):
         out_specs=_block_spec((batch_tile, c)),
         out_shape=jax.ShapeDtypeStruct((b, c), logits.dtype),
         interpret=interpret,
+        name="softmax_xent_bwd",
     )(logits, labels, g2d)
 
 

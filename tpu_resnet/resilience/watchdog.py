@@ -54,11 +54,16 @@ class HangWatchdog:
     """``maybe_start`` returns None when ``stall_sec <= 0`` (disabled)."""
 
     def __init__(self, stall_sec: float, train_dir: str, telemetry=None,
-                 spans=None, poll_sec: Optional[float] = None):
+                 spans=None, poll_sec: Optional[float] = None,
+                 on_stall=None):
+        """``on_stall`` is called once per stall, after the stack dump:
+        the train loop writes its in-memory phase spans out there
+        (obs/breakdown.py), so a hang leaves what the loop last did."""
         self.stall_sec = float(stall_sec)
         self.train_dir = train_dir
         self._telemetry = telemetry
         self._spans = spans
+        self._dump_spans = on_stall
         self._poll = poll_sec if poll_sec else min(self.stall_sec / 4, 5.0)
         self._lock = threading.Lock()
         self._last_wall: Optional[float] = None  # armed by first progress()
@@ -73,10 +78,12 @@ class HangWatchdog:
 
     @classmethod
     def maybe_start(cls, stall_sec: float, train_dir: str, telemetry=None,
-                    spans=None) -> Optional["HangWatchdog"]:
+                    spans=None, on_stall=None
+                    ) -> Optional["HangWatchdog"]:
         if stall_sec is None or stall_sec <= 0:
             return None
-        wd = cls(stall_sec, train_dir, telemetry=telemetry, spans=spans)
+        wd = cls(stall_sec, train_dir, telemetry=telemetry, spans=spans,
+                 on_stall=on_stall)
         wd.start()
         return wd
 
@@ -130,6 +137,11 @@ class HangWatchdog:
             self._spans.event("watchdog_stall", step=step,
                               stalled_sec=round(stalled_sec, 3),
                               stack_dump=path)
+        if self._dump_spans is not None:
+            try:
+                self._dump_spans()
+            except Exception as e:  # noqa: BLE001 - the dump is best effort
+                log.warning("watchdog: span dump failed: %s", e)
         # Published last: pollers of ``stalls`` see the dump/telemetry/
         # span side effects already landed.
         self.stalls = n

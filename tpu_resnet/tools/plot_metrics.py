@@ -9,7 +9,7 @@ Reads ``<dir>/metrics.jsonl`` (train series: loss/precision/lr/steps_per_sec,
 written by train/metrics_io.py) and, when present,
 ``<dir>/eval/metrics.jsonl`` (Precision/Best_Precision vs restored step from
 the eval sidecar) and renders one PNG: precision, loss, throughput, the
-step-time breakdown (data-wait fraction + sampled device step time from
+step-time breakdown (data-wait fraction + the loop thread's own time from
 tpu_resnet/obs/breakdown.py — the "are we input-bound" panel), and the
 MFU / step-time-percentile panel (the live mfu gauge + train_step_ms
 histogram percentiles from tpu_resnet/obs/mfu.py and obs/server.py — the
@@ -118,10 +118,10 @@ def plot(train_dir: str, out: Optional[str] = None,
         ax.plot(xs, [100 * y for y in ys], label="data wait %",
                 color="tab:red")
     ax2 = ax.twinx()
-    xs2, ys2 = _column(train, "device_step_sec_sampled")
+    xs2, ys2 = _column(train, "loop_host_sec")
     if xs2:
         ax2.plot(xs2, [1e3 * y for y in ys2], linestyle="--",
-                 color="tab:orange", label="device step ms (sampled)")
+                 color="tab:orange", label="loop host ms / interval")
         ax2.set_ylabel("ms")
     ax.set_xlabel("step")
     ax.set_ylim(0, 102)

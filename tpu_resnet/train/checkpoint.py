@@ -58,7 +58,8 @@ class CheckpointManager:
 
     def restore(self, state_template, step: Optional[int] = None,
                 fallback: Optional[bool] = None,
-                discard_failed: bool = False):
+                discard_failed: bool = False,
+                parent: Optional[int] = None):
         """Restore into the structure/shardings of ``state_template``.
 
         ``fallback`` (default: on exactly when ``step`` is None) is the
@@ -74,7 +75,10 @@ class CheckpointManager:
         resume path sets it (the process that owns the directory and will
         re-reach those step numbers, colliding on save): a read-only
         consumer (export, a notebook) must never destroy a checkpoint that
-        merely failed transiently for *it*."""
+        merely failed transiently for *it*.
+
+        ``parent`` is the ``id`` of the caller's span (the loop's
+        ``train.restore``) that the restore spans name as theirs."""
         import logging
         import time
 
@@ -108,10 +112,10 @@ class CheckpointManager:
                 if self._spans is not None:
                     self._spans.record(
                         "checkpoint_restore_failed", t0, time.time(),
-                        step=int(cand),
+                        step=int(cand), parent=parent,
                         error=f"{type(e).__name__}: {e}"[:200])
                 continue
-            attrs = {"step": int(cand)}
+            attrs = {"step": int(cand), "parent": parent}
             if cand != candidates[0]:
                 attrs["fallback_from_step"] = int(candidates[0])
             if self._spans is not None:

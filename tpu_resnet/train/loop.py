@@ -150,6 +150,12 @@ def _local_image_slice(batch, n: int = 4) -> np.ndarray:
 def train(cfg: RunConfig, mesh=None, metrics: Optional[MetricsWriter] = None,
           max_steps: Optional[int] = None):
     """Run training to ``cfg.train.train_steps``; returns the final state."""
+    # The loop's span recorder (obs/breakdown.py), from the first line:
+    # ``train.startup`` runs from here to the end of the first dispatch,
+    # its phases and every compile beneath it are written to events.jsonl
+    # at the first log boundary (the tracer does not exist yet).
+    breakdown = obs.StepBreakdown()
+    breakdown.begin("train.startup", keep=True)  # first_dispatch_done ends it
     elastic_ctx = None
     if mesh is None:
         # Elastic resume (resilience/elastic.py): derive the mesh from
@@ -177,8 +183,9 @@ def train(cfg: RunConfig, mesh=None, metrics: Optional[MetricsWriter] = None,
     # BEFORE any compile is paid) and lands the optimizer slots in their
     # data-axis shards.
     partitioner = parallel.make_partitioner(cfg.mesh, mesh)
-    state = init_partitioned_state(model, cfg.optim, schedule, init_rng,
-                                   sample, partitioner)
+    with breakdown.phase("train.init_state", keep=True):
+        state = init_partitioned_state(model, cfg.optim, schedule,
+                                       init_rng, sample, partitioner)
     n_params = param_count(state.params)
 
     # Observability (tpu_resnet/obs): event spans + run manifest + the
@@ -240,7 +247,8 @@ def train(cfg: RunConfig, mesh=None, metrics: Optional[MetricsWriter] = None,
                                           enabled=rcfg.nan_guard)
         watchdog = resilience.HangWatchdog.maybe_start(
             rcfg.watchdog_stall_sec, cfg.train.train_dir,
-            telemetry=telemetry, spans=spans)
+            telemetry=telemetry, spans=spans,
+            on_stall=lambda: breakdown.flush(spans, ring=True))
 
         injector.maybe_corrupt_checkpoint(cfg.train.train_dir)
         ckpt = CheckpointManager(
@@ -280,7 +288,9 @@ def train(cfg: RunConfig, mesh=None, metrics: Optional[MetricsWriter] = None,
             # checkpoint written on a different mesh/partition restores
             # through an explicit cross-topology reshard (orbax stores
             # global logical arrays) — value-identical, never corrupted.
-            state = ckpt.restore(state, discard_failed=True)
+            with breakdown.phase("train.restore", keep=True) as ph:
+                state = ckpt.restore(state, discard_failed=True,
+                                     parent=ph.id)
             log.info("resumed from step %d in %s",
                      int(jax.device_get(state.step)), cfg.train.train_dir)
         if elastic_ctx is not None and elastic_ctx.changed:
@@ -312,18 +322,19 @@ def train(cfg: RunConfig, mesh=None, metrics: Optional[MetricsWriter] = None,
         # only a measured loss sends a site to XLA.
         from tpu_resnet import ops
         if cfg.model.fused_epilogue == "auto" and ops.is_tpu_backend():
-            t_probe = time.time()
             kernel_batch = (cfg.train.global_batch_size
                             // mesh.shape["data"] if per_replica_bn
                             else cfg.train.global_batch_size)
-            ops.probe_model_epilogues(cfg, kernel_batch)
-            spans.record("autotune_probe", t_probe, time.time(),
-                         op="epilogue")
+            with breakdown.phase("train.autotune_probe", keep=True,
+                                 op="epilogue"):
+                ops.probe_model_epilogues(cfg, kernel_batch)
         # The xent kernel always sees the PER-DEVICE batch (shard_mapped
         # over 'data' under auto-jit, the local shard under per-replica
         # BN, the full batch only on one device) — probe at that shape,
         # not the global one (b1024-probe/b128-execute would decide at
         # the wrong point of the speedup curve).
+        xent_probe = breakdown.begin("train.autotune_probe", keep=True,
+                                     op="xent")
         base_step = make_train_step(model, cfg.optim, schedule,
                                     cfg.data.num_classes, augment_fn,
                                     base_rng=step_rng, mesh=mesh,
@@ -332,6 +343,7 @@ def train(cfg: RunConfig, mesh=None, metrics: Optional[MetricsWriter] = None,
                                         1, cfg.train.global_batch_size
                                         // mesh.shape["data"]),
                                     partitioner=partitioner)
+        breakdown.end(xent_probe, decisions=ops.autotune.decisions())
         # zero1 compiles with the partitioner's state layout so the
         # optimizer-slot arguments are per-shard buffers; replicated
         # passes None and keeps the exact historical program.
@@ -364,10 +376,13 @@ def train(cfg: RunConfig, mesh=None, metrics: Optional[MetricsWriter] = None,
         if resident:
             import tpu_resnet.data as data_lib
 
-            images_np, labels_np = data_lib.load_split(cfg.data, train=True)
-            ds = device_data.DeviceDataset(mesh, images_np, labels_np,
-                                           cfg.train.global_batch_size,
-                                           seed=cfg.train.seed)
+            with breakdown.phase("train.load_split", keep=True):
+                images_np, labels_np = data_lib.load_split(cfg.data,
+                                                           train=True)
+            with breakdown.phase("train.dataset_to_device", keep=True):
+                ds = device_data.DeviceDataset(mesh, images_np, labels_np,
+                                               cfg.train.global_batch_size,
+                                               seed=cfg.train.seed)
             run_chunk = device_data.compile_resident_steps(
                 base_step, ds, mesh, max(1, cfg.train.steps_per_call),
                 per_replica_bn=per_replica_bn,
@@ -378,9 +393,10 @@ def train(cfg: RunConfig, mesh=None, metrics: Optional[MetricsWriter] = None,
                               if prog_reg.cache_enabled else None))
             data_iter = None
         else:
-            data_iter, stage, host_iter = build_train_iterator(
-                cfg, mesh, start_step=step, injector=injector,
-                stop_event=shutdown.event)
+            with breakdown.phase("train.build_iterator", keep=True):
+                data_iter, stage, host_iter = build_train_iterator(
+                    cfg, mesh, start_step=step, injector=injector,
+                    stop_event=shutdown.event)
             if stage > 1:
                 run_staged = device_data.compile_staged_stream_steps(
                     base_step, mesh, per_replica_bn=per_replica_bn,
@@ -407,14 +423,19 @@ def train(cfg: RunConfig, mesh=None, metrics: Optional[MetricsWriter] = None,
 
         profiling.maybe_start_server(cfg.train.profiler_port)
         tracer = profiling.StepTracer(cfg.train.train_dir,
-                                      cfg.train.profile_steps, spans=spans)
+                                      cfg.train.profile_steps, spans=spans,
+                                      phases=breakdown.spans)
 
-        # Step-time breakdown (tpu_resnet/obs/breakdown.py): data_wait /
-        # dispatch / sampled device backlog per log interval, compile time of
-        # the first dispatch reported separately. Sampling reuses the existing
-        # log boundaries (chunks already end exactly there), so it never
-        # changes fusion behavior.
-        breakdown = obs.StepBreakdown()
+        # From here to the first chunk drained is ``train.first_dispatch``:
+        # the recorder's interval clock restarts so that compile_seconds
+        # counts from this line, and every backend compile of the first
+        # dispatch hangs beneath its ``compile`` span. The breakdown then
+        # samples at the existing log boundaries only (chunks already end
+        # exactly there), so it never changes fusion behavior.
+        first_phase = breakdown.begin("train.first_dispatch", step,
+                                      keep=True)
+        breakdown.compile_parent = first_compile_id = obs.next_span_id()
+        breakdown.reset_interval(step)
         telemetry.heartbeat(step)
         run_wall0 = time.time()
         start_step = step
@@ -450,12 +471,15 @@ def train(cfg: RunConfig, mesh=None, metrics: Optional[MetricsWriter] = None,
             if resident:
                 k = _chunk_len(step, total, cfg.train, ds.steps_per_epoch,
                                tracer.boundaries())
-                with breakdown.dispatch():
+                with breakdown.dispatch(step, k):
+                    if ds.epoch_of(step) != ds.epoch:
+                        with breakdown.phase("train.epoch_shuffle", step):
+                            ds.ensure_epoch(ds.epoch_of(step))
                     state, m = run_chunk(state, step, k)
                 step += k
             elif stage > 1:
                 if stage_buf is None:
-                    with breakdown.data_wait():
+                    with breakdown.data_wait(step):
                         try:
                             gi, gl, k = next(data_iter)
                         except StopIteration:
@@ -470,21 +494,21 @@ def train(cfg: RunConfig, mesh=None, metrics: Optional[MetricsWriter] = None,
                 c = min(k - off,
                         _chunk_len(step, total, cfg.train, 0,
                                    tracer.boundaries()))
-                with breakdown.dispatch():
+                with breakdown.dispatch(step, c):
                     state, m = run_staged(state, gi, gl, off, c)
                 step += c
                 off += c
                 last_inputs = gi  # reference only; sliced at summary time
                 stage_buf = None if off >= k else (gi, gl, k, off)
             else:
-                with breakdown.data_wait():
+                with breakdown.data_wait(step):
                     try:
                         images, labels = next(data_iter)
                     except StopIteration:
                         if shutdown.requested:
                             break  # preempted mid-data-wait: save below
                         raise
-                with breakdown.dispatch():
+                with breakdown.dispatch(step, 1):
                     state, m = train_step(state, images, labels)
                 step += 1
                 last_inputs = images
@@ -504,7 +528,11 @@ def train(cfg: RunConfig, mesh=None, metrics: Optional[MetricsWriter] = None,
                 compile_s = breakdown.first_dispatch_done(m)
                 now = time.time()
                 spans.record("compile", now - compile_s, now,
-                             seconds=round(compile_s, 3), step=start_step)
+                             seconds=round(compile_s, 3), step=start_step,
+                             id=first_compile_id, parent=first_phase.id)
+                # Start-up has ended: its phases and the compiles beneath
+                # them, held back until now, follow the span they name.
+                breakdown.flush(spans)
                 telemetry.set("compile_seconds", compile_s)
                 # What the memory and comms ledgers account: the program
                 # this run's input edge dispatches in steady state — on
@@ -533,7 +561,7 @@ def train(cfg: RunConfig, mesh=None, metrics: Optional[MetricsWriter] = None,
                         log.warning(            # must never kill training
                             "mfu accounting failed (%s: %s) — mfu gauges "
                             "stay 0", type(e).__name__, e)
-                    breakdown.reset_interval()
+                    breakdown.reset_interval(step)
                 if cfg.train.memory_ledger:
                     # HBM budget of the compiled step (obs/memory.py).
                     # memory_analysis needs a COMPILED program and the
@@ -572,7 +600,7 @@ def train(cfg: RunConfig, mesh=None, metrics: Optional[MetricsWriter] = None,
                         log.warning(            # must never kill training
                             "memory ledger failed (%s: %s) — memory.json "
                             "absent for this run", type(e).__name__, e)
-                    breakdown.reset_interval()
+                    breakdown.reset_interval(step)
                 if cfg.train.comms_ledger:
                     # Collective summary of the compiled step
                     # (obs/comms.py): op multiset + analytic bytes-on-
@@ -612,14 +640,15 @@ def train(cfg: RunConfig, mesh=None, metrics: Optional[MetricsWriter] = None,
                         log.warning(            # must never kill training
                             "comms ledger failed (%s: %s) — comms.json "
                             "absent for this run", type(e).__name__, e)
-                    breakdown.reset_interval()
+                    breakdown.reset_interval(step)
                 meter.rate(step)
                 last_sync = step
                 last_log_step = step
 
             if step % cfg.train.log_every == 0 or step == total:
-                breakdown.sample_device(m, step - last_sync)
-                m = {k: float(v) for k, v in jax.device_get(m).items()}
+                breakdown.sample_device(m, step - last_sync, step)
+                with breakdown.phase("train.log_fetch", step):
+                    m = {k: float(v) for k, v in jax.device_get(m).items()}
                 last_sync = step
                 if sentinel.check(step, m["loss"]):
                     # Divergence rollback: restore the last checkpoint and
@@ -627,11 +656,12 @@ def train(cfg: RunConfig, mesh=None, metrics: Optional[MetricsWriter] = None,
                     # window so the replayed steps see fresh batches. The
                     # check reuses this boundary's host-synced metrics —
                     # zero extra device syncs, fusion/chunking unchanged.
-                    ckpt.wait()
-                    if ckpt.latest_step() is None:
-                        raise sentinel.no_checkpoint(step, m["loss"])
                     bad_step = step
-                    state = ckpt.restore(state, discard_failed=True)
+                    with breakdown.phase("train.checkpoint", step):
+                        ckpt.wait()
+                        if ckpt.latest_step() is None:
+                            raise sentinel.no_checkpoint(step, m["loss"])
+                        state = ckpt.restore(state, discard_failed=True)
                     step = int(jax.device_get(state.step))
                     spans.event("nan_rollback", from_step=bad_step,
                                 to_step=step, loss=str(m["loss"]),
@@ -650,13 +680,14 @@ def train(cfg: RunConfig, mesh=None, metrics: Optional[MetricsWriter] = None,
                             injector=injector, stop_event=shutdown.event)
                         stage_buf = None
                     m = None
-                    breakdown.reset_interval()
+                    breakdown.reset_interval(step)
                     meter.rate(step)  # re-prime the throughput baseline
                     last_sync = step
                     last_ckpt_step = step
                     last_log_step = step
                     telemetry.heartbeat(step)
                     continue
+                log_write = breakdown.begin("train.log_write", step)
                 rate = meter.rate(step)
                 if rate:
                     m.update(rate)
@@ -700,6 +731,10 @@ def train(cfg: RunConfig, mesh=None, metrics: Optional[MetricsWriter] = None,
                     for t0, t1, nbytes, c in data_iter.drain_transfers():
                         spans.record("h2d_transfer", t0, t1,
                                      bytes=nbytes, steps=c)
+                # A compile since the last boundary (a new chunk
+                # length, a rebuilt stream) is one line that names
+                # its step; no-op while none is pending.
+                breakdown.flush(spans)
                 telemetry.update(m)
                 telemetry.set("checkpoint_lag_steps", step - last_ckpt_step)
                 telemetry.heartbeat(step)
@@ -716,6 +751,7 @@ def train(cfg: RunConfig, mesh=None, metrics: Optional[MetricsWriter] = None,
                         or step == total):
                     metrics.write(step, m)
                     last_summary = step
+                breakdown.end(log_write)
             if (cfg.train.image_summary_every > 0 and metrics.enabled
                     and last_inputs is not None
                     and step % cfg.train.image_summary_every == 0):
@@ -738,10 +774,12 @@ def train(cfg: RunConfig, mesh=None, metrics: Optional[MetricsWriter] = None,
                                 "the next log boundary", step)
                     spans.event("checkpoint_save_skipped_nonfinite",
                                 step=step)
-                elif ckpt.save(step, state):
-                    last_ckpt_step = step
-                    telemetry.set("checkpoint_lag_steps", 0)
-                    record_topology()
+                else:
+                    with breakdown.phase("train.checkpoint", step):
+                        if ckpt.save(step, state):
+                            last_ckpt_step = step
+                            telemetry.set("checkpoint_lag_steps", 0)
+                            record_topology()
         if shutdown.requested and step < total:
             # Preemption honored at the chunk boundary: force a final save
             # so the resume loses zero steps, then mark the event. The
@@ -754,9 +792,11 @@ def train(cfg: RunConfig, mesh=None, metrics: Optional[MetricsWriter] = None,
             if injector.plan.preempt_burst > 0:
                 telemetry.set("fault_preempt_burst",
                               float(injector.burst_fired))
-            if step > last_ckpt_step and ckpt.save(step, state, force=True):
-                last_ckpt_step = step
-                record_topology()
+            if step > last_ckpt_step:
+                with breakdown.phase("train.checkpoint", step):
+                    if ckpt.save(step, state, force=True):
+                        last_ckpt_step = step
+                        record_topology()
     finally:
         # One shutdown path for clean exits AND exceptions. Each closer
         # runs even if an earlier one raises (a failed ckpt.wait must not
@@ -816,6 +856,11 @@ def train(cfg: RunConfig, mesh=None, metrics: Optional[MetricsWriter] = None,
             _close(lambda: spans.record(
                 "run", run_wall0, time.time(), start_step=start_step,
                 stop_step=step, train_steps=total))
+        # The recorder's ring (the last iterations' phases) and whatever
+        # start-up span or compile is still pending: the one place the
+        # per-iteration spans reach the file.
+        _close(breakdown.close)
+        _close(lambda: breakdown.flush(spans, ring=True))
         _close(spans.close)
         if server is not None:
             _close(server.close)

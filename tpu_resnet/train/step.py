@@ -168,49 +168,62 @@ def make_train_step(model, optim_cfg, schedule, num_classes: int,
             # replica would replay the same crops/flips on its slot-j
             # example.
             rng = jax.random.fold_in(rng, jax.lax.axis_index(grad_axis))
+        # The named scopes split a profiler capture by what the step is
+        # doing (tools/profiling.py::reduce_capture); Flax's module scopes
+        # nest under ``forward``, and JAX marks the backward pass
+        # ``transpose(jvp(...))`` on the same path. They change HLO
+        # metadata only: the printed jaxpr is the same.
         if augment_fn is not None:
-            images = augment_fn(rng, images)
+            with jax.named_scope("augment"):
+                images = augment_fn(rng, images)
 
         def loss_fn(params):
-            logits, new_model_state = model.apply(
-                {"params": params, "batch_stats": state.batch_stats},
-                images, train=True, mutable=["batch_stats"])
-            if use_pallas:
-                xent = _pallas_xent(logits.astype(jnp.float32), labels)
-            else:
-                xent = softmax_xent(logits.astype(jnp.float32), labels,
-                                    num_classes, optim_cfg.label_smoothing)
-            penalty = optim_cfg.weight_decay * l2_weight_penalty(
-                params, optim_cfg.weight_decay_on_bn)
-            return xent + penalty, (logits, new_model_state)
+            with jax.named_scope("forward"):
+                logits, new_model_state = model.apply(
+                    {"params": params, "batch_stats": state.batch_stats},
+                    images, train=True, mutable=["batch_stats"])
+            with jax.named_scope("loss"):
+                if use_pallas:
+                    xent = _pallas_xent(logits.astype(jnp.float32), labels)
+                else:
+                    xent = softmax_xent(logits.astype(jnp.float32), labels,
+                                        num_classes,
+                                        optim_cfg.label_smoothing)
+                penalty = optim_cfg.weight_decay * l2_weight_penalty(
+                    params, optim_cfg.weight_decay_on_bn)
+                return xent + penalty, (logits, new_model_state)
 
         (loss, (logits, new_model_state)), grads = jax.value_and_grad(
             loss_fn, has_aux=True)(state.params)
         new_batch_stats = new_model_state["batch_stats"]
-        precision = jnp.mean(
-            (jnp.argmax(logits, axis=-1) == labels).astype(jnp.float32))
+        with jax.named_scope("metrics"):
+            precision = jnp.mean(
+                (jnp.argmax(logits, axis=-1) == labels).astype(jnp.float32))
         if grad_axis is not None:
             # Explicit ICI all-reduces (the shard_map analog of what XLA
             # emits on the jit path): average grads; average the EMA stats
             # so the stored state is one consistent replicated tree.
-            grads = jax.lax.pmean(grads, grad_axis)
-            new_batch_stats = jax.lax.pmean(new_batch_stats, grad_axis)
-            loss = jax.lax.pmean(loss, grad_axis)
-            precision = jax.lax.pmean(precision, grad_axis)
-        new_params, new_opt_state = apply_update(grads, state.opt_state,
-                                                 state.params)
+            with jax.named_scope("grad_exchange"):
+                grads = jax.lax.pmean(grads, grad_axis)
+                new_batch_stats = jax.lax.pmean(new_batch_stats, grad_axis)
+                loss = jax.lax.pmean(loss, grad_axis)
+                precision = jax.lax.pmean(precision, grad_axis)
+        with jax.named_scope("optimizer"):
+            new_params, new_opt_state = apply_update(grads, state.opt_state,
+                                                     state.params)
         new_state = state.replace(
             step=state.step + 1,
             params=new_params,
             batch_stats=new_batch_stats,
             opt_state=new_opt_state,
         )
-        metrics = {
-            "loss": loss,
-            "precision": precision,
-            "learning_rate": schedule(state.step),
-            "grad_norm": optax.global_norm(grads),
-        }
+        with jax.named_scope("metrics"):
+            metrics = {
+                "loss": loss,
+                "precision": precision,
+                "learning_rate": schedule(state.step),
+                "grad_norm": optax.global_norm(grads),
+            }
         return new_state, metrics
 
     return train_step
