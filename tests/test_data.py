@@ -272,6 +272,104 @@ def test_cifar_train_augment_shapes_and_determinism():
     assert not np.allclose(a, c)  # different key → different crops/flips
 
 
+def _numpy_crop(key, images, pad):
+    """The crop as a plain loop: offsets from the same split keys, then
+    ``padded[i, oh:oh+h, ow:ow+w]`` image by image."""
+    b, h, w, _ = images.shape
+    key_h, key_w = jax.random.split(key)
+    off_h = np.asarray(jax.random.randint(key_h, (b,), 0, 2 * pad + 1))
+    off_w = np.asarray(jax.random.randint(key_w, (b,), 0, 2 * pad + 1))
+    padded = np.pad(images, ((0, 0), (pad, pad), (pad, pad), (0, 0)))
+    want = np.stack([padded[i, oh:oh + h, ow:ow + w]
+                     for i, (oh, ow) in enumerate(zip(off_h, off_w))])
+    return want, off_h, off_w
+
+
+@pytest.mark.parametrize("shape", [(8, 32, 32, 3), (5, 12, 20, 3),
+                                   (1, 32, 32, 3)],
+                         ids=["square", "nonsquare", "batch1"])
+@pytest.mark.parametrize("dtype", [np.uint8, np.float32])
+@pytest.mark.parametrize("pad", [1, 2, 4])
+def test_random_crop_batch_matches_numpy_loop(pad, dtype, shape):
+    imgs = np.random.default_rng(pad).integers(
+        0, 256, shape).astype(dtype)
+    for seed in (0, 7):
+        key = jax.random.PRNGKey(seed)
+        got = augment._random_crop_batch(key, imgs, pad)
+        want, _, _ = _numpy_crop(key, imgs, pad)
+        assert got.dtype == dtype and got.shape == shape
+        np.testing.assert_array_equal(np.asarray(got), want)
+
+
+@pytest.mark.parametrize("pad", [1, 2, 4])
+def test_random_crop_batch_hits_every_offset_pair(pad):
+    n = (2 * pad + 1) ** 2
+    b, h, w = 40 * n, 6, 5
+    # every pixel of an image distinct and non-zero: a wrong shift on
+    # either axis, or padding in the wrong place, cannot pass
+    imgs = (1 + np.arange(h * w * 2, dtype=np.float32)).reshape(1, h, w, 2)
+    imgs = imgs + 100.0 * np.arange(b, dtype=np.float32)[:, None, None, None]
+    key = jax.random.PRNGKey(3)
+    want, off_h, off_w = _numpy_crop(key, imgs, pad)
+    assert len(set(zip(off_h.tolist(), off_w.tolist()))) == n
+    got = np.asarray(augment._random_crop_batch(key, imgs, pad))
+    np.testing.assert_array_equal(got, want)
+
+
+def _primitives(jaxpr):
+    for eqn in jaxpr.eqns:
+        yield eqn.primitive.name
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _primitives(sub)
+
+
+def test_cifar_train_augment_has_no_per_image_op():
+    imgs = np.zeros((16, 32, 32, 3), np.uint8)
+    jaxpr = jax.make_jaxpr(augment.cifar_train_augment)(
+        jax.random.PRNGKey(0), imgs)
+    found = set(_primitives(jaxpr.jaxpr))
+    assert "select_n" in found  # the walk reaches the crop
+    assert not found & {"gather", "dynamic_slice", "dynamic_update_slice",
+                        "scan", "while"}
+
+
+@pytest.mark.parametrize("how", ["jit_sharded", "shard_map_folded_key"])
+def test_cifar_train_augment_shards_over_data_without_collectives(how):
+    """Batch split over ``data`` (auto-sharded jit) and the per-replica
+    key folding of ``train_step`` under ``shard_map``: the rows the
+    unsharded function gives, and nothing crosses devices."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from tpu_resnet.config import load_config
+    from tpu_resnet.obs.comms import extract_collectives
+    from tpu_resnet.parallel import create_mesh
+
+    mesh = create_mesh(load_config("smoke").mesh, devices=jax.devices()[:8])
+    imgs = np.random.default_rng(0).integers(
+        0, 256, (32, 32, 32, 3), dtype=np.uint8)
+    key = jax.random.PRNGKey(5)
+    if how == "jit_sharded":
+        fn = jax.jit(augment.cifar_train_augment,
+                     in_shardings=(NamedSharding(mesh, P()),
+                                   NamedSharding(mesh, P("data"))),
+                     out_shardings=NamedSharding(mesh, P("data")))
+        want = np.asarray(jax.jit(augment.cifar_train_augment)(key, imgs))
+    else:
+        def body(rng, images):
+            rng = jax.random.fold_in(rng, jax.lax.axis_index("data"))
+            return augment.cifar_train_augment(rng, images)
+
+        fn = jax.jit(jax.shard_map(body, mesh=mesh,
+                                   in_specs=(P(), P("data")),
+                                   out_specs=P("data"), check_vma=False))
+        want = np.concatenate([
+            np.asarray(jax.jit(augment.cifar_train_augment)(
+                jax.random.fold_in(key, i), shard))
+            for i, shard in enumerate(np.split(imgs, 8))])
+    np.testing.assert_array_equal(np.asarray(fn(key, imgs)), want)
+    hlo = fn.lower(key, imgs).compile().as_text()
+    assert extract_collectives(hlo, data_axis=8, model_axis=1) == []
+
+
 def test_imagenet_mean_subtraction():
     imgs = np.full((2, 8, 8, 3), 255, np.uint8)
     out = np.asarray(augment.imagenet_eval_preprocess(imgs))

@@ -9,7 +9,9 @@ CPU from the per-step critical path entirely.
 CIFAR semantics match reference cifar_input.py:70-79 exactly:
 pad to 36×36 (symmetric — resize_image_with_crop_or_pad(36,36) pads 2 px per
 side), random 32×32 crop, random horizontal flip, per-image standardization
-with TF's ``adjusted_stddev = max(std, 1/sqrt(num_elements))``.
+with TF's ``adjusted_stddev = max(std, 1/sqrt(num_elements))``. Crop and
+flip are selects over the whole batch, so the step holds no per-image
+operation.
 
 ImageNet device-side ops cover the tail of the VGG pipeline: random flip and
 mean subtraction (reference vgg_preprocessing.py:284-314; the RGB means are
@@ -40,17 +42,29 @@ def per_image_standardization(images: jnp.ndarray) -> jnp.ndarray:
 
 def _random_crop_batch(rng: jax.Array, images: jnp.ndarray,
                        pad: int) -> jnp.ndarray:
-    """Pad symmetrically then take a per-image random crop of original size."""
-    b, h, w, c = images.shape
+    """Pad symmetrically then take a per-image random crop of original size.
+
+    Each axis is a batch-wide select among its ``2*pad+1`` static shifts:
+    elementwise along the batch, so XLA fuses it (no gather, no per-image
+    ``dynamic-update-slice``) and it shards over ``data`` with no collective.
+    A select copies values, so the rows are the bits a per-image
+    ``dynamic_slice`` at ``(off_h, off_w)`` gives.
+    """
+    b, h, w, _ = images.shape
     padded = jnp.pad(images, ((0, 0), (pad, pad), (pad, pad), (0, 0)))
     rng_h, rng_w = jax.random.split(rng)
     off_h = jax.random.randint(rng_h, (b,), 0, 2 * pad + 1)
     off_w = jax.random.randint(rng_w, (b,), 0, 2 * pad + 1)
+    off_h = off_h[:, None, None, None]
+    off_w = off_w[:, None, None, None]
 
-    def crop_one(img, oh, ow):
-        return jax.lax.dynamic_slice(img, (oh, ow, 0), (h, w, c))
-
-    return jax.vmap(crop_one)(padded, off_h, off_w)
+    rows = padded[:, 0:h]
+    for k in range(1, 2 * pad + 1):
+        rows = jnp.where(off_h == k, padded[:, k:k + h], rows)
+    out = rows[:, :, 0:w]
+    for k in range(1, 2 * pad + 1):
+        out = jnp.where(off_w == k, rows[:, :, k:k + w], out)
+    return out
 
 
 def _random_flip_batch(rng: jax.Array, images: jnp.ndarray) -> jnp.ndarray:
