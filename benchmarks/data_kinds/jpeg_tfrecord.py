@@ -1,26 +1,20 @@
-"""The traffic generator: one general function per kind of data set, driven
-by a traffic file's parameters and the run's ``--seed``.
-
-``jpeg_tfrecord`` writes photo-like JPEGs (smooth structure plus mild
-noise, about 10:1 like real photographs) as Inception-style
-``tf.train.Example`` records in TFRecord shards named
+"""The kind of data set ``jpeg_tfrecord``: photo-like JPEGs (smooth
+structure plus mild noise, about 10:1 like real photographs) as
+Inception-style ``tf.train.Example`` records in TFRecord shards named
 ``train-XXXXX-of-NNNNN``: the format the ImageNet trainer reads. It follows
 ``tools/input_edge.py::make_shards`` and
 ``tpu_resnet.data.engine.synthetic_photo_jpeg``, with each image drawn from
 ``(seed, index)``, so that a pool of processes makes them in any order, and with
 1/f random fields where the original has one sine wave (see ``photo_jpeg``).
 
-``cifar_bin`` writes a CIFAR-100-format ``train.bin`` (coarse label, fine
-label, 3072 bytes depth-major) of uniform random pixels and labels.
-
-Both return the directory the program's ``data.data_dir`` points at.
+``generate`` returns the directory the program's ``data.data_dir`` points
+at.
 """
 
 from __future__ import annotations
 
 import io
 import os
-import shutil
 import struct
 import multiprocessing
 from concurrent.futures import ProcessPoolExecutor
@@ -138,7 +132,7 @@ def _record(args) -> bytes:
     return encode_example(photo_jpeg(size, quality, rng), label)
 
 
-def jpeg_tfrecord(out_dir: str, params: Dict, seed: int) -> str:
+def generate(out_dir: str, params: Dict, seed: int) -> str:
     n_shards, per_shard = params["shards"], params["per_shard"]
     lo, hi = params["label_range"]
     # Every seed decodes the same multiset of sizes (decode cost follows
@@ -162,32 +156,3 @@ def jpeg_tfrecord(out_dir: str, params: Dict, seed: int) -> str:
             os.path.join(out_dir, f"train-{s:05d}-of-{n_shards:05d}"),
             records[s * per_shard:(s + 1) * per_shard])
     return out_dir
-
-
-def cifar_bin(out_dir: str, params: Dict, seed: int) -> str:
-    n, classes = params["examples"], params["classes"]
-    rng = np.random.default_rng((seed, 0))
-    raw = np.empty((n, 2 + 3072), np.uint8)
-    raw[:, 2:] = rng.integers(0, 256, (n, 3072), dtype=np.uint8)
-    fine = rng.integers(0, classes, n)
-    raw[:, 1] = fine
-    raw[:, 0] = fine // max(1, classes // 20)  # coarse label, unread
-    d = os.path.join(out_dir, "cifar-100-binary")
-    os.makedirs(d, exist_ok=True)
-    raw.tofile(os.path.join(d, "train.bin"))
-    return out_dir
-
-
-KINDS = {"jpeg_tfrecord": jpeg_tfrecord, "cifar_bin": cifar_bin}
-
-
-def generate(data_dir: str, traffic: Dict, seed: int) -> str:
-    """Make the cell's data set anew from the seed under ``data_dir``
-    (emptied first: a run leaves one data set behind, not one a seed)."""
-    shutil.rmtree(data_dir, ignore_errors=True)
-    os.makedirs(data_dir)
-    data = traffic["data"]
-    if data["kind"] not in KINDS:
-        raise ValueError(f"unknown data kind {data['kind']!r}; have "
-                         f"{sorted(KINDS)}")
-    return KINDS[data["kind"]](data_dir, data, seed)

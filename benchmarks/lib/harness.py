@@ -7,8 +7,16 @@ the newest loss from the device, so every call is a synced timestamp. The
 window opens on such a boundary once the warm-up boundaries have passed,
 closes on the first boundary at least ``--seconds`` later, and the loop is
 then stopped the way a preemption stops it (SIGTERM to the shutdown
-coordinator; its final save falls after the window). The rate is the images
-of all steps between the two boundaries over the time between them.
+coordinator; its final save falls after the window). The rate is the
+examples of all steps (steps x global batch; for the families so far an
+example is an image) between the two boundaries over the time between them.
+
+What belongs to a model family is the family's module to say
+(``families/<family>.py``, named by the configuration's file and found by
+``Manifest.family_of``): the example input the weights are drawn on, the
+host copy of a state that is compared, the plain reference and its
+stand-ins in a lower precision, the numbers read from the two, and the
+FLOPs of one example.
 
 ``FirstDispatch`` wraps the chunk runner the loop builds
 (``device_data.compile_staged_stream_steps``, which both input edges go
@@ -33,8 +41,7 @@ from typing import Callable, Dict, List, Optional
 
 import numpy as np
 
-from benchmarks.lib import check, flops, lastline, peaks, trace_reduce
-from benchmarks.lib import traffic as traffic_lib
+from benchmarks.lib import check, lastline, peaks, trace_reduce
 from benchmarks.lib.manifest import Manifest
 
 CACHE_DIR = ".bench_cache"  # inside the checkout; data sets and train_dirs
@@ -65,43 +72,22 @@ def flat(tree) -> Dict[str, np.ndarray]:
     return out
 
 
-def momentum_of(opt_state, params: Dict[str, np.ndarray]
-                ) -> Dict[str, np.ndarray]:
-    """The optimizer's momentum buffers keyed like the parameters: the
-    leaves of its state whose path ends in a parameter's path."""
-    leaves = flat(opt_state)
-    out = {}
-    for key in params:
-        hits = [v for k, v in leaves.items()
-                if k.endswith("/" + key) and v.shape == params[key].shape]
-        if len(hits) != 1:
-            raise BenchmarkError(
-                f"optimizer state holds {len(hits)} buffers for {key!r}; "
-                f"the comparison expects one momentum buffer per leaf")
-        out[key] = hits[0]
-    return out
-
-
-def snapshot(state) -> Dict:
-    params = flat(state.params)
-    return {"params": params, "stats": flat(state.batch_stats),
-            "mom": momentum_of(state.opt_state, params),
-            "step": int(np.asarray(state.step))}
-
-
 # ---------------------------------------------------------- first dispatch
 class FirstDispatch:
     """Observes the loop's chunk runner; see the module docstring.
-    ``fault`` (tests and fault readings only) replaces the runner by a
-    broken one underneath the observation."""
+    ``snapshot`` is the family's: the host copy of a state that is
+    compared. ``fault`` (tests and fault readings only) replaces the
+    runner by a broken one underneath the observation."""
 
-    def __init__(self, fault: Optional[Callable] = None):
+    def __init__(self, snapshot: Callable,
+                 fault: Optional[Callable] = None):
+        self.snapshot = snapshot
         self.fault = fault
         self.calls = 0
         self.steps = 0          # steps dispatched so far
         self.raised = 0         # steps whose dispatch raised
         self.before = self.after = self.metrics = None
-        self.rows = None        # (images, labels) the first call consumed
+        self.rows = None        # (inputs, labels) the first call consumed
         self.spans: List[tuple] = []  # (enter_ns, exit_ns) of every call
         self._original = None
 
@@ -132,7 +118,7 @@ class FirstDispatch:
             first = self.calls == 0
             self.calls += 1
             if first:
-                self.before = snapshot(state)
+                self.before = self.snapshot(state)
                 # fetched whole and cut on the host: a slice on the
                 # device would be a copy counted in the program's peak
                 self.rows = (np.asarray(jax.device_get(gi))[off:off + c],
@@ -146,7 +132,7 @@ class FirstDispatch:
             self.spans.append((t_in, time.monotonic_ns()))
             self.steps += c
             if first:
-                self.after = snapshot(out[0])
+                self.after = self.snapshot(out[0])
                 self.metrics = {k: float(v) for k, v in
                                 jax.device_get(out[1]).items()}
             return out
@@ -297,11 +283,12 @@ def build_config(config: Dict, traffic: Dict, chips: int,
     return cfg
 
 
-def plant_weights(cfg, seed: int, start_step: int) -> None:
+def plant_weights(cfg, seed: int, start_step: int, example_input) -> None:
     """Draw the run's initial state from ``--seed`` with the program's own
-    initializer and save it as a checkpoint of the fresh ``train_dir``:
-    the loop resumes from it (its documented restart path) and so trains
-    the seed's weights under the fixed ``train.seed``.
+    initializer, shown the family's ``example_input``, and save it as a
+    checkpoint of the fresh ``train_dir``: the loop resumes from it (its
+    documented restart path) and so trains the seed's weights under the
+    fixed ``train.seed``.
 
     The checkpoint is labelled ``start_step`` (the traffic file's; the
     state's step and the optimizer's counts say the same). The loop ends
@@ -320,13 +307,11 @@ def plant_weights(cfg, seed: int, start_step: int) -> None:
     from tpu_resnet.train.state import init_partitioned_state
 
     mesh = resilience.elastic.resolve(cfg).mesh
-    size = cfg.data.resolved_image_size
     state = init_partitioned_state(
         build_model(cfg), cfg.optim,
         sched_lib.build_schedule(cfg.optim, cfg.train),
         jax.random.PRNGKey(seed % (2 ** 31 - 1)),
-        jnp.zeros((1, size, size, 3), jnp.float32),
-        parallel.make_partitioner(cfg.mesh, mesh))
+        example_input, parallel.make_partitioner(cfg.mesh, mesh))
     if start_step:
         def at_start(x):
             whole = x.ndim == 0 and jnp.issubdtype(x.dtype, jnp.integer)
@@ -377,46 +362,14 @@ def memory_peak(devices) -> int:
     return peak
 
 
-# What can stand in the program's place for a reading: the reference in a
-# lower precision. ``fp8`` is the control; ``bf16`` is the reference's own
-# picture of the program's rounding (benchmarks/reference/resnet_v2.py).
-STAND_INS = ("fp8", "bf16")
-
-
-def follow_reference(obs: FirstDispatch, config: Dict, seed: int,
-                     quantize: str = "none") -> Dict:
-    """The plain reference (or, with ``quantize``, a stand-in for the
-    program) over the rows of the first dispatch, from the state the
-    program started from."""
-    import jax
-
-    from benchmarks.reference import resnet_v2 as ref
-
-    images, labels = obs.rows
-
-    def put(tree):
-        return jax.device_put({k: np.asarray(v, np.float32)
-                               for k, v in tree.items()})
-
-    with jax.default_matmul_precision("highest"):
-        params, stats, mom, losses, gnorms = ref.follow(
-            put(obs.before["params"]), put(obs.before["stats"]),
-            put(obs.before["mom"]), images, labels, config["model"],
-            config["job"], seed, quantize=quantize,
-            start_step=obs.before["step"])
-    out = {"params": flat(params), "stats": flat(stats), "mom": flat(mom),
-           "loss": losses[-1], "gnorm": gnorms[-1], "losses": losses}
-    del params, stats, mom
-    return out
-
-
 def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
              started: float, manifest: Optional[Manifest] = None,
              require_tpu: bool = True, control: str = "",
              fault: Optional[Callable] = None, out=sys.stdout) -> int:
     """Run one cell once and print its result line on ``out``. Returns the
-    exit code. ``control`` (comma-separated names of ``STAND_INS``) also
-    reads the numbers of the reference in a lower precision put in the
+    exit code. ``control`` (comma-separated names of the family's
+    ``STAND_INS``) also reads the numbers of the reference in a lower
+    precision put in the
     program's place (never part of a driver's run); ``fault`` breaks the
     timed path underneath (tests, fault readings)."""
     manifest = manifest or Manifest()
@@ -424,6 +377,8 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
     config = manifest.config_of(workload)
     traffic = manifest.traffic_of(workload)
     limits = manifest.limits_of(workload)
+    family = manifest.family_of(workload)
+    make_data = manifest.data_kind_of(workload)
     metrics = manifest.metrics_for(workload, trace)
     readers = {m["name"]: manifest.reader(m["name"])
                for m in metrics if trace}
@@ -464,16 +419,19 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
         if d:
             shutil.rmtree(d, ignore_errors=True)
     os.makedirs(train_dir)
-    traffic_lib.generate(data_dir, traffic, seed)
+    # anew from the seed: a run leaves one data set behind, not one a seed
+    shutil.rmtree(data_dir, ignore_errors=True)
+    os.makedirs(data_dir)
+    make_data(data_dir, traffic["data"], seed)
     log(f"data set made from seed {seed} "
         f"({time.perf_counter() - started:.1f} s after start)")
 
     cfg = build_config(config, traffic, chips, data_dir, train_dir)
     start_step = int(traffic.get("start_step", 0))
-    plant_weights(cfg, seed, start_step)
+    plant_weights(cfg, seed, start_step, family.example_input(cfg))
     log(f"weights drawn from seed {seed} and planted as step {start_step} "
         f"({time.perf_counter() - started:.1f} s after start)")
-    observer = FirstDispatch(fault).install()
+    observer = FirstDispatch(family.snapshot, fault).install()
     window_s = float(traffic["trace_seconds"] if trace else seconds)
     writer = WindowWriter(window_s, int(traffic["warmup_boundaries"]),
                           observer, trace_dir, started,
@@ -545,7 +503,9 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
             window_s=span, steps=steps, images=steps * batch,
             global_batch=batch, chips=chips, records=writer.records,
             trace=reduction, peaks=peak, arch=config["model"],
-            flops_per_image=flops.train_flops_per_image(config["model"]))
+            example=family.example(config["model"]),
+            flops_per_image=family.train_flops_per_example(
+                config["model"]))
         for name, read in readers.items():
             values[name] = read(run)
         log(f"trace: busy {reduction['busy_s']:.4f} s of "
@@ -562,36 +522,39 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
     # ------------------------------------------------------ the comparison
     t_ref = time.perf_counter()
     train_seed = cfg.train.seed
-    reference = follow_reference(observer, config, train_seed)
-    program = dict(observer.after, params0=observer.before["params"],
-                   stats0=observer.before["stats"],
+    reference = family.follow(observer.before, observer.rows, config,
+                              train_seed)
+    # the state after the chunk, and every part of the shared start under
+    # its name with a 0 (``params0``, ``step0``)
+    program = dict(observer.after,
+                   **{k + "0": v for k, v in observer.before.items()},
                    loss=observer.metrics["loss"],
                    gnorm=observer.metrics["grad_norm"],
-                   step0=observer.before["step"],
                    rows=len(observer.rows[0]))
-    read = check.readings(program, reference)
+    read = family.readings(program, reference)
     correct, compared = check.judge(read, limits)
     log(f"reference followed {program['rows']} steps in "
         f"{time.perf_counter() - t_ref:.1f} s; losses {reference['losses']}"
         f" | program's last {program['loss']}")
     for name in filter(None, control.split(",")):
-        if name not in STAND_INS:
-            raise BenchmarkError(f"unknown stand-in {name!r}; have "
-                                 f"{STAND_INS}")
+        if name not in family.STAND_INS:
+            raise BenchmarkError(f"unknown stand-in {name!r}; the family "
+                                 f"has {family.STAND_INS}")
         t_ctl = time.perf_counter()
-        ctl = follow_reference(observer, config, train_seed, quantize=name)
-        ctl_prog = dict(program, params=ctl["params"], stats=ctl["stats"],
-                        mom=ctl["mom"], loss=ctl["loss"],
-                        gnorm=ctl["gnorm"])
-        ctl_ok, ctl_cmp = check.judge(check.readings(ctl_prog, reference),
+        ctl = family.follow(observer.before, observer.rows, config,
+                            train_seed, quantize=name)
+        # the stand-in's state and last step in the program's place
+        ctl_prog = dict(program, **{k: v for k, v in ctl.items()
+                                    if k in program})
+        ctl_ok, ctl_cmp = check.judge(family.readings(ctl_prog, reference),
                                       limits)
         log(f"CONTROL {name} correct={ctl_ok} "
             f"({time.perf_counter() - t_ctl:.1f} s): {ctl_cmp}")
         log(f"CONTROL {name} losses {ctl['losses']} worst leaves "
-            f"{check.worst_leaves(ctl_prog, reference)}")
+            f"{check.worst_leaves(family.groups(ctl_prog, reference))}")
     if control:
         log(f"PROGRAM worst leaves "
-            f"{check.worst_leaves(program, reference)}")
+            f"{check.worst_leaves(family.groups(program, reference))}")
 
     line = lastline.build(correct=correct, attempted=attempted,
                           failed=failed, values=values, metrics=metrics,

@@ -3,13 +3,21 @@
 A cell ``<config>.<traffic>`` reads ``configs/<config>.json`` (through the
 configuration's ``file``), ``traffic/<traffic>.json``,
 ``limits/<cell>.json`` and, for each per-layer metric listed for it,
-``layer_metrics/<metric>.py``. A later PR adds a cell, a configuration, a
-traffic mix or a metric by adding such files and the entries that name
-them; nothing here lists them.
+``layer_metrics/<metric>.py``. The configuration's file names its model
+family (``"family"``), found as ``families/<family>.py``: the example
+input the weights are drawn on, the snapshot of the state that is
+compared, the plain reference and its stand-ins, the numbers read from
+the two, and the FLOPs of one example. The traffic file names its kind of
+data set (``data.kind``), found as ``data_kinds/<kind>.py`` with a
+``generate(out_dir, params, seed)``. A later PR adds a cell, a
+configuration, a family, a traffic mix, a kind of data or a metric by
+adding such files and the entries that name them; nothing here lists
+them.
 """
 
 from __future__ import annotations
 
+import importlib
 import importlib.util
 import json
 import os
@@ -36,11 +44,30 @@ def _load_json(path: str) -> Dict:
         raise ManifestError(f"cannot read {path}: {e}") from None
 
 
+def load_module(path: str):
+    """The Python file at ``path`` as a module: a reader, a family, a kind
+    of data, a family's reference beside it. A file of the benchmark's own
+    package comes under its package name (a pool's workers find a task by
+    it); one from elsewhere, such as a test's fixture, by its path."""
+    path = os.path.abspath(path)
+    if path.startswith(BENCH_DIR + os.sep):
+        rel = os.path.relpath(path, REPO_ROOT)
+        return importlib.import_module(
+            os.path.splitext(rel)[0].replace(os.sep, "."))
+    spec = importlib.util.spec_from_file_location(
+        "benchmarks_file_" + re.sub(r"\W", "_", path), path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
 class Manifest:
     """The benchmark as data: ``root`` holds ``BENCHMARK.json`` (and is
-    where a run keeps its cache directory); ``traffic`` and ``limits`` sit
-    in ``bench_dir``, the metrics' readers in ``metrics_dir``. Tests point
-    ``root`` and ``bench_dir`` at a tiny benchmark of their own."""
+    where a run keeps its cache directory); ``traffic``, ``limits`` and
+    ``families`` sit in ``bench_dir``, the metrics' readers in
+    ``metrics_dir``. Tests point ``root`` and ``bench_dir`` at a tiny
+    benchmark of their own; a family that such a benchmark does not hold
+    itself is the benchmark's own."""
 
     def __init__(self, root: str = REPO_ROOT, bench_dir: str = BENCH_DIR,
                  metrics_dir: str = os.path.join(BENCH_DIR,
@@ -61,7 +88,35 @@ class Manifest:
 
     def config_of(self, workload: str) -> Dict:
         entry = self.configs[self.workload(workload)["config"]]
-        return _load_json(os.path.join(self.root, entry["file"]))
+        config = _load_json(os.path.join(self.root, entry["file"]))
+        if "family" not in config:
+            raise ManifestError(f"configuration {entry['name']!r} names no "
+                                f"family ({entry['file']})")
+        return config
+
+    def family_of(self, workload: str):
+        """The module of the configuration's model family:
+        ``families/<family>.py`` in ``bench_dir`` or, failing that, in the
+        benchmark's own directory."""
+        name = self.config_of(workload)["family"]
+        dirs = [os.path.join(d, "families")
+                for d in dict.fromkeys((self.bench_dir, BENCH_DIR))]
+        for d in dirs:
+            path = os.path.join(d, name + ".py")
+            if os.path.exists(path):
+                return load_module(path)
+        raise ManifestError(f"family {name!r} has no file {name}.py in "
+                            f"{dirs}")
+
+    def data_kind_of(self, workload: str):
+        """``generate(out_dir, params, seed)`` of the traffic's kind of
+        data set: ``data_kinds/<kind>.py``."""
+        kind = self.traffic_of(workload)["data"]["kind"]
+        path = os.path.join(BENCH_DIR, "data_kinds", kind + ".py")
+        if not os.path.exists(path):
+            raise ManifestError(f"data kind {kind!r} has no generator at "
+                                f"{path}")
+        return load_module(path).generate
 
     def traffic_of(self, workload: str) -> Dict:
         return _load_json(os.path.join(
@@ -97,12 +152,7 @@ class Manifest:
         if not os.path.exists(path):
             raise ManifestError(f"per-layer metric {metric_name!r} has no "
                                 f"reader at {path}")
-        spec = importlib.util.spec_from_file_location(
-            "benchmarks_layer_metric_" + re.sub(r"\W", "_", metric_name),
-            path)
-        module = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(module)
-        return module.read
+        return load_module(path).read
 
     # --------------------------------------------------------- validation
     def problems(self) -> List[str]:
@@ -144,7 +194,8 @@ class Manifest:
             if w["config"] not in self.configs:
                 out.append(f"cell {w['name']}: unknown config")
                 continue
-            for what in (self.traffic_of, self.limits_of):
+            for what in (self.traffic_of, self.limits_of, self.family_of,
+                         self.data_kind_of):
                 try:
                     what(w["name"])
                 except (ManifestError, KeyError) as e:

@@ -29,8 +29,8 @@ def main(argv=None) -> int:
     ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
     ap.add_argument("--control", default="",
                     help="the reference in a lower precision, read in "
-                         "the program's place as well: fp8, bf16 or both "
-                         "with a comma")
+                         "the program's place as well: names of the "
+                         "family's STAND_INS, with commas")
     ap.add_argument("--fault", default="")
     args = ap.parse_args(argv)
 

@@ -1,4 +1,5 @@
-"""The comparison's arithmetic and the per-layer readers on made-up
+"""The comparison's arithmetic (the ResNet family's readings on
+benchmarks/lib/check.py's measures) and the per-layer readers on made-up
 numbers: what reads nought, what a fault moves, and that a reader with
 nothing to read returns nothing."""
 
@@ -8,6 +9,7 @@ from types import SimpleNamespace
 import numpy as np
 import pytest
 
+from benchmarks.families import resnet_v2 as family
 from benchmarks.lib import check
 from benchmarks.lib.manifest import Manifest
 
@@ -31,7 +33,7 @@ def sides(rng):
 
 def test_equal_sides_read_nought_and_pass_any_limit():
     prog, ref = sides(np.random.default_rng(0))
-    read = check.readings(prog, ref)
+    read = family.readings(prog, ref)
     assert all(abs(v) < 1e-12 for v in read.values()), read
     ok, compared = check.judge(read, {k: 0.0 for k in read
                                       if k == "step_count"})
@@ -57,7 +59,7 @@ def test_each_fault_moves_its_number(fault, number):
         prog["stats"]["a/bn/mean"] += rng.standard_normal(8)
     elif fault == "head_bias":
         prog["mom"]["final_dense/bias"] += rng.standard_normal(4)
-    read = check.readings(prog, ref)
+    read = family.readings(prog, ref)
     assert read[number] > 0.01, read
     untouched = {"loss": "head_cos", "steps": "loss_rel", "head": "bn_cos",
                  "unchanged": "bn_cos", "bn_mean": "head_cos",
@@ -70,7 +72,7 @@ def test_a_cosine_sees_direction_and_not_length():
     for k in ("a/bn/mean", "c/bn/mean"):
         prog["stats"][k] = prog["stats0"][k] + 3.0 * (
             ref["stats"][k] - prog["stats0"][k])
-    read = check.readings(prog, ref)
+    read = family.readings(prog, ref)
     assert abs(read["bn_mean_cos"]) < 1e-12
     assert read["bn_all"] > 0.1
 
@@ -88,7 +90,7 @@ def test_leaves_have_to_match():
     prog, ref = sides(np.random.default_rng(3))
     del ref["stats"]["c/bn/var"]
     with pytest.raises(KeyError):
-        check.readings(prog, ref)
+        family.readings(prog, ref)
 
 
 # ------------------------------------------------------------ the readers

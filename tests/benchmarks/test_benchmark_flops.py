@@ -1,4 +1,4 @@
-"""The benchmark's FLOPs function against XLA's count of the compiled
+"""The ResNet family's FLOPs function against XLA's count of the compiled
 step: on the CPU at a small size, and against the two counts ISSUE 25
 tabulated for the described v5e (24.2 and 28.7 GFLOP an image)."""
 
@@ -9,7 +9,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from benchmarks.lib import flops
+from benchmarks.families import resnet_v2 as flops
 from benchmarks.lib.manifest import BENCH_DIR
 from benchmarks.reference import resnet_v2 as ref
 
@@ -25,7 +25,7 @@ def config(name):
 ])
 def test_against_the_issues_table(name, xla_gflop_per_image):
     arch = config(name)["model"]
-    mine = flops.train_flops_per_image(arch) / 1e9
+    mine = flops.train_flops_per_example(arch) / 1e9
     assert abs(mine - xla_gflop_per_image) / xla_gflop_per_image < 0.03
 
 
@@ -72,7 +72,7 @@ def test_against_xla_cost_analysis_on_the_cpu(arch):
     # XLA adds BN, ReLU, pooling and the means; the model count is the
     # convolutions and the dense layer alone.
     assert 0.85 < mine / xla <= 1.0, (mine, xla)
-    assert flops.train_flops_per_image(arch) == 3 * mine
+    assert flops.train_flops_per_example(arch) == 3 * mine
 
 
 def _leaf_names(arch):

@@ -5,6 +5,7 @@ device it does not know."""
 import json
 import os
 import re
+import shutil
 
 import pytest
 
@@ -67,11 +68,17 @@ def test_every_cell_finds_its_files(manifest):
         name = w["name"]
         assert name == f"{w['config']}.{w['traffic']}"
         config = manifest.config_of(name)
-        assert {"source", "preset", "model", "job"} <= set(config)
+        assert {"source", "family", "preset", "model", "job"} <= set(config)
         traffic = manifest.traffic_of(name)
         assert {"data", "overrides", "warmup_boundaries",
                 "trace_seconds"} <= set(traffic)
         assert manifest.limits_of(name)
+        family = manifest.family_of(name)
+        for said in ("example", "example_input", "snapshot", "follow",
+                     "groups", "readings", "train_flops_per_example"):
+            assert callable(getattr(family, said)), said
+        assert family.STAND_INS
+        assert callable(manifest.data_kind_of(name))
         for metric in manifest.per_layer(name):
             assert callable(manifest.reader(metric["name"]))
         assert {m["name"] for m in manifest.end_to_end(name)} == \
@@ -95,6 +102,45 @@ def test_unknown_workload_and_metric_are_errors(manifest):
         manifest.workload("no_such.cell")
     with pytest.raises(ManifestError):
         manifest.reader("no_such_metric")
+
+
+TINY = os.path.join(os.path.dirname(__file__), "fixtures", "tiny")
+CELL = "tiny_rn8.resident_b16"
+
+
+@pytest.mark.parametrize("file,spoil,lookup,said", [
+    ("configs/tiny_rn8.json", lambda c: c.pop("family"),
+     "config_of", "names no family"),
+    ("configs/tiny_rn8.json", lambda c: c.update(family="no_such_family"),
+     "family_of", "family 'no_such_family' has no file"),
+    ("traffic/resident_b16.json",
+     lambda t: t["data"].update(kind="no_such_kind"),
+     "data_kind_of", "data kind 'no_such_kind' has no generator"),
+], ids=["no_family_key", "family_file_absent", "data_kind_absent"])
+def test_missing_family_or_data_kind_is_an_error_and_a_problem(
+        tmp_path, file, spoil, lookup, said):
+    """No default family and no default kind of data: a configuration
+    without the key, a family without its file and a kind without its
+    generator are errors where they are looked up, and ``problems()``
+    reports each for its cell."""
+    root = str(tmp_path)
+    shutil.copytree(TINY, root, dirs_exist_ok=True)
+    os.rename(os.path.join(root, "tiny_manifest.json"),
+              os.path.join(root, "BENCHMARK.json"))
+    sound = Manifest(root=root, bench_dir=root)
+    assert sound.problems() == []
+    # the fixture's own family, and the benchmark's for the fixture's ResNet
+    assert sound.family_of("tiny_mlp.resident_b16").STAND_INS == ("bf16",)
+    assert sound.family_of(CELL).STAND_INS == ("fp8", "bf16")
+    with open(os.path.join(root, file)) as f:
+        data = json.load(f)
+    spoil(data)
+    with open(os.path.join(root, file), "w") as f:
+        json.dump(data, f)
+    broken = Manifest(root=root, bench_dir=root)
+    with pytest.raises(ManifestError, match=said):
+        getattr(broken, lookup)(CELL)
+    assert [p for p in broken.problems() if CELL in p and said in p]
 
 
 def test_peaks_table():
