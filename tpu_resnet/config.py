@@ -28,8 +28,13 @@ class DataConfig:
     design: this pipeline shards files/records per host.
     """
 
-    dataset: str = "cifar10"  # cifar10 | cifar100 | imagenet | synthetic
+    dataset: str = "cifar10"  # cifar10 | cifar100 | imagenet | synthetic | tokens
     data_dir: str = ""
+    # tokens only (data/tokens.py): ``<data_dir>/train.tokens`` is cut into
+    # consecutive sequences of ``seq_len`` ids, all in [0, vocab_size);
+    # the labels are the next ids.
+    seq_len: int = 0
+    vocab_size: int = 0
     # synthetic only: derive labels from image content (a brightened band)
     # so training must genuinely learn — the no-download stand-in for
     # real-data convergence runs (data/cifar.py::synthetic_data).
@@ -118,6 +123,8 @@ class DataConfig:
     def num_classes(self) -> int:
         if self.dataset == "synthetic":
             return self.synthetic_classes
+        if self.dataset == "tokens":
+            return self.vocab_size
         return {"cifar10": 10, "cifar100": 100,
                 "imagenet": 1000}[self.dataset]
 
@@ -156,7 +163,7 @@ class ModelConfig:
     (e.g. WRN-28-10 = resnet_size 28, width 10).
     """
 
-    name: str = "resnet"  # resnet | mlp
+    name: str = "resnet"  # resnet | mlp | afmoe (its fields: AfmoeConfig)
     resnet_size: int = 50
     width_multiplier: int = 1
     # bf16 compute on the MXU with fp32 params/BN stats. "float32" for
@@ -174,9 +181,9 @@ class ModelConfig:
     # input — identical math and identical parameters/checkpoints, much
     # better MXU utilization (models/resnet.py::SpaceToDepthStem).
     stem_space_to_depth: bool = True
-    # Rematerialize residual blocks in backward (activation memory
-    # O(depth)): enables batches past the HBM ceiling (e.g. b512 @224)
-    # at ~33% block recompute cost. Off by default.
+    # Rematerialize residual blocks (afmoe: whole layers) in backward
+    # (activation memory O(depth)): enables batches past the HBM ceiling
+    # (e.g. b512 @224) at ~33% block recompute cost. Off by default.
     remat: bool = False
     # Hybrid fused-Pallas block dispatch (CIFAR basic-block nets only):
     # stride-1 identity blocks run as single VMEM-resident Pallas kernels
@@ -205,6 +212,36 @@ class ModelConfig:
 
 
 @dataclasses.dataclass
+class AfmoeConfig:
+    """The fields of ``model.name=afmoe`` (models/afmoe.py::Arch has the
+    equations): a sparse-expert transformer as one chip of an
+    expert-parallel group holds it. The defaults are the published widths
+    of the preset's source; the vocabulary's rows held here are
+    ``data.vocab_size``."""
+
+    # each layer's kind, in order: dense_sliding | dense_full |
+    # moe_sliding | moe_full
+    layers: tuple = ("dense_sliding", "moe_sliding", "moe_sliding",
+                     "moe_sliding", "moe_full")
+    hidden: int = 2048
+    heads: int = 32
+    kv_heads: int = 4
+    head_dim: int = 128
+    window: int = 2048
+    dense_width: int = 6144
+    expert_width: int = 1024
+    experts_total: int = 128   # the router's width
+    experts_first: int = 0     # the routed experts this chip holds:
+    experts_held: int = 8      # experts_first .. experts_first + held
+    top_k: int = 8
+    shared: int = 1
+    rope_theta: float = 10000.0
+    rms_eps: float = 1e-5
+    route_scale: float = 2.826
+    balance_coeff: float = 0.001
+
+
+@dataclasses.dataclass
 class OptimConfig:
     """Optimizer + schedule.
 
@@ -216,8 +253,16 @@ class OptimConfig:
     ImageNet (resnet_imagenet_train.py:236-260).
     """
 
-    optimizer: str = "momentum"  # sgd | momentum
+    optimizer: str = "momentum"  # sgd | momentum | adamw
     momentum: float = 0.9
+    # adamw only: its three constants. Its decay is ``weight_decay``,
+    # decoupled and on matrices only (no L2 term enters the loss).
+    adam_b1: float = 0.9
+    adam_b2: float = 0.95
+    adam_eps: float = 1e-8
+    # Clip the gradients to this global norm before the optimizer
+    # (0 = off).
+    grad_clip_norm: float = 0.0
     schedule: str = "cifar_piecewise"  # cifar_piecewise | imagenet_warmup | constant | cosine
     base_lr: float = 0.1
     weight_decay: float = 0.0002  # reference _WEIGHT_DECAY for cifar
@@ -705,6 +750,7 @@ class ProgramsConfig:
 class RunConfig:
     data: DataConfig = dataclasses.field(default_factory=DataConfig)
     model: ModelConfig = dataclasses.field(default_factory=ModelConfig)
+    afmoe: AfmoeConfig = dataclasses.field(default_factory=AfmoeConfig)
     optim: OptimConfig = dataclasses.field(default_factory=OptimConfig)
     mesh: MeshConfig = dataclasses.field(default_factory=MeshConfig)
     train: TrainConfig = dataclasses.field(default_factory=TrainConfig)
@@ -838,6 +884,28 @@ def _smoke() -> RunConfig:
     return cfg
 
 
+def _trinity_mini_ep16() -> RunConfig:
+    """Trinity-Mini (arcee-ai, ``afmoe``) as one of sixteen chips that
+    share each layer: 8 of the 128 routed experts and an eighth of the
+    vocabulary held here, one dense and four expert layers (one period of
+    the 3:1 window:full pattern); next-token cross-entropy, AdamW."""
+    cfg = RunConfig()
+    cfg.data.dataset = "tokens"
+    cfg.data.seq_len = 4096
+    cfg.data.vocab_size = 25_024
+    cfg.model.name = "afmoe"
+    cfg.optim.optimizer = "adamw"
+    # linear warm-up from 0 over 2,000 steps, then a cosine over the run
+    cfg.optim.schedule = "cosine"
+    cfg.optim.warmup_steps = 2000
+    cfg.optim.base_lr = 3e-4
+    cfg.optim.weight_decay = 0.1
+    cfg.optim.grad_clip_norm = 1.0
+    cfg.train.global_batch_size = 2
+    cfg.train.image_summary_every = 0
+    return cfg
+
+
 # The supported config space (these presets × mesh/dtype/fused/remat/
 # engine variations) is certified statically: tpu_resnet/analysis/
 # configmatrix.py traces the compiled train/eval program of every
@@ -851,6 +919,7 @@ PRESETS = {
     "wrn28_10_cifar100": _wrn_28_10_cifar100,
     "imagenet": _imagenet,
     "smoke": _smoke,
+    "trinity_mini_ep16": _trinity_mini_ep16,
 }
 
 

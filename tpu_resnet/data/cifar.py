@@ -174,4 +174,9 @@ def load_split(cfg, train: bool) -> Tuple[np.ndarray, np.ndarray]:
                               task=cfg.synthetic_task,
                               label_noise=(cfg.synthetic_label_noise
                                            if train else 0.0))
+    if cfg.dataset == "tokens":
+        if not train:
+            raise ValueError("dataset 'tokens' has a training split only")
+        from tpu_resnet.data.tokens import load_tokens
+        return load_tokens(cfg)
     raise ValueError(f"load_split does not handle {cfg.dataset!r}")

@@ -46,6 +46,14 @@ def should_use(data_cfg) -> bool:
     double-buffered residency (flat + epoch buffer). Policy 'on' raises
     when the path is impossible rather than silently streaming."""
     policy = getattr(data_cfg, "device_resident", "auto")
+    if data_cfg.dataset == "tokens":
+        if policy == "off" or jax.process_count() != 1:
+            raise ValueError(
+                "dataset 'tokens' trains from a split resident on the "
+                "device in a single process only: the host data engine "
+                "does not carry token data (data.device_resident=off and "
+                "multi-host runs are refused)")
+        return True
     if policy == "off":
         return False
     forced = policy == "on"
@@ -67,7 +75,9 @@ def should_use(data_cfg) -> bool:
 
 
 class DeviceDataset:
-    """Training split resident in HBM with on-device epoch shuffling."""
+    """Training split resident in HBM with on-device epoch shuffling.
+    ``labels`` has one entry an example, ``(N,)``, or one a position,
+    ``(N, S)`` beside ``(N, S)`` token inputs."""
 
     def __init__(self, mesh: Mesh, images: np.ndarray, labels: np.ndarray,
                  batch: int, seed: int = 0):
@@ -98,7 +108,8 @@ class DeviceDataset:
                 order = jax.random.permutation(rng, n)[: spe * b]
                 ib = jnp.take(flat_i, order, axis=0).reshape(
                     (spe, b) + flat_i.shape[1:])
-                lb = jnp.take(flat_l, order, axis=0).reshape((spe, b))
+                lb = jnp.take(flat_l, order, axis=0).reshape(
+                    (spe, b) + flat_l.shape[1:])
             return ib, lb
 
         self._shuffle = jax.jit(

@@ -28,7 +28,7 @@ import numpy as np
 from tpu_resnet import parallel
 from tpu_resnet.config import RunConfig
 from tpu_resnet.data import augment as aug_lib
-from tpu_resnet.models import build_model
+from tpu_resnet.models import build_model, require_image_model
 from tpu_resnet.train import schedule as sched_lib
 from tpu_resnet.train.checkpoint import (CheckpointManager, latest_step_in,
                                          partitioned_template,
@@ -118,6 +118,7 @@ def build_eval_step(cfg: RunConfig, mesh, state_sharding=None,
     ``state_template`` (the abstract restore template) supplies the
     state avals the cache path lowers over; both default to the
     historical plain-jit behavior."""
+    require_image_model(cfg, "evaluation")
     model = build_model(cfg)
     _, eval_pre = aug_lib.get_augment_fns(cfg.data.dataset)
     step = make_eval_step(model, cfg.data.num_classes, eval_pre)

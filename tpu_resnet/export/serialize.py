@@ -32,7 +32,7 @@ from jax import export as jax_export
 
 from tpu_resnet.config import RunConfig
 from tpu_resnet.data import augment as aug_lib
-from tpu_resnet.models import build_model
+from tpu_resnet.models import build_model, require_image_model
 from tpu_resnet.ops import quant as quant_lib
 
 MANIFEST = "manifest.json"
@@ -67,6 +67,7 @@ def make_inference_fn(cfg: RunConfig, params, batch_stats) -> Callable:
     """Pure fn: uint8 [B,H,W,3] → logits [B,classes]; eval preprocessing
     (standardization / mean subtraction) baked in, like the frozen graph's
     in-graph preprocessing (resnet_cifar_frozen_model.py:81-88)."""
+    require_image_model(cfg, "export")
     model = build_model(cfg)
     _, eval_pre = aug_lib.get_augment_fns(cfg.data.dataset)
 
@@ -207,6 +208,7 @@ def export_from_checkpoint(cfg: RunConfig, out_dir: str,
                                              partitioned_template)
 
     mesh = parallel.create_mesh(cfg.mesh)
+    require_image_model(cfg, "export")
     model = build_model(cfg)
     # Abstract template in the run's partition layout (no device
     # allocation; a zero1 run's checkpoint restores into its shards and

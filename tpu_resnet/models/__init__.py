@@ -6,6 +6,7 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
+from tpu_resnet.models.afmoe import Afmoe, Arch
 from tpu_resnet.models.mlp import MLP
 from tpu_resnet.models.resnet import (
     ResNetV2,
@@ -14,11 +15,14 @@ from tpu_resnet.models.resnet import (
 )
 
 __all__ = [
+    "Afmoe",
     "MLP",
     "ResNetV2",
     "cifar_resnet_v2",
     "imagenet_resnet_v2",
     "build_model",
+    "sample_input",
+    "require_image_model",
 ]
 
 
@@ -38,9 +42,44 @@ _BOTTLENECK_REFUSAL = (
     "2e-2). Run with model.fused_blocks=false; see ROADMAP C4.")
 
 
+def sample_input(cfg):
+    """What a fresh state's weights are drawn on: one image of the data
+    set's size or, for token data, one short sequence of ids (no leaf's
+    shape depends on the length)."""
+    if cfg.data.dataset == "tokens":
+        return jnp.zeros((1, 8), jnp.int32)
+    size = cfg.data.resolved_image_size
+    return jnp.zeros((1, size, size, 3), jnp.float32)
+
+
+def require_image_model(cfg, what: str) -> None:
+    """Evaluation, serving and export carry image classifiers only: a
+    token model has no evaluation split, no cache for its attention and
+    no serving path (ROADMAP B-I)."""
+    if cfg.data.dataset == "tokens" or cfg.model.name == "afmoe":
+        raise NotImplementedError(
+            f"{what} is not supported for a token model "
+            f"(model.name={cfg.model.name!r}, data.dataset="
+            f"{cfg.data.dataset!r}): only `train` carries it; evaluation "
+            f"needs a held-out token split and serving a cache for "
+            f"attention, and neither exists yet")
+
+
 def build_model(cfg):
     """Build the model from a ``RunConfig`` (tpu_resnet.config.RunConfig)."""
     dtype = jnp.dtype(cfg.model.compute_dtype)
+    if cfg.model.name == "afmoe":
+        a = cfg.afmoe
+        return Afmoe(Arch(
+            layers=tuple(a.layers), hidden=a.hidden, heads=a.heads,
+            kv_heads=a.kv_heads, head_dim=a.head_dim, window=a.window,
+            dense_width=a.dense_width, expert_width=a.expert_width,
+            experts_total=a.experts_total,
+            experts_held=(a.experts_first, a.experts_held), top_k=a.top_k,
+            shared=a.shared, vocab_rows=cfg.data.num_classes,
+            rope_theta=a.rope_theta, eps=a.rms_eps,
+            route_scale=a.route_scale, balance_coeff=a.balance_coeff,
+            remat=cfg.model.remat, dtype=dtype))
     if cfg.model.name == "mlp":
         return MLP(hidden_units=cfg.model.mlp_hidden_units,
                    num_classes=cfg.data.num_classes,

@@ -203,14 +203,12 @@ def lower_train_step(cfg, mesh, state, base_step,
     import jax
 
     from tpu_resnet import parallel
-    from tpu_resnet.programs.registry import BATCH_DTYPE
+    from tpu_resnet.programs.registry import batch_avals
     from tpu_resnet.train.step import shard_step
 
     state_sharding = (partitioner.state_shardings(state)
                      if partitioner is not None and partitioner.is_sharded
                      else None)
-    size = cfg.data.resolved_image_size
-    gb = cfg.train.global_batch_size
     if stage_rows > 1:
         # The staged/double-buffered input edge's fused chunk program —
         # built by the ONE canonical constructor the loop itself
@@ -221,18 +219,13 @@ def lower_train_step(cfg, mesh, state, base_step,
         jitted = staged_chunk_jit(base_step, mesh, max(1, chunk_steps),
                                   per_replica_bn=per_replica_bn,
                                   state_sharding=state_sharding)
-        gi = jax.ShapeDtypeStruct((stage_rows, gb, size, size, 3),
-                                  BATCH_DTYPE)
-        gl = jax.ShapeDtypeStruct((stage_rows, gb), "int32")
+        gi, gl = batch_avals(cfg, rows=stage_rows)
         off = jax.ShapeDtypeStruct((), "int32")
         lowered = jitted.lower(state, gi, gl, off)
         variant = (f"staged-chunk(steps={max(1, chunk_steps)}"
                    f",stage={stage_rows})")
     else:
-        bs = parallel.batch_sharding(mesh)
-        images = jax.ShapeDtypeStruct((gb, size, size, 3), BATCH_DTYPE,
-                                      sharding=bs)
-        labels = jax.ShapeDtypeStruct((gb,), "int32", sharding=bs)
+        images, labels = batch_avals(cfg, parallel.batch_sharding(mesh))
         probe = shard_step(base_step, mesh, per_replica_bn=per_replica_bn,
                            state_sharding=state_sharding)
         lowered = probe.lower(state, images, labels)

@@ -119,6 +119,7 @@ def spell(cfg, mesh_shape: Dict[str, int], kind: str = "train",
         train|cifar10_rn50_bf16|mesh8x1|b128
         train|cifar10_rn8_f32_zero1|mesh8x1|b16
         serve|cifar10_rn50_bf16|mesh1x1|b4
+        train|tokens4096_afmoe5l_e8of128_bf16|mesh1x1|b2
 
     One key names exactly one compiled program (the config-matrix
     coverage check enforces it), so the family variant carries every
@@ -142,6 +143,12 @@ def spell(cfg, mesh_shape: Dict[str, int], kind: str = "train",
     if m.name == "resnet" and m.width_multiplier != 1:
         name = f"wrn{m.resnet_size}_{m.width_multiplier}"
     dataset = cfg.data.dataset
+    if m.name == "afmoe":
+        # A token model: depth, the experts held of the router's width,
+        # and the sequence length each change the traced program.
+        a = cfg.afmoe
+        name = f"afmoe{len(a.layers)}l_e{a.experts_held}of{a.experts_total}"
+        dataset = f"tokens{cfg.data.seq_len}"
     if dataset == "synthetic" and getattr(cfg.data, "synthetic_classes",
                                           10) != 10:
         dataset = f"synthetic{cfg.data.synthetic_classes}"
@@ -713,27 +720,48 @@ def state_avals(state):
 BATCH_DTYPE = "uint8"
 
 
+def init_program(model):
+    """``init(rng, sample, train=False)`` of a model as ONE program. A
+    model whose forward pass is many operations (a transformer) is drawn
+    by one compile, not by one small eager program an operation."""
+    import jax
+
+    return jax.jit(lambda rng, sample, train: model.init(rng, sample,
+                                                         train=train),
+                   static_argnames=("train",))
+
+
+def batch_avals(cfg, sharding=None, rows: int = 0):
+    """``(inputs, labels)`` avals of one global batch as the input edge
+    hands it to the step or, with ``rows``, of a staged superbatch of that
+    many: uint8 images with a label an example, or int32 ids with a label
+    a position. The one place the check engines, the ledgers and the
+    executable cache spell a batch."""
+    import jax
+
+    lead = ((rows,) if rows else ()) + (cfg.train.global_batch_size,)
+    kw = {} if sharding is None else {"sharding": sharding}
+    if cfg.data.dataset == "tokens":
+        ids = jax.ShapeDtypeStruct(lead + (cfg.data.seq_len,), "int32", **kw)
+        return ids, ids
+    size = cfg.data.resolved_image_size
+    return (jax.ShapeDtypeStruct(lead + (size, size, 3), BATCH_DTYPE, **kw),
+            jax.ShapeDtypeStruct(lead, "int32", **kw))
+
+
 def wrap_train_step(registry: ProgramRegistry, step_fn, avals,
                     donate_state: bool = True):
     """Route the single-step train program through the registry over
     the canonical batch avals. The one spelling of the single-step key
     (+``|nodon`` for the sweep's donation knob), shared by the train
     loop and sweep_measure so their cache entries can never drift."""
-    import jax
-
     from tpu_resnet import parallel
 
-    cfg = registry.cfg
-    gb = cfg.train.global_batch_size
-    size = cfg.data.resolved_image_size
-    bsh = parallel.batch_sharding(registry.mesh)
     program, _ = registry.wrap(
         registry.key("train") + ("" if donate_state else "|nodon"),
         step_fn,
-        (avals,
-         jax.ShapeDtypeStruct((gb, size, size, 3), BATCH_DTYPE,
-                              sharding=bsh),
-         jax.ShapeDtypeStruct((gb,), "int32", sharding=bsh)),
+        (avals,) + batch_avals(registry.cfg,
+                               parallel.batch_sharding(registry.mesh)),
         donated_args=(0,) if donate_state else ())
     return program
 
@@ -751,13 +779,9 @@ def staged_chunk_hook(registry: ProgramRegistry, avals, rows: int,
 
     from tpu_resnet import parallel
 
-    cfg = registry.cfg
-    gb = cfg.train.global_batch_size
-    size = cfg.data.resolved_image_size
-    ssh = parallel.staged_batch_sharding(registry.mesh)
-    gi = jax.ShapeDtypeStruct((rows, gb, size, size, 3),
-                              BATCH_DTYPE, sharding=ssh)
-    gl = jax.ShapeDtypeStruct((rows, gb), "int32", sharding=ssh)
+    gi, gl = batch_avals(registry.cfg,
+                         parallel.staged_batch_sharding(registry.mesh),
+                         rows=rows)
     off = jax.ShapeDtypeStruct((), "int32")
     base_key = registry.key("chunk") + ("" if donate_state else "|nodon")
     donated = (0,) if donate_state else ()

@@ -15,7 +15,7 @@ import jax
 
 from tpu_resnet.config import RunConfig
 from tpu_resnet.data import augment as aug_lib
-from tpu_resnet.models import build_model
+from tpu_resnet.models import build_model, require_image_model
 from tpu_resnet.ops import quant
 
 
@@ -38,6 +38,7 @@ def make_serve_infer(cfg: RunConfig) -> Callable:
     folds into the scale_bias_relu epilogue; ops/quant.py). A different
     argument tree means a different program signature — the registry
     spells it under the ``_q8`` key family (programs/registry.py)."""
+    require_image_model(cfg, "serving")
     model = build_model(cfg)
     _, eval_pre = aug_lib.get_augment_fns(cfg.data.dataset)
     quantized = getattr(cfg.serve, "quantize", "off") == "int8"

@@ -210,21 +210,18 @@ def partitioned_template(cfg, mesh, model=None):
     partitioner cannot satisfy on this mesh raises its per-leaf
     ``validate`` error here, before any restore I/O."""
     import jax
-    import jax.numpy as jnp
 
     from tpu_resnet import parallel
-    from tpu_resnet.models import build_model
+    from tpu_resnet.models import build_model, sample_input
     from tpu_resnet.train import schedule as sched_lib
     from tpu_resnet.train.state import init_state
 
     if model is None:
         model = build_model(cfg)
     schedule = sched_lib.build_schedule(cfg.optim, cfg.train)
-    size = cfg.data.resolved_image_size
     abstract = jax.eval_shape(
         lambda: init_state(model, cfg.optim, schedule,
-                           jax.random.PRNGKey(0),
-                           jnp.zeros((1, size, size, 3))))
+                           jax.random.PRNGKey(0), sample_input(cfg)))
     partitioner = parallel.make_partitioner(cfg.mesh, mesh)
     return partitioner.abstract_state(abstract)
 

@@ -30,7 +30,7 @@ from tpu_resnet.config import RunConfig
 from tpu_resnet.data import augment as aug_lib
 from tpu_resnet.data import device_data
 from tpu_resnet.data import pipeline
-from tpu_resnet.models import build_model
+from tpu_resnet.models import build_model, sample_input
 from tpu_resnet.tools import profiling
 from tpu_resnet.train import schedule as sched_lib
 from tpu_resnet.train.checkpoint import CheckpointManager
@@ -170,12 +170,13 @@ def train(cfg: RunConfig, mesh=None, metrics: Optional[MetricsWriter] = None,
 
     model = build_model(cfg)
     schedule = sched_lib.build_schedule(cfg.optim, cfg.train)
-    augment_fn, _ = aug_lib.get_augment_fns(cfg.data.dataset)
+    tokens = cfg.data.dataset == "tokens"
+    augment_fn = (None if tokens
+                  else aug_lib.get_augment_fns(cfg.data.dataset)[0])
 
     rng = jax.random.PRNGKey(cfg.train.seed)
     init_rng, step_rng = jax.random.split(rng)
-    size = cfg.data.resolved_image_size
-    sample = jnp.zeros((1, size, size, 3), jnp.float32)
+    sample = sample_input(cfg)
     # The partitioner (parallel/partition.py) owns every TrainState
     # sharding decision: cfg.mesh.partition=replicated reproduces the
     # historical full-copy device_put; zero1 validates the rule set
@@ -289,7 +290,13 @@ def train(cfg: RunConfig, mesh=None, metrics: Optional[MetricsWriter] = None,
             # through an explicit cross-topology reshard (orbax stores
             # global logical arrays) — value-identical, never corrupted.
             with breakdown.phase("train.restore", keep=True) as ph:
-                state = ckpt.restore(state, discard_failed=True,
+                # The restore takes the fresh state's layout, not its
+                # buffers: they are freed first, so that two states never
+                # stand on the device together (a token model's is 6 GB).
+                template = partitioner.abstract_state(state)
+                for leaf in jax.tree_util.tree_leaves(state):
+                    leaf.delete()
+                state = ckpt.restore(template, discard_failed=True,
                                      parent=ph.id)
             log.info("resumed from step %d in %s",
                      int(jax.device_get(state.step)), cfg.train.train_dir)
@@ -342,7 +349,7 @@ def train(cfg: RunConfig, mesh=None, metrics: Optional[MetricsWriter] = None,
                                     xent_probe_batch=max(
                                         1, cfg.train.global_batch_size
                                         // mesh.shape["data"]),
-                                    partitioner=partitioner)
+                                    partitioner=partitioner, tokens=tokens)
         breakdown.end(xent_probe, decisions=ops.autotune.decisions())
         # zero1 compiles with the partitioner's state layout so the
         # optimizer-slot arguments are per-shard buffers; replicated
@@ -461,7 +468,7 @@ def train(cfg: RunConfig, mesh=None, metrics: Optional[MetricsWriter] = None,
         # cifar_input.py:118): the resident split's head, or the newest
         # streamed batch; augmented at write time so the summary shows what
         # the model actually saw.
-        last_inputs = images_np[:4] if resident else None
+        last_inputs = images_np[:4] if resident and not tokens else None
         while step < total:
             injector.maybe_sigterm(step)
             injector.maybe_oom(step)  # OOM-forensics drill (doctor)

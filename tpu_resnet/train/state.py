@@ -16,7 +16,8 @@ import optax
 class TrainState:
     step: jnp.ndarray          # int32 scalar — the reference's global_step
     params: Any
-    batch_stats: Any           # BN moving mean/var (fp32)
+    batch_stats: Any           # state no gradient reaches (fp32): BN
+                               # moving mean/var, a router's expert_bias
     opt_state: Any
 
     @classmethod
@@ -38,12 +39,32 @@ def build_optimizer(optim_cfg, schedule) -> optax.GradientTransformation:
         return optax.sgd(schedule)
     if optim_cfg.optimizer == "momentum":
         return optax.sgd(schedule, momentum=optim_cfg.momentum)
+    if optim_cfg.optimizer == "adamw":
+        # Decoupled decay on matrices only (norm weights and biases are
+        # left alone); the global-norm clip comes first, as a trainer of
+        # token models applies it.
+        adamw = optax.adamw(
+            schedule, b1=optim_cfg.adam_b1, b2=optim_cfg.adam_b2,
+            eps=optim_cfg.adam_eps, weight_decay=optim_cfg.weight_decay,
+            mask=lambda params: jax.tree_util.tree_map(
+                lambda p: p.ndim >= 2, params))
+        if optim_cfg.grad_clip_norm > 0:
+            return optax.chain(
+                optax.clip_by_global_norm(optim_cfg.grad_clip_norm), adamw)
+        return adamw
     raise ValueError(f"unknown optimizer {optim_cfg.optimizer!r}")
 
 
 def init_state(model, optim_cfg, schedule, rng: jax.Array,
                sample_batch: jnp.ndarray) -> TrainState:
-    variables = model.init(rng, sample_batch, train=False)
+    init = model.init
+    if jnp.issubdtype(sample_batch.dtype, jnp.integer):
+        # a token model: its forward pass as one program, not one small
+        # program an operation of every layer
+        from tpu_resnet.programs.registry import init_program
+
+        init = init_program(model)
+    variables = init(rng, sample_batch, train=False)
     params = variables["params"]
     batch_stats = variables.get("batch_stats", {})
     tx = build_optimizer(optim_cfg, schedule)

@@ -33,8 +33,10 @@ from typing import Callable, Optional
 import jax
 import jax.numpy as jnp
 import optax
+from flax.traverse_util import flatten_dict
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from tpu_resnet.models.afmoe import COUNTERS
 from tpu_resnet.train.state import TrainState, build_optimizer
 
 
@@ -47,6 +49,14 @@ def softmax_xent(logits: jnp.ndarray, labels: jnp.ndarray,
         onehot = (onehot * (1 - label_smoothing)
                   + label_smoothing / num_classes)
     return optax.softmax_cross_entropy(logits, onehot).mean()
+
+
+def token_xent(logits: jnp.ndarray, labels: jnp.ndarray) -> jnp.ndarray:
+    """Mean next-token cross-entropy: ``logits`` ``(B, S, V)`` float32,
+    ``labels`` ``(B, S)`` ids. The label's logit is picked out by
+    ``take_along_axis``; no one-hot of the vocabulary is built."""
+    picked = jnp.take_along_axis(logits, labels[..., None], axis=-1)[..., 0]
+    return jnp.mean(jax.nn.logsumexp(logits, axis=-1) - picked)
 
 
 def l2_weight_penalty(params, include_bn: bool) -> jnp.ndarray:
@@ -79,6 +89,36 @@ def check_step_config(cfg, data_axis: int) -> None:
     per_replica_bn = (not cfg.model.sync_bn) and data_axis > 1
     partition = check_partition_mode(
         getattr(cfg.mesh, "partition", "replicated"))
+    tokens = cfg.data.dataset == "tokens"
+    if tokens != (cfg.model.name == "afmoe"):
+        raise ValueError(
+            f"model {cfg.model.name!r} on dataset {cfg.data.dataset!r}: "
+            f"dataset 'tokens' feeds model 'afmoe' and nothing else does")
+    if cfg.optim.grad_clip_norm and cfg.optim.optimizer != "adamw":
+        raise ValueError("optim.grad_clip_norm is applied before adamw "
+                         "only; sgd and momentum take none")
+    if tokens:
+        refused = [
+            ("model.sync_bn=false on a multi-chip data axis (the "
+             "shard_map step would route each shard's tokens apart)",
+             per_replica_bn),
+            ("mesh.partition=zero1 (no rule shards expert or attention "
+             "leaves yet)", partition != "replicated"),
+            ("model.fused_blocks / model.fused_epilogue (ResNet kernels)",
+             cfg.model.fused_blocks or cfg.model.fused_epilogue != "off"),
+            ("optim.label_smoothing", cfg.optim.label_smoothing != 0.0),
+            ("optim.use_pallas_xent=on (the kernel one-hots class "
+             "labels; the token loss never consults it or its probe)",
+             str(cfg.optim.use_pallas_xent).lower() in ("on", "true", "1",
+                                                        "yes")),
+            ("an optimizer other than adamw (the L2 term of sgd and "
+             "momentum is not part of the token loss)",
+             cfg.optim.optimizer != "adamw"),
+        ]
+        bad = [what for what, is_set in refused if is_set]
+        if bad:
+            raise ValueError("a token model does not train with: "
+                             + "; ".join(bad))
     if partition == "zero1" and per_replica_bn:
         raise ValueError(
             "mesh.partition=zero1 on a multi-chip data axis requires "
@@ -108,8 +148,14 @@ def make_train_step(model, optim_cfg, schedule, num_classes: int,
                     mesh: Optional[Mesh] = None,
                     grad_axis: Optional[str] = None,
                     xent_probe_batch: int = 128,
-                    partitioner=None):
+                    partitioner=None, tokens: bool = False):
     """Returns ``train_step(state, images, labels) -> (state, metrics)``.
+
+    ``tokens``: the inputs are ``(B, S)`` ids and the labels the next ids.
+    The loss is ``token_xent``; no augmentation, no L2 term and no xent
+    probe are part of that path, and the metrics also carry the model's
+    routing counters (models/afmoe.py::COUNTERS, meaned over its expert
+    layers) and the ``tokens`` of the step.
 
     ``images`` may be raw uint8 (augment_fn applied on device) or
     pre-processed floats (augment_fn=None).
@@ -152,7 +198,7 @@ def make_train_step(model, optim_cfg, schedule, num_classes: int,
         # silently mean "off" while the operator believes the A/B runs.
         raise ValueError(f"optim.use_pallas_xent must be auto|on|off, "
                          f"got {optim_cfg.use_pallas_xent!r}")
-    use_pallas = (mode in ("on", "auto")
+    use_pallas = (mode in ("on", "auto") and not tokens
                   and optim_cfg.label_smoothing == 0.0
                   and is_tpu_backend())
     if use_pallas and mode == "auto":
@@ -160,6 +206,8 @@ def make_train_step(model, optim_cfg, schedule, num_classes: int,
                                        num_classes).use_pallas
     if use_pallas:
         _pallas_xent = make_pallas_xent(mesh if grad_axis is None else None)
+
+    collections = ["batch_stats"] + (["counters"] if tokens else [])
 
     def train_step(state: TrainState, images, labels):
         rng = jax.random.fold_in(base_rng, state.step)
@@ -181,8 +229,11 @@ def make_train_step(model, optim_cfg, schedule, num_classes: int,
             with jax.named_scope("forward"):
                 logits, new_model_state = model.apply(
                     {"params": params, "batch_stats": state.batch_stats},
-                    images, train=True, mutable=["batch_stats"])
+                    images, train=True, mutable=collections)
             with jax.named_scope("loss"):
+                if tokens:
+                    return token_xent(logits, labels), (logits,
+                                                        new_model_state)
                 if use_pallas:
                     xent = _pallas_xent(logits.astype(jnp.float32), labels)
                 else:
@@ -224,6 +275,13 @@ def make_train_step(model, optim_cfg, schedule, num_classes: int,
                 "learning_rate": schedule(state.step),
                 "grad_norm": optax.global_norm(grads),
             }
+            if tokens:
+                counters = flatten_dict(new_model_state.get("counters", {}))
+                for name in COUNTERS:
+                    layers = [v for k, v in counters.items() if k[-1] == name]
+                    if layers:  # a model of dense layers routes nothing
+                        metrics[name] = jnp.mean(jnp.stack(layers))
+                metrics["tokens"] = jnp.float32(labels.size)
         return new_state, metrics
 
     return train_step
