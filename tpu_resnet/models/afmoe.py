@@ -36,23 +36,30 @@ while no expert overflows. The counters say what happened
 (``moe_dropped_frac`` reads 0 by that construction and is counted from the
 dispatch tables all the same).
 
-Attention is computed a block of queries at a time against the keys its
-mask can reach (a ``(B, H, S, S)`` score tensor is never built), as a scan
-whose body is under ``jax.checkpoint``. Parameters, the residual stream,
-norms, router, softmax and logits are float32; matrix products take
-``dtype`` operands (bf16), accumulate in float32 and hand on ``dtype``.
+Attention never builds a ``(B, H, S, S)`` score tensor. On one TPU chip, at
+heads of a multiple of 128 and sequences its blocks divide, it is one fused
+kernel that keeps each tile of scores on the chip (``ops/attention.py``,
+which also says how the path is chosen); everywhere else a block of
+queries at a time against the keys its mask can reach, as a scan whose
+body is under ``jax.checkpoint`` (``blocked_attention``). Parameters, the
+residual stream, norms, router, softmax and logits are float32; matrix
+products take ``dtype`` operands (bf16), accumulate in float32 and hand on
+``dtype``.
 """
 
 from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Any, Tuple
+from typing import Any, Dict, List, Tuple
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
+
+from tpu_resnet.ops.attention import (attention_path, fused_attention,
+                                      key_blocks)
 
 # layer kinds: what F is, and which mask attention takes
 LAYER_KINDS = ("dense_sliding", "dense_full", "moe_sliding", "moe_full")
@@ -102,6 +109,13 @@ def rotary(x, theta: float):
     return x * cos + jnp.concatenate([-x2, x1], -1) * sin
 
 
+def _reach(seq_len: int, window: int, block: int) -> int:
+    """The positions before a block's first query that the scan's every
+    block takes keys from: the window, or on a full layer everything
+    before the last block."""
+    return min(window, seq_len - block) if window else seq_len - block
+
+
 def blocked_attention(q, k, v, doc, window: int, block: int, dtype):
     """Causal attention within documents, ``window`` > 0 for a sliding
     layer. ``q`` is ``(B, S, KV, G, D)`` (G query heads a key/value head),
@@ -120,7 +134,7 @@ def blocked_attention(q, k, v, doc, window: int, block: int, dtype):
     if s % block:
         raise ValueError(f"sequence length {s} is not a multiple of the "
                          f"attention block {block}")
-    reach = min(window, s - block) if window else s - block
+    reach = _reach(s, window, block)
     span = reach + block
     scale = 1.0 / math.sqrt(d)
     front = ((0, 0), (reach, 0))
@@ -182,10 +196,14 @@ class Attention(nn.Module):
             if self.window:
                 q, k = rotary(q, self.rope_theta), rotary(k, self.rope_theta)
         with jax.named_scope("scores"):
-            out = blocked_attention(
-                q.reshape(b, s, kv, h // kv, hd), k, v, doc, self.window,
-                self.block, self.dtype).reshape(b, s, h * hd)
-            out = checkpoint_name(out, "attention")
+            q = q.reshape(b, s, kv, h // kv, hd)
+            if attention_path(jax.default_backend(), jax.device_count(),
+                              hd, s) == "kernel":
+                out = fused_attention(q, k, v, doc, self.window, self.dtype)
+            else:
+                out = blocked_attention(q, k, v, doc, self.window,
+                                        self.block, self.dtype)
+            out = checkpoint_name(out.reshape(b, s, h * hd), "attention")
         with jax.named_scope("gate_out"):
             out = out * jax.nn.sigmoid(gate)
             return _dot(out, self.param("wo", _init, (h * hd, d), _f32),
@@ -392,7 +410,7 @@ class Arch:
     # overflow path is rare, and the fast path's cost does not turn on
     # the routing.
     fast_slack: float = 4.0
-    attn_block: int = 256              # queries a block of attention
+    attn_block: int = 256              # queries a block of the scan path
     remat: bool = False                # each layer's backward keeps _KEEP
     dtype: Any = jnp.bfloat16
 
@@ -438,6 +456,30 @@ class Afmoe(nn.Module):
             return _dot(RMSNorm(m.eps, name="final_norm")(h),
                         self.param("head", _init, (m.hidden, m.vocab_rows),
                                    _f32), m.dtype, out=_f32)
+
+
+def attention_paths(model: Arch, seq_len: int, backend: str,
+                    devices: int) -> List[Dict[str, object]]:
+    """For each layer, the ``path`` its attention takes at ``seq_len`` on
+    ``devices`` of ``backend`` and, in tiles of queries by keys, ``key_blocks_visited``
+    of ``key_blocks_total``: the kernel's from its own mask table, the
+    scan's from its uniform span of ``reach + block`` keys a block. What
+    ``train()`` says once, as the event ``attention_path``."""
+    path = attention_path(backend, devices, model.head_dim, seq_len)
+    block = min(model.attn_block, seq_len)
+    rows = []
+    for i, kind in enumerate(model.layers):
+        window = model.window if kind.endswith("_sliding") else 0
+        if path == "kernel":
+            visited, total = key_blocks(seq_len, window,
+                                        model.heads // model.kv_heads)
+        else:
+            span = _reach(seq_len, window, block) + block
+            visited = (seq_len // block) * -(-span // block)
+            total = (seq_len // block) ** 2
+        rows.append(dict(layer=i, kind=kind, path=path,
+                         key_blocks_visited=visited, key_blocks_total=total))
+    return rows
 
 
 def multiply_adds_per_token(model: Arch, seq_len: int) -> float:

@@ -31,6 +31,7 @@ from tpu_resnet.data import augment as aug_lib
 from tpu_resnet.data import device_data
 from tpu_resnet.data import pipeline
 from tpu_resnet.models import build_model, sample_input
+from tpu_resnet.models.afmoe import attention_paths
 from tpu_resnet.tools import profiling
 from tpu_resnet.train import schedule as sched_lib
 from tpu_resnet.train.checkpoint import CheckpointManager
@@ -205,6 +206,12 @@ def train(cfg: RunConfig, mesh=None, metrics: Optional[MetricsWriter] = None,
         extra=({"topology_change": elastic_ctx.attrs()}
                if elastic_ctx is not None and elastic_ctx.changed
                else None))
+    if tokens:
+        # static, so said once: the path each layer's attention takes here
+        # and the key blocks its mask leaves (docs/OBSERVABILITY.md)
+        spans.event("attention_path", layers=attention_paths(
+            model.arch, cfg.data.seq_len, jax.default_backend(),
+            jax.device_count()))
     from tpu_resnet.obs.server import CORE_HISTOGRAMS
     telemetry = obs.TelemetryRegistry(
         stale_after_sec=cfg.train.telemetry_stale_sec,
