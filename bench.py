@@ -312,13 +312,12 @@ def _train_step_comms(compiled, mesh):
 
 def _measure_imagenet(mesh, warmup_steps, measure_steps, resnet_size=50,
                       batch=128, image=224, dtype="bfloat16",
-                      stem_s2d=None, mutate_cfg=None):
+                      stem_s2d=None):
     """ImageNet-shaped training step: ResNet-50 @ 224, batch 128, bf16,
     synthetic pre-processed input resident on device. Returns
     (steps/s, flops_per_step or None, comms bench fields — possibly {}).
     ``stem_s2d`` overrides model.stem_space_to_depth (None = config
-    default) for the stem A/B; ``mutate_cfg`` as in
-    ``_build_train_setup``."""
+    default) for the stem A/B."""
     import jax
     import numpy as np
 
@@ -327,7 +326,7 @@ def _measure_imagenet(mesh, warmup_steps, measure_steps, resnet_size=50,
 
     cfg, model, sched, state, rng = _build_train_setup(
         mesh, "imagenet", resnet_size=resnet_size, batch=batch,
-        dtype=dtype, image=image, mutate_cfg=mutate_cfg)
+        dtype=dtype, image=image)
     if stem_s2d is not None and stem_s2d != cfg.model.stem_space_to_depth:
         from tpu_resnet.models import build_model
         cfg.model.stem_space_to_depth = stem_s2d

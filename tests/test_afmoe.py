@@ -304,7 +304,7 @@ def test_ten_steps_of_the_program_follow_the_reference():
     state = init_state(model, cfg.optim, schedule, jax.random.PRNGKey(3),
                        sample_input(cfg))
     step = jax.jit(make_train_step(model, cfg.optim, schedule,
-                                   cfg.data.num_classes, tokens=True))
+                                   cfg.data.num_classes))
     before = family.snapshot(state)
     assert before["moments"] == 0.0
     xs, ys = zip(*(tokens(seed, batch=8) for seed in range(4)))

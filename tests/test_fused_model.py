@@ -188,17 +188,24 @@ def test_fused_composes_with_remat(setup):
 def test_imagenet_basic_nets_accept_fused_blocks():
     """ImageNet ResNet-18/34 fused dispatch (VERDICT r4 item 8 — replaces
     the old rejection test): the basic-block stages at 56²/28²/14² get
-    VMEM-derived tile plans; bottleneck sizes keep FusedBottleneckBlock."""
+    VMEM-derived tile plans; a bottleneck size with the switch raises, on
+    every backend (the switch means basic blocks and nothing else)."""
     from tpu_resnet.config import load_config
     from tpu_resnet.models import build_model
     from tpu_resnet.models.resnet import ResNetV2
 
     cfg = load_config("imagenet")
     cfg.model.fused_blocks = True
-    for size in (18, 50):
+    for size in (18, 34):
         cfg.model.resnet_size = size
         model = build_model(cfg)
         assert isinstance(model, ResNetV2) and model.fused_blocks
+    for size in (50, 101, 152, 200):
+        cfg.model.resnet_size = size
+        with pytest.raises(ValueError, match="basic blocks only"):
+            build_model(cfg)
+    cfg.model.fused_blocks = False
+    assert not build_model(cfg).fused_blocks
 
 
 def test_auto_batch_tile_plans():
@@ -255,9 +262,9 @@ def test_imagenet_rn18_fused_forward_equivalence():
 
 
 def test_imagenet_basic_512_stage_stays_xla():
-    """The planless 7²x512 stage must dispatch to the XLA BuildingBlock —
-    hybrid dispatch, mirroring the f=512 bottleneck exclusion."""
-    from tpu_resnet.models.resnet import BlockLayer
+    """The planless 7²x512 stage must dispatch to the XLA BuildingBlock
+    (hybrid dispatch), and a stage of bottleneck blocks never fuses."""
+    from tpu_resnet.models.resnet import BlockLayer, imagenet_resnet_v2
 
     x = jnp.zeros((2, 7, 7, 512), jnp.float32)
     layer = BlockLayer(filters=512, blocks=2, strides=1, bottleneck=False,
@@ -268,6 +275,16 @@ def test_imagenet_basic_512_stage_stays_xla():
     variables = layer.init(jax.random.PRNGKey(0), x, train=False)
     y = layer.apply(variables, x, train=False)
     assert y.shape == x.shape
+    with pytest.raises(ValueError, match="basic blocks only"):
+        imagenet_resnet_v2(50, 1000, fused_blocks=True)
+    # a BlockLayer built directly: bottleneck blocks stay on XLA
+    xb = jnp.zeros((2, 8, 8, 64), jnp.float32)
+    stage = BlockLayer(filters=16, blocks=2, strides=1, bottleneck=True,
+                       dtype=jnp.float32, fused=True)
+    text = str(jax.make_jaxpr(lambda v: stage.apply(
+        stage.init(jax.random.PRNGKey(0), xb, train=False), v,
+        train=False))(xb))
+    assert "pallas_call" not in text
 
 
 @pytest.mark.slow  # 31s: default-OFF feature; the shard_map 8-device
@@ -396,40 +413,18 @@ def test_fused_blocks_rejected_for_wide_resnet():
         build_model(cfg)
 
 
-def test_fused_bottleneck_switch_raises_on_a_tpu_backend(monkeypatch):
-    """The bottleneck family does not build on the chip yet (backward
-    kernels refused by Mosaic — ROADMAP C4): on a TPU backend its switch
-    raises with the compiler's message. It never trains on an interpreter
-    or a silent XLA substitute; elsewhere (CPU, interpret mode) it builds,
-    and the basic-block nets are not affected."""
-    from tpu_resnet import ops
-    from tpu_resnet.config import load_config
-    from tpu_resnet.models import build_model
-
-    cfg = load_config("imagenet")
-    cfg.model.fused_blocks = True
-    assert build_model(cfg).fused_blocks        # CPU: interpret-mode path
-    monkeypatch.setattr(ops, "is_tpu_backend", lambda: True)
-    with pytest.raises(NotImplementedError, match="RESOURCE_EXHAUSTED"):
-        build_model(cfg)
-    cfg.model.resnet_size = 18                  # basic blocks: untouched
-    assert build_model(cfg).fused_blocks
-    cfg.model.resnet_size, cfg.model.fused_blocks = 50, False
-    assert not build_model(cfg).fused_blocks
-
-
 def test_direct_constructors_carry_the_same_fused_guards():
     """ADVICE r4: the fused_blocks guards must live in the generators,
     not only build_model — a direct cifar_resnet_v2 call must fail with
-    the same clear message, not an obscure downstream tile error. (The
-    old 18/34 rejection is gone: those sizes now carry tile plans —
-    VERDICT r4 item 8.)"""
+    the same clear message, not an obscure downstream tile error: the
+    constructors hold the guards and build_model repeats none. (The old
+    18/34 rejection is gone: those sizes now carry tile plans — VERDICT
+    r4 item 8.)"""
     from tpu_resnet.models.resnet import cifar_resnet_v2, imagenet_resnet_v2
 
     with pytest.raises(ValueError, match="width_multiplier"):
         cifar_resnet_v2(28, 100, width_multiplier=10, fused_blocks=True)
     assert imagenet_resnet_v2(18, 1000, fused_blocks=True).fused_blocks
-    assert imagenet_resnet_v2(50, 1000, fused_blocks=True).fused_blocks
 
 
 def test_fused_blocks_reject_sync_bn_axis():
@@ -445,102 +440,3 @@ def test_fused_blocks_reject_sync_bn_axis():
     with pytest.raises(ValueError, match="sync-BN"):
         layer.init(jax.random.PRNGKey(0), jnp.zeros((2, 8, 8, 16)),
                    train=True)
-
-
-# --- FusedBottleneckBlock (ImageNet generator) ---------------------------
-
-BF = 64                      # smallest width with a default tile plan
-
-
-def _bottleneck_pair():
-    from tpu_resnet.models.resnet import (BottleneckBlock,
-                                          FusedBottleneckBlock)
-    xla = BottleneckBlock(BF, 1, False, jnp.float32)
-    fused = FusedBottleneckBlock(BF, jnp.float32)
-    return xla, fused
-
-
-@pytest.fixture(scope="module")
-def bsetup():
-    xla, fused = _bottleneck_pair()
-    rng = np.random.default_rng(3)
-    x = jnp.asarray(rng.normal(size=(2, 8, 8, 4 * BF)), jnp.float32)
-    variables = xla.init(jax.random.PRNGKey(0), x, True)
-    return xla, fused, variables, x
-
-
-def test_bottleneck_param_tree_identical(bsetup):
-    xla, fused, variables, x = bsetup
-    fused_vars = fused.init(jax.random.PRNGKey(0), x, True)
-    assert (jax.tree.map(lambda a: (a.shape, a.dtype), variables)
-            == jax.tree.map(lambda a: (a.shape, a.dtype), fused_vars))
-
-
-def test_bottleneck_eval_forward_equivalence(bsetup):
-    xla, fused, variables, x = bsetup
-    y_xla = xla.apply(variables, x, False)
-    y_fused = fused.apply(variables, x, False)
-    np.testing.assert_allclose(np.asarray(y_fused), np.asarray(y_xla),
-                               rtol=1e-4, atol=1e-4)
-
-
-def test_bottleneck_train_forward_stats_and_grads(bsetup):
-    xla, fused, variables, x = bsetup
-    y_xla, upd_xla = xla.apply(variables, x, True,
-                               mutable=["batch_stats"])
-    y_fused, upd_fused = fused.apply(variables, x, True,
-                                     mutable=["batch_stats"])
-    np.testing.assert_allclose(np.asarray(y_fused), np.asarray(y_xla),
-                               rtol=1e-4, atol=1e-4)
-    flat_x = jax.tree_util.tree_leaves_with_path(upd_xla)
-    flat_f = dict(jax.tree_util.tree_leaves_with_path(upd_fused))
-    for path, leaf in flat_x:
-        np.testing.assert_allclose(
-            np.asarray(flat_f[path]), np.asarray(leaf),
-            rtol=1e-4, atol=1e-5, err_msg=jax.tree_util.keystr(path))
-
-    def loss_for(model):
-        def loss(params):
-            y, _ = model.apply(
-                {"params": params,
-                 "batch_stats": variables["batch_stats"]},
-                x, True, mutable=["batch_stats"])
-            return jnp.mean(y ** 2)
-        return loss
-
-    g_xla = jax.grad(loss_for(xla))(variables["params"])
-    g_fused = jax.grad(loss_for(fused))(variables["params"])
-    flat_x = jax.tree_util.tree_leaves_with_path(g_xla)
-    flat_f = dict(jax.tree_util.tree_leaves_with_path(g_fused))
-    for path, leaf in flat_x:
-        np.testing.assert_allclose(
-            np.asarray(flat_f[path]), np.asarray(leaf),
-            rtol=5e-3, atol=1e-5, err_msg=jax.tree_util.keystr(path))
-
-
-@pytest.mark.slow
-def test_imagenet_rn50_fused_model_forward():
-    """Whole-model dispatch: rn50 at 64-pixel inputs (stages 16/8/4/2 —
-    the f=512 stage stays XLA by width policy) matches the XLA model in
-    both modes with shared variables. 64², batch 4 keeps every train-mode
-    BN normalizing over >=16 elements: at 32² the f=512 stage runs 1×1
-    spatial and its 2-element batch variance is near-singular, amplifying
-    the fused stages' benign 1e-6 diffs past any tolerance."""
-    from tpu_resnet.models.resnet import imagenet_resnet_v2
-
-    rng = np.random.default_rng(5)
-    x = jnp.asarray(rng.normal(size=(4, 64, 64, 3)), jnp.float32)
-    xla_model = imagenet_resnet_v2(50, 100, dtype=jnp.float32)
-    fused_model = imagenet_resnet_v2(50, 100, dtype=jnp.float32,
-                                     fused_blocks=True)
-    variables = xla_model.init(jax.random.PRNGKey(0), x, train=True)
-    y_xla = xla_model.apply(variables, x, train=False)
-    y_fused = fused_model.apply(variables, x, train=False)
-    np.testing.assert_allclose(np.asarray(y_fused), np.asarray(y_xla),
-                               rtol=1e-4, atol=1e-4)
-    t_xla, _ = xla_model.apply(variables, x, train=True,
-                               mutable=["batch_stats"])
-    t_fused, _ = fused_model.apply(variables, x, train=True,
-                                   mutable=["batch_stats"])
-    np.testing.assert_allclose(np.asarray(t_fused), np.asarray(t_xla),
-                               rtol=1e-3, atol=1e-3)

@@ -18,7 +18,7 @@ import fused_model_ab  # noqa: E402
 @pytest.mark.slow
 def test_ab_tiny_config(tmp_path, monkeypatch):
     """Full A/B harness (two model compiles) — battery stage 15 runs it
-    unattended; slow-tiered like its imagenet sibling below."""
+    unattended; slow-tiered."""
     out = tmp_path / "ab.json"
     monkeypatch.setattr(sys, "argv", [
         "fused_model_ab.py", "--resnet-size", "14", "--batch", "8",
@@ -29,20 +29,3 @@ def test_ab_tiny_config(tmp_path, monkeypatch):
     assert got["steps_per_sec"]["xla"] > 0
     assert got["steps_per_sec"]["fused"] > 0
     assert "fused_speedup" in got
-
-
-@pytest.mark.slow
-def test_ab_tiny_imagenet_config(tmp_path, monkeypatch):
-    """The --preset imagenet path (FusedBottleneckBlock dispatch through
-    bench._measure_imagenet) at tiny shapes — battery stage 56 runs it
-    unattended."""
-    out = tmp_path / "ab_in.json"
-    monkeypatch.setattr(sys, "argv", [
-        "fused_model_ab.py", "--preset", "imagenet", "--image", "32",
-        "--batch", "8", "--warmup-steps", "1", "--measure-steps", "1",
-        "--out", str(out)])
-    fused_model_ab.main()
-    got = json.load(open(out))
-    assert got["preset"] == "imagenet"
-    assert got["steps_per_sec"]["xla"] > 0
-    assert got["steps_per_sec"]["fused"] > 0

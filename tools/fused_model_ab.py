@@ -2,9 +2,7 @@
 ``model.fused_blocks`` on vs off through the REAL headline measurement
 path — resident HBM split, on-device augmentation, fused multi-step
 dispatch, fetch-synced timing (bench._measure_cifar) — at the CIFAR
-ResNet-50 b128 configuration the driver benches. ``--preset imagenet``
-runs the same A/B through bench._measure_imagenet (ResNet-50 @224 b128
-bf16, FusedBottleneckBlock dispatch) instead.
+ResNet-50 b128 configuration the driver benches.
 
 Battery stage 05 (tools/fused_block_ab.py) decides at the KERNEL level
 (isolated block shapes, both directions); this measures what the headline
@@ -26,14 +24,6 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 def main() -> int:
     ap = argparse.ArgumentParser()
-    ap.add_argument("--preset", default="cifar10",
-                    choices=["cifar10", "imagenet"])
-    ap.add_argument("--image", type=int, default=224,
-                    help="imagenet only: input resolution")
-    ap.add_argument("--warmup-steps", type=int, default=3,
-                    help="imagenet only")
-    ap.add_argument("--measure-steps", type=int, default=12,
-                    help="imagenet only")
     ap.add_argument("--resnet-size", type=int, default=None,
                     help="default: the preset's 50")
     ap.add_argument("--batch", type=int, default=128)
@@ -42,17 +32,9 @@ def main() -> int:
     ap.add_argument("--warmup-chunks", type=int, default=2)
     ap.add_argument("--measure-chunks", type=int, default=6)
     ap.add_argument("--batch-tile", type=int, default=None,
-                    help="fused-kernel forward batch tile (cifar only; "
-                         "the bottleneck kernels use their own sized "
-                         "tile plans)")
+                    help="fused-kernel forward batch tile")
     ap.add_argument("--out", default="")
     args = ap.parse_args()
-    if args.preset == "imagenet" and args.batch_tile is not None:
-        # FusedBottleneckBlock has no tile knob (ops-level _DEFAULT_TILES
-        # govern) — fail loudly rather than record a tile that was never
-        # applied (the repo's conflicting-override convention).
-        raise SystemExit("--batch-tile does not apply to --preset "
-                         "imagenet (bottleneck tile plans are fixed)")
 
     import bench
     from tpu_resnet.parallel import create_mesh
@@ -65,31 +47,21 @@ def main() -> int:
             cfg.model.fused_blocks = fused
             if args.batch_tile is not None:
                 cfg.model.fused_block_tile = args.batch_tile
-        if args.preset == "imagenet":
-            sps, _flops, _comms = bench._measure_imagenet(
-                mesh, args.warmup_steps, args.measure_steps,
-                resnet_size=args.resnet_size or 50, batch=args.batch,
-                image=args.image, mutate_cfg=mutate)
-        else:
-            sps = bench._measure_cifar(
-                mesh, plans, resnet_size=args.resnet_size,
-                batch=args.batch, split=args.split,
-                mutate_cfg=mutate)[args.steps_per_call]
+        sps = bench._measure_cifar(
+            mesh, plans, resnet_size=args.resnet_size,
+            batch=args.batch, split=args.split,
+            mutate_cfg=mutate)[args.steps_per_call]
         arms[name] = sps
         print(f"[fused_model_ab] {name}: {sps:.2f} st/s", flush=True)
 
-    what_cifar = ("model.fused_blocks A/B through the headline resident "
-                  "path (fetch-synced, steps_per_call="
-                  f"{args.steps_per_call}, b{args.batch})")
-    what_imagenet = ("model.fused_blocks A/B through the ImageNet train "
-                     f"step (fetch-synced, @{args.image} b{args.batch}, "
-                     "FusedBottleneckBlock dispatch)")
     # Ratios from the UNROUNDED rates, with zero guards: a degenerate
     # measurement (0.0 steps/s) must record an artifact, not crash the
     # battery stage with ZeroDivisionError (ADVICE r4).
     out = {
-        "what": what_imagenet if args.preset == "imagenet" else what_cifar,
-        "preset": args.preset,
+        "what": ("model.fused_blocks A/B through the headline resident "
+                 "path (fetch-synced, steps_per_call="
+                 f"{args.steps_per_call}, b{args.batch})"),
+        "preset": "cifar10",
         "resnet_size": args.resnet_size or 50,
         "batch": args.batch,
         "steps_per_sec": {k: round(v, 2) for k, v in arms.items()},

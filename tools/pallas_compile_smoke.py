@@ -6,7 +6,7 @@ they lower to ordinary XLA ops — so the first Mosaic compile of a kernel
 is on a TPU, and a lowering error or a VMEM plan that does not fit shows
 up only there. This tool is that first compile, outside any training run:
 
-    python tools/pallas_compile_smoke.py                 # all four families
+    python tools/pallas_compile_smoke.py                 # all three families
     python tools/pallas_compile_smoke.py --family xent --family epilogue
 
 Families and shapes (batch 128, the per-chip batch of the rn50 configs):
@@ -18,9 +18,6 @@ Families and shapes (batch 128, the per-chip batch of the rn50 configs):
 - ``block``       ops/fused_block.py at the CIFAR rn50 stages (16/32/64
                   channels at 32/16/8): folded forward + VJP, live-stats
                   forward + three-pass VJP (bf16).
-- ``bottleneck``  ops/fused_bottleneck.py at the ImageNet rn50 stages
-                  (f=64/128/256 at 56/28/14): folded forward + VJP,
-                  live-stats forward + four-pass VJP (bf16).
 
 A check passes when ``max|got - want| / max(1, max|want|) < 2e-2`` — wide
 enough for bf16 activations and the MXU's default matmul precision on
@@ -43,7 +40,7 @@ import time
 sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
-FAMILIES = ("xent", "epilogue", "block", "bottleneck")
+FAMILIES = ("xent", "epilogue", "block")
 TOL = 2e-2
 BATCH = 128
 
@@ -182,52 +179,8 @@ def _cases_block(interpret, tiny):
         yield f"b{b}_{h}x{h}x{c}", case
 
 
-def _cases_bottleneck(interpret, tiny):
-    import jax
-    import jax.numpy as jnp
-
-    from tpu_resnet.ops import fused_bottleneck as fbn
-
-    for b, h, f in ([(1, 14, 64)] if tiny else
-                    [(BATCH, 56, 64), (BATCH, 28, 128), (BATCH, 14, 256)]):
-        def case(b=b, h=h, f=f):
-            c4 = 4 * f
-            ks = jax.random.split(jax.random.PRNGKey(f), 8)
-            dtype = jnp.float32 if tiny else jnp.bfloat16
-            x = jax.random.normal(ks[0], (b, h, h, c4), dtype)
-            w1 = jax.random.normal(ks[1], (c4, f)) * 0.05
-            w2 = jax.random.normal(ks[2], (3, 3, f, f)) * 0.05
-            w3 = jax.random.normal(ks[3], (f, c4)) * 0.05
-            g4 = jax.random.uniform(ks[4], (c4,), jnp.float32, 0.5, 1.5)
-            be4 = jax.random.normal(ks[5], (c4,)) * 0.1
-            g = jax.random.uniform(ks[6], (f,), jnp.float32, 0.5, 1.5)
-            be = jax.random.normal(ks[7], (f,)) * 0.1
-            args = (x, w1, w2, w3, g4, be4, g, be, g, be)
-            # Default tile plans: what FusedBottleneckBlock dispatches.
-            apply = lambda *a: fbn.bottleneck_apply(*a, None, None,
-                                                    interpret)
-            train = lambda *a: fbn.bottleneck_train_apply(
-                *a, 1e-5, None, None, interpret)
-            return {
-                "fwd": lambda: _err(apply(*args),
-                                    fbn.bottleneck_fwd_reference(*args)),
-                "bwd": lambda: _err(
-                    jax.grad(_sq(apply))(args),
-                    jax.grad(_sq(fbn.bottleneck_fwd_reference))(args)),
-                "train_fwd": lambda: _err(
-                    train(*args),
-                    fbn.bottleneck_train_fwd_reference(*args)),
-                "train_bwd": lambda: _err(
-                    jax.grad(_sq(train))(args),
-                    jax.grad(_sq(
-                        fbn.bottleneck_train_fwd_reference))(args)),
-            }
-
-        yield f"b{b}_{h}x{h}_f{f}", case
-
-
 _CASES = {"xent": _cases_xent, "epilogue": _cases_epilogue,
-          "block": _cases_block, "bottleneck": _cases_bottleneck}
+          "block": _cases_block}
 
 
 def run_family(family, interpret, tiny):
@@ -264,7 +217,7 @@ def run_family(family, interpret, tiny):
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--family", action="append", choices=FAMILIES,
-                    help="repeatable; default: all four")
+                    help="repeatable; default: all three")
     ap.add_argument("--interpret", action="store_true",
                     help="run under the Pallas interpreter (CPU harness "
                          "test); default compiles for the ambient backend")

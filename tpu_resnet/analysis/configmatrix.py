@@ -225,8 +225,6 @@ MATRIX: Tuple[MatrixEntry, ...] = (
        dtype="bfloat16", s2d=False),
     _e("imagenet_rn50_bf16", dataset="imagenet", size=50,
        dtype="bfloat16"),
-    _e("imagenet_rn50_bf16_fused", dataset="imagenet", size=50,
-       dtype="bfloat16", fused=True),
     # --- fused Pallas epilogues (ops/epilogue.py, MFU campaign) -------
     # "on" pins the kernel-everywhere program (what a forced run and the
     # CPU parity tests compile); the per-replica row pins the supported

@@ -246,8 +246,8 @@ GUARD_PARITY_REQS = (
      "compute per-replica BN (ADVICE r4)"),
     ("tpu_resnet/models/resnet.py", "cifar_resnet_v2",
      "guard:fused_blocks&width_multiplier",
-     "the build_model width_multiplier guard must also fail direct "
-     "constructor calls (ADVICE r4)"),
+     "the width_multiplier guard lives in the constructor, so that "
+     "direct calls fail like build_model's (ADVICE r4)"),
     ("tpu_resnet/models/resnet.py", "imagenet_resnet_v2",
      "calls:_check_fused_bn_axis",
      "sync-BN (bn_axis_name) + fused_blocks must raise, not silently "
@@ -256,9 +256,6 @@ GUARD_PARITY_REQS = (
      "calls:_check_fused_bn_axis",
      "the fused dispatch must re-check bn_axis_name at apply time — "
      "BlockLayer is constructible directly (ADVICE r4)"),
-    ("tpu_resnet/models/__init__.py", "build_model",
-     "guard:fused_blocks&width_multiplier",
-     "the config-level guard that the constructor guards mirror"),
 )
 
 
@@ -886,7 +883,7 @@ def rule_sharding_scope(tree: SourceTree) -> List[Finding]:
 
 
 def rule_guard_parity(tree: SourceTree) -> List[Finding]:
-    """build_model validation mirrored into public constructors (ADVICE r4)."""
+    """The fused-kernel guards live in the public constructors (ADVICE r4)."""
     findings = []
 
     def find_fn(mod: ast.AST, qualname: str) -> Optional[ast.FunctionDef]:

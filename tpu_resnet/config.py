@@ -213,8 +213,8 @@ class ModelConfig:
 
 @dataclasses.dataclass
 class AfmoeConfig:
-    """The fields of ``model.name=afmoe`` (models/afmoe.py::Arch has the
-    equations): a sparse-expert transformer as one chip of an
+    """The fields of ``model.name=afmoe`` (the family's module has the
+    equations, at ``Arch``): a sparse-expert transformer as one chip of an
     expert-parallel group holds it. The defaults are the published widths
     of the preset's source; the vocabulary's rows held here are
     ``data.vocab_size``."""

@@ -509,3 +509,54 @@ def train_flops_per_sequence(model: Arch, seq_len: int) -> float:
     """Forward and backward model FLOPs of one sequence: 3 x 2 x
     multiply-adds (nothing recomputed counts)."""
     return 6.0 * multiply_adds_per_token(model, seq_len) * seq_len
+
+
+# ------------------------------------------------------------------ family
+# What models/__init__.py registers as the family ``afmoe``, with
+# ``COUNTERS`` above.
+def build(cfg) -> Afmoe:
+    a = cfg.afmoe
+    return Afmoe(Arch(
+        layers=tuple(a.layers), hidden=a.hidden, heads=a.heads,
+        kv_heads=a.kv_heads, head_dim=a.head_dim, window=a.window,
+        dense_width=a.dense_width, expert_width=a.expert_width,
+        experts_total=a.experts_total,
+        experts_held=(a.experts_first, a.experts_held), top_k=a.top_k,
+        shared=a.shared, vocab_rows=cfg.data.num_classes,
+        rope_theta=a.rope_theta, eps=a.rms_eps,
+        route_scale=a.route_scale, balance_coeff=a.balance_coeff,
+        remat=cfg.model.remat, dtype=jnp.dtype(cfg.model.compute_dtype)))
+
+
+def spell(cfg):
+    """Depth, the experts held of the router's width, and the sequence
+    length each change the traced program."""
+    a = cfg.afmoe
+    return (f"tokens{cfg.data.seq_len}",
+            f"afmoe{len(a.layers)}l_e{a.experts_held}of{a.experts_total}")
+
+
+def train_flops_per_example(cfg, xla_counted: bool = True) -> float:
+    """Counted from the shapes: XLA's count of the lowered step would
+    hold what attention recomputes backward."""
+    return train_flops_per_sequence(build(cfg).arch, cfg.data.seq_len)
+
+
+def refuses(cfg, data_axis: int):
+    """What of ``cfg`` this family does not train with, beside what no
+    token model does (train/step.py::check_step_config)."""
+    refused = [
+        ("mesh.partition=zero1 (no rule shards expert or attention "
+         "leaves yet)", cfg.mesh.partition != "replicated"),
+        ("model.fused_blocks / model.fused_epilogue (ResNet kernels)",
+         cfg.model.fused_blocks or cfg.model.fused_epilogue != "off"),
+    ]
+    return [what for what, is_set in refused if is_set]
+
+
+def startup_events(model: Afmoe, cfg):
+    """Static, so said once: the path each layer's attention takes here
+    and the key blocks its mask leaves (docs/OBSERVABILITY.md)."""
+    return {"attention_path": {"layers": attention_paths(
+        model.arch, cfg.data.seq_len, jax.default_backend(),
+        jax.device_count())}}

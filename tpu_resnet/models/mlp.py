@@ -16,6 +16,8 @@ from typing import Any
 import flax.linen as nn
 import jax.numpy as jnp
 
+from tpu_resnet.models.resnet import image_dataset
+
 
 class MLP(nn.Module):
     hidden_units: int = 100
@@ -39,3 +41,14 @@ class MLP(nn.Module):
                 1.0 / math.sqrt(self.hidden_units)),
             name="softmax_linear")(x)
         return jnp.asarray(x, jnp.float32)
+
+
+# What models/__init__.py registers as the family ``mlp``.
+def build(cfg) -> MLP:
+    return MLP(hidden_units=cfg.model.mlp_hidden_units,
+               num_classes=cfg.data.num_classes,
+               image_size=cfg.data.resolved_image_size)
+
+
+def spell(cfg):
+    return image_dataset(cfg), "mlp"

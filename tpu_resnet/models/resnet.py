@@ -335,11 +335,6 @@ class FusedBuildingBlock(nn.Module):
         return fb.block_apply(x, w1, w2, s1, b1, s2, b2, bt)
 
 
-# Bottleneck widths whose fused-kernel tile plans are sized for core
-# VMEM (ops/fused_bottleneck.py::_DEFAULT_TILES); f=512 blocks stay XLA.
-_FUSED_BOTTLENECK_WIDTHS = frozenset((64, 128, 256))
-
-
 def _check_fused_bn_axis(fused_blocks: bool, bn_axis_name) -> None:
     """Fail-loud convention (ADVICE r4): the fused kernels compute batch
     moments per replica with no cross-device axis sync — a sync-BN
@@ -350,74 +345,18 @@ def _check_fused_bn_axis(fused_blocks: bool, bn_axis_name) -> None:
                          "(bn_axis_name); unset one of the two")
 
 
-def _check_epilogue_bn_axis(fused_epilogue: str, bn_axis_name) -> None:
-    """Same fail-loud convention for the fused BN+ReLU epilogues: the
-    manual-moments epilogue path computes batch statistics per replica
-    with no cross-device axis sync — sync-BN via ``bn_axis_name`` must
-    raise, not silently degrade (mirrors _check_fused_bn_axis)."""
+def _check_epilogue(fused_epilogue: str, bn_axis_name) -> None:
+    """Same fail-loud convention for the fused BN+ReLU epilogues: a typo
+    must not mean "off" while the operator believes the kernels run, and
+    the manual-moments epilogue path computes batch statistics per
+    replica with no cross-device axis sync — sync-BN via ``bn_axis_name``
+    must raise, not silently degrade (mirrors _check_fused_bn_axis)."""
+    if fused_epilogue not in ("off", "on", "auto"):
+        raise ValueError(f"fused_epilogue must be off|on|auto, got "
+                         f"{fused_epilogue!r}")
     if fused_epilogue != "off" and bn_axis_name is not None:
         raise ValueError("fused_epilogue does not implement sync-BN "
                          "(bn_axis_name); unset one of the two")
-
-
-class FusedBottleneckBlock(nn.Module):
-    """BottleneckBlock (stride 1, identity shortcut) executed as the
-    halo-tiled fused Pallas bottleneck kernel family
-    (tpu_resnet/ops/fused_bottleneck.py) — the ImageNet analog of
-    FusedBuildingBlock, built to cut the block-internal HBM traffic that
-    parks ImageNet MFU at the ~37% roofline (docs/PERF.md).
-
-    Parameter/stat tree is IDENTICAL to BottleneckBlock (asserted by
-    tests/test_fused_model.py), so checkpoints interchange. Training uses
-    ``bottleneck_train_apply`` (live batch moments for all three BNs,
-    four-pass correction backward) with the flax EMA; eval folds running
-    stats into ``bottleneck_apply``. Same BN-semantics caveat as
-    FusedBuildingBlock (single-device is the measured path; battery
-    stage 55 is the gate).
-    """
-
-    filters: int
-    dtype: Dtype = jnp.float32
-
-    @nn.compact
-    def __call__(self, x, train: bool):
-        import jax
-
-        from tpu_resnet.ops import fused_bottleneck as fbn
-
-        f = self.filters
-        c4 = 4 * f
-        g1, be1, mean1, var1 = _BNSite(c4, name="preact")()
-        w1 = _ConvSite(f, c4, 1, name="conv1")()
-        g2, be2, mean2, var2 = _BNSite(f, name="bnrelu1")()
-        w2 = _ConvSite(f, f, 3, name="conv2")()
-        g3, be3, mean3, var3 = _BNSite(f, name="bnrelu2")()
-        w3 = _ConvSite(c4, f, 1, name="conv3")()
-        w1m, w3m = w1[0, 0], w3[0, 0]   # 1×1 kernels as matrices
-
-        if train:
-            y, (bm1, bv1, bm2, bv2, bm3, bv3) = fbn.bottleneck_train_apply(
-                x, w1m, w2, w3m, g1, be1, g2, be2, g3, be3,
-                _BATCH_NORM_EPSILON)
-            if not self.is_initializing():
-                m = _BATCH_NORM_MOMENTUM  # flax EMA convention
-                for ra_m, ra_v, bm, bv in ((mean1, var1, bm1, bv1),
-                                           (mean2, var2, bm2, bv2),
-                                           (mean3, var3, bm3, bv3)):
-                    ra_m.value = m * ra_m.value + (1 - m) * bm
-                    ra_v.value = m * ra_v.value + (1 - m) * bv
-            return y
-        s1, b1 = fbn._fold_bn(g1, be1, mean1.value,
-                              jax.lax.rsqrt(var1.value
-                                            + _BATCH_NORM_EPSILON))
-        s2, b2 = fbn._fold_bn(g2, be2, mean2.value,
-                              jax.lax.rsqrt(var2.value
-                                            + _BATCH_NORM_EPSILON))
-        s3, b3 = fbn._fold_bn(g3, be3, mean3.value,
-                              jax.lax.rsqrt(var3.value
-                                            + _BATCH_NORM_EPSILON))
-        return fbn.bottleneck_apply(x, w1m, w2, w3m, s1, b1, s2, b2,
-                                    s3, b3)
 
 
 class BuildingBlock(nn.Module):
@@ -502,8 +441,7 @@ class BlockLayer(nn.Module):
     @nn.compact
     def __call__(self, x, *, train: bool):
         block_cls = BottleneckBlock if self.bottleneck else BuildingBlock
-        fused_cls = (FusedBottleneckBlock if self.bottleneck
-                     else FusedBuildingBlock)
+        fused_cls = FusedBuildingBlock
         if self.remat:
             # Rematerialize per block: activations are recomputed in the
             # backward pass instead of stored — trades ~33% more FLOPs in
@@ -513,15 +451,14 @@ class BlockLayer(nn.Module):
             # bool must stay a Python static.
             block_cls = nn.remat(block_cls, static_argnums=(2,))
             fused_cls = nn.remat(fused_cls, static_argnums=(2,))
-        # Hybrid dispatch: only the stride-1 identity blocks fuse, and
-        # only at widths with a VMEM-sized tile plan — bottlenecks per
-        # _FUSED_BOTTLENECK_WIDTHS, basic blocks per auto_batch_tile
-        # (which rejects f=512 ImageNet blocks: weights alone ~18.9 MB).
-        # The checked shape is the STAGE shape — block0 (projection/
-        # stride) runs first, so probe with its output geometry.
-        fuse = self.fused and (not self.bottleneck
-                               or self.filters in _FUSED_BOTTLENECK_WIDTHS)
-        if fuse and not self.bottleneck:
+        # Hybrid dispatch: only the stride-1 identity basic blocks fuse,
+        # and only at widths with a VMEM-sized tile plan per
+        # auto_batch_tile (which rejects f=512 ImageNet blocks: weights
+        # alone ~18.9 MB). The checked shape is the STAGE shape — block0
+        # (projection/stride) runs first, so probe with its output
+        # geometry.
+        fuse = self.fused and not self.bottleneck
+        if fuse:
             from tpu_resnet.ops.fused_block import auto_batch_tile
             try:
                 auto_batch_tile(
@@ -533,15 +470,12 @@ class BlockLayer(nn.Module):
             except ValueError:
                 fuse = False   # no VMEM plan at this width: stay on XLA
         _check_fused_bn_axis(fuse, self.bn_axis_name)
-        _check_epilogue_bn_axis(self.epilogue, self.bn_axis_name)
+        _check_epilogue(self.epilogue, self.bn_axis_name)
         x = block_cls(self.filters, self.strides, True, self.dtype,
                       self.bn_axis_name, self.epilogue,
                       name="block0")(x, train)
         for i in range(1, self.blocks):
-            if fuse and self.bottleneck:
-                x = fused_cls(self.filters, self.dtype,
-                              name=f"block{i}")(x, train)
-            elif fuse:
+            if fuse:
                 x = fused_cls(self.filters, self.dtype, self.fused_tile,
                               name=f"block{i}")(x, train)
             else:
@@ -648,15 +582,13 @@ def cifar_resnet_v2(resnet_size: int, num_classes: int,
         raise ValueError(f"resnet_size must be 6n+2 (or 6n+4 for wide), "
                          f"got {resnet_size}")
     if fused_blocks and width_multiplier > 1:
-        # Same guard as models.build_model (ADVICE r4: direct constructor
-        # calls must fail with the same clear message, not an obscure
-        # downstream tile error): Wide-ResNet channels (160/320/640 at
-        # WRN-28-10) put the default tile far past core VMEM, and no A/B
-        # has measured those shapes.
+        # Wide-ResNet channels (160/320/640 at WRN-28-10) put the default
+        # tile far past core VMEM, and no A/B has measured those shapes —
+        # fail loudly rather than ship an untested kernel configuration.
         raise ValueError("fused_blocks is only measured/tiled for "
                          "width_multiplier=1 (16/32/64-channel stages)")
     _check_fused_bn_axis(fused_blocks, bn_axis_name)
-    _check_epilogue_bn_axis(fused_epilogue, bn_axis_name)
+    _check_epilogue(fused_epilogue, bn_axis_name)
     w = width_multiplier
     return ResNetV2(
         stage_filters=(16 * w, 32 * w, 64 * w),
@@ -699,8 +631,12 @@ def imagenet_resnet_v2(resnet_size: int, num_classes: int,
         raise ValueError(
             f"invalid resnet_size {resnet_size}; have {sorted(_IMAGENET_PARAMS)}")
     bottleneck, blocks = _IMAGENET_PARAMS[resnet_size]
+    if fused_blocks and bottleneck:
+        raise ValueError(f"fused_blocks covers basic blocks only "
+                         f"(ImageNet rn18/rn34); rn{resnet_size} is built "
+                         f"of bottleneck blocks")
     _check_fused_bn_axis(fused_blocks, bn_axis_name)
-    _check_epilogue_bn_axis(fused_epilogue, bn_axis_name)
+    _check_epilogue(fused_epilogue, bn_axis_name)
     return ResNetV2(
         stage_filters=(64, 128, 256, 512),
         stage_blocks=blocks,
@@ -716,3 +652,65 @@ def imagenet_resnet_v2(resnet_size: int, num_classes: int,
         fused_blocks=fused_blocks,
         fused_epilogue=fused_epilogue,
     )
+
+
+# ------------------------------------------------------------------ family
+# What models/__init__.py registers as the family ``resnet``: the glue
+# between a RunConfig and the constructors above. The constructors hold
+# every guard; nothing here repeats one.
+def build(cfg) -> ResNetV2:
+    m = cfg.model
+    dtype = jnp.dtype(m.compute_dtype)
+    if cfg.data.dataset == "imagenet":
+        # fused_blocks: rn18/34 basic blocks get VMEM-derived tile plans
+        # (ops.fused_block.auto_batch_tile); the planless 7²x512 stage
+        # stays XLA. Bottleneck sizes refuse the switch.
+        return imagenet_resnet_v2(
+            m.resnet_size, cfg.data.num_classes, dtype=dtype,
+            stem_space_to_depth=m.stem_space_to_depth, remat=m.remat,
+            fused_blocks=m.fused_blocks, fused_epilogue=m.fused_epilogue)
+    return cifar_resnet_v2(
+        m.resnet_size, cfg.data.num_classes,
+        width_multiplier=m.width_multiplier, dtype=dtype, remat=m.remat,
+        fused_blocks=m.fused_blocks, fused_block_tile=m.fused_block_tile,
+        fused_epilogue=m.fused_epilogue)
+
+
+def image_dataset(cfg) -> str:
+    """The data part of an image family's program key: the data set's
+    name, with the class count where ``synthetic`` is not at its 10 (the
+    head's shape follows it)."""
+    if cfg.data.dataset == "synthetic" and cfg.data.synthetic_classes != 10:
+        return f"synthetic{cfg.data.synthetic_classes}"
+    return cfg.data.dataset
+
+
+def spell(cfg):
+    m = cfg.model
+    name = (f"wrn{m.resnet_size}_{m.width_multiplier}"
+            if m.width_multiplier != 1 else f"rn{m.resnet_size}")
+    return image_dataset(cfg), name
+
+
+def variants(cfg):
+    """The kernel switches that change the traced program, as the
+    suffixes a key carries before and after ``_remat`` (the order is that
+    of the keys already written). ``fused_epilogue=auto`` spells like
+    ``off``: its dispatch is the probe's."""
+    m = cfg.model
+    return ("_fused" if m.fused_blocks else "",
+            ("_ep" if m.fused_epilogue == "on" else "")
+            + ("_nos2d" if cfg.data.dataset == "imagenet"
+               and not m.stem_space_to_depth else ""))
+
+
+def train_flops_per_example(cfg, xla_counted: bool = True):
+    """None: XLA's count of the lowered step is this family's. Where XLA
+    gave none, ResNet-50 on ImageNet has its analytic count (another size
+    reports no number rather than that one's)."""
+    if xla_counted or (cfg.data.dataset, cfg.model.resnet_size) != (
+            "imagenet", 50):
+        return None
+    from tpu_resnet.obs.mfu import analytic_resnet50_flops
+
+    return analytic_resnet50_flops(1, cfg.data.resolved_image_size)

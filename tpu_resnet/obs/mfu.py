@@ -203,35 +203,31 @@ def account_train_step(cfg, mesh, state, base_step,
     superbatches, streaming) runs per step, so one entry covers all
     three dispatch shapes."""
     from tpu_resnet import parallel
+    from tpu_resnet.models import family
     from tpu_resnet.programs.registry import batch_avals
     from tpu_resnet.train.step import shard_step
 
     registry = registry or FlopsRegistry()
     key = train_program_key(cfg, dict(mesh.shape))
     gb = cfg.train.global_batch_size
-    if cfg.data.dataset == "tokens":
-        # A token model is counted from its shapes: XLA's count of the
-        # lowered step would hold what attention recomputes backward.
-        from tpu_resnet.models import build_model
-        from tpu_resnet.models.afmoe import train_flops_per_sequence
-
-        flops, source = gb * train_flops_per_sequence(
-            build_model(cfg).arch, cfg.data.seq_len), "analytic"
-    else:
-        size = cfg.data.resolved_image_size
+    fam = family(cfg)
+    per_example = fam.train_flops_per_example(cfg)
+    if per_example is None:
         probe = shard_step(base_step, mesh, donate_state=False,
                            per_replica_bn=per_replica_bn)
         flops = lowered_flops(probe, state, *batch_avals(
             cfg, parallel.batch_sharding(mesh)))
         source = "xla_cost_analysis"
-        if flops is None and cfg.model.name == "resnet" \
-                and cfg.data.dataset == "imagenet":
-            flops, source = analytic_resnet50_flops(gb, size), "analytic"
-        elif flops is not None and per_replica_bn:
+        if flops is None:
+            per_example = fam.train_flops_per_example(cfg,
+                                                      xla_counted=False)
+        elif per_replica_bn:
             # The shard_map body is lowered per-shard: scale the local
             # count back to the global batch so the entry means the same
             # thing on every mesh shape.
             flops *= mesh.shape["data"]
+    if per_example is not None:
+        flops, source = gb * per_example, "analytic"
     kind = mesh.devices.flat[0].device_kind
     entry = registry.register(
         key, flops, source=source, global_batch=gb,

@@ -8,7 +8,7 @@ HBM, forward or backward.
 The kernels are JAX's own ``splash_attention`` (``jax.experimental.pallas
 .ops.tpu``), in its form for one key/value head shared by ``G`` query
 heads, mapped over the key/value heads and the batch. What this module adds
-is the model's contract (``models/afmoe.py::blocked_attention``, which
+is the model's contract (the afmoe module's ``blocked_attention``, which
 stays the path of every other backend and shape and the reference of the
 tests), the masks, the block sizes, and the choice between the two paths
 as a pure function of what the code can see (``attention_path``: backend,

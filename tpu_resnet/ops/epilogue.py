@@ -48,16 +48,15 @@ OP_SBR_ADD = "epilogue_sbr_add"
 
 def scale_bias_relu_math(x, scale, bias):
     """The epilogue math itself — shared in-kernel helper (also imported
-    by ops/fused_block.py / ops/fused_bottleneck.py, whose block kernels
-    apply the same epilogue between their convs)."""
+    by ops/fused_block.py, whose block kernels apply the same epilogue
+    between their convs)."""
     return jnp.maximum(x * scale + bias, 0.0)
 
 
 def _acc_out(first, refs, vals):
     """Init-or-accumulate outputs across a sequential grid; ``first`` is
-    the predicate marking the first grid step (a bool so 2-D grids — the
-    bottleneck kernels — can use it too). Canonical home of the idiom
-    ops/fused_block.py re-exports."""
+    the predicate marking the first grid step. Canonical home of the
+    idiom ops/fused_block.py imports."""
     @pl.when(first)
     def _init():
         for ref, v in zip(refs, vals):

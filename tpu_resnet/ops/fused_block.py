@@ -59,9 +59,8 @@ from tpu_resnet.ops.softmax_xent import is_tpu_backend
 # The epilogue math (scale-bias-ReLU) and the init-or-accumulate grid
 # idiom live with the standalone epilogue kernels (ops/epilogue.py); the
 # block kernels here apply the same epilogue between their convs.
-from tpu_resnet.ops.epilogue import _acc_out  # noqa: F401  (re-exported:
-from tpu_resnet.ops.epilogue import (         # fused_bottleneck imports
-    scale_bias_relu_math as _scale_bias_relu)  # both from this module)
+from tpu_resnet.ops.epilogue import _acc_out
+from tpu_resnet.ops.epilogue import scale_bias_relu_math as _scale_bias_relu
 from tpu_resnet.ops.epilogue import vmem_row_bytes
 
 

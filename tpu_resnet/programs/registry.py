@@ -122,13 +122,18 @@ def spell(cfg, mesh_shape: Dict[str, int], kind: str = "train",
         train|tokens4096_afmoe5l_e8of128_bf16|mesh1x1|b2
 
     One key names exactly one compiled program (the config-matrix
-    coverage check enforces it), so the family variant carries every
-    config dimension that changes the traced program: ``_fused`` /
-    ``_remat`` (block implementation), ``_ep`` (fused_epilogue forced
-    on), ``_nos2d`` (ImageNet stem without space-to-depth), ``_pr``
-    (per-replica BN — the shard_map dispatch is a different program
-    from the auto-sharded sync-BN jit), and the partition mode when not
-    replicated. ``data.engine`` is deliberately NOT part of the key:
+    coverage check enforces it), so it carries every config dimension
+    that changes the traced program. The data part and the model part
+    (``cifar10``, ``rn50``; ``tokens4096``, ``afmoe5l_e8of128``) are the
+    model family's to spell (``models.Family.spell``), as are the
+    suffixes of its own switches (``Family.variants``): the ResNet
+    family's are ``_fused`` (block implementation), ``_ep``
+    (fused_epilogue forced on) and ``_nos2d`` (ImageNet stem without
+    space-to-depth). This function joins them with what every family
+    has: the compute dtype, ``_remat``, ``_pr`` (per-replica BN — the
+    shard_map dispatch is a different program from the auto-sharded
+    sync-BN jit), the partition mode when not replicated, and ``_q8``.
+    ``data.engine`` is deliberately NOT part of the key:
     thread and process engines feed byte-identical programs (the
     engine-invariance twins the verifier pins). ``fused_epilogue=auto``
     spells like ``off`` — its dispatch is probe-dependent by design, and
@@ -138,34 +143,21 @@ def spell(cfg, mesh_shape: Dict[str, int], kind: str = "train",
     ``batch`` overrides ``cfg.train.global_batch_size`` — the serve
     path spells one key per bucket shape.
     """
+    from tpu_resnet.models import family
+
     m = cfg.model
-    name = m.name if m.name != "resnet" else f"rn{m.resnet_size}"
-    if m.name == "resnet" and m.width_multiplier != 1:
-        name = f"wrn{m.resnet_size}_{m.width_multiplier}"
-    dataset = cfg.data.dataset
-    if m.name == "afmoe":
-        # A token model: depth, the experts held of the router's width,
-        # and the sequence length each change the traced program.
-        a = cfg.afmoe
-        name = f"afmoe{len(a.layers)}l_e{a.experts_held}of{a.experts_total}"
-        dataset = f"tokens{cfg.data.seq_len}"
-    if dataset == "synthetic" and getattr(cfg.data, "synthetic_classes",
-                                          10) != 10:
-        dataset = f"synthetic{cfg.data.synthetic_classes}"
+    fam = family(cfg)
+    dataset, name = fam.spell(cfg)
+    before, after = fam.variants(cfg)
     dtype = {"bfloat16": "bf16", "float32": "f32"}.get(
         m.compute_dtype, m.compute_dtype)
     data_axis = mesh_shape.get("data", 1)
-    partition = getattr(getattr(cfg, "mesh", None), "partition",
-                        "replicated")
+    partition = cfg.mesh.partition
     per_replica = (not m.sync_bn) and data_axis > 1
-    quantized = (kind == "serve" and getattr(
-        getattr(cfg, "serve", None), "quantize", "off") == "int8")
-    variant = (("_fused" if m.fused_blocks else "")
+    quantized = kind == "serve" and cfg.serve.quantize == "int8"
+    variant = (before
                + ("_remat" if m.remat else "")
-               + ("_ep" if getattr(m, "fused_epilogue", "off") == "on"
-                  else "")
-               + ("_nos2d" if dataset.startswith("imagenet")
-                  and not getattr(m, "stem_space_to_depth", True) else "")
+               + after
                + ("_pr" if per_replica else "")
                + (f"_{partition}" if partition != "replicated" else "")
                # Quantized serve programs (serve.quantize=int8) take the
@@ -739,9 +731,11 @@ def batch_avals(cfg, sharding=None, rows: int = 0):
     executable cache spell a batch."""
     import jax
 
+    from tpu_resnet.models import family
+
     lead = ((rows,) if rows else ()) + (cfg.train.global_batch_size,)
     kw = {} if sharding is None else {"sharding": sharding}
-    if cfg.data.dataset == "tokens":
+    if family(cfg).inputs == "tokens":
         ids = jax.ShapeDtypeStruct(lead + (cfg.data.seq_len,), "int32", **kw)
         return ids, ids
     size = cfg.data.resolved_image_size

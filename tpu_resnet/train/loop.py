@@ -30,8 +30,7 @@ from tpu_resnet.config import RunConfig
 from tpu_resnet.data import augment as aug_lib
 from tpu_resnet.data import device_data
 from tpu_resnet.data import pipeline
-from tpu_resnet.models import build_model, sample_input
-from tpu_resnet.models.afmoe import attention_paths
+from tpu_resnet.models import build_model, family, sample_input
 from tpu_resnet.tools import profiling
 from tpu_resnet.train import schedule as sched_lib
 from tpu_resnet.train.checkpoint import CheckpointManager
@@ -171,7 +170,8 @@ def train(cfg: RunConfig, mesh=None, metrics: Optional[MetricsWriter] = None,
 
     model = build_model(cfg)
     schedule = sched_lib.build_schedule(cfg.optim, cfg.train)
-    tokens = cfg.data.dataset == "tokens"
+    fam = family(cfg)
+    tokens = fam.inputs == "tokens"
     augment_fn = (None if tokens
                   else aug_lib.get_augment_fns(cfg.data.dataset)[0])
 
@@ -206,12 +206,8 @@ def train(cfg: RunConfig, mesh=None, metrics: Optional[MetricsWriter] = None,
         extra=({"topology_change": elastic_ctx.attrs()}
                if elastic_ctx is not None and elastic_ctx.changed
                else None))
-    if tokens:
-        # static, so said once: the path each layer's attention takes here
-        # and the key blocks its mask leaves (docs/OBSERVABILITY.md)
-        spans.event("attention_path", layers=attention_paths(
-            model.arch, cfg.data.seq_len, jax.default_backend(),
-            jax.device_count()))
+    for event, fields in fam.startup_events(model, cfg).items():
+        spans.event(event, **fields)  # what the family says once
     from tpu_resnet.obs.server import CORE_HISTOGRAMS
     telemetry = obs.TelemetryRegistry(
         stale_after_sec=cfg.train.telemetry_stale_sec,
@@ -356,7 +352,7 @@ def train(cfg: RunConfig, mesh=None, metrics: Optional[MetricsWriter] = None,
                                     xent_probe_batch=max(
                                         1, cfg.train.global_batch_size
                                         // mesh.shape["data"]),
-                                    partitioner=partitioner, tokens=tokens)
+                                    partitioner=partitioner)
         breakdown.end(xent_probe, decisions=ops.autotune.decisions())
         # zero1 compiles with the partitioner's state layout so the
         # optimizer-slot arguments are per-shard buffers; replicated

@@ -11,6 +11,8 @@ import jax
 import jax.numpy as jnp
 import optax
 
+from tpu_resnet.models import family_of
+
 
 @flax.struct.dataclass
 class TrainState:
@@ -58,7 +60,7 @@ def build_optimizer(optim_cfg, schedule) -> optax.GradientTransformation:
 def init_state(model, optim_cfg, schedule, rng: jax.Array,
                sample_batch: jnp.ndarray) -> TrainState:
     init = model.init
-    if jnp.issubdtype(sample_batch.dtype, jnp.integer):
+    if family_of(model).inputs == "tokens":
         # a token model: its forward pass as one program, not one small
         # program an operation of every layer
         from tpu_resnet.programs.registry import init_program

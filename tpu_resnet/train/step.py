@@ -36,7 +36,7 @@ import optax
 from flax.traverse_util import flatten_dict
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from tpu_resnet.models.afmoe import COUNTERS
+from tpu_resnet import models
 from tpu_resnet.train.state import TrainState, build_optimizer
 
 
@@ -87,25 +87,24 @@ def check_step_config(cfg, data_axis: int) -> None:
     from tpu_resnet.parallel.partition import check_partition_mode
 
     per_replica_bn = (not cfg.model.sync_bn) and data_axis > 1
-    partition = check_partition_mode(
-        getattr(cfg.mesh, "partition", "replicated"))
-    tokens = cfg.data.dataset == "tokens"
-    if tokens != (cfg.model.name == "afmoe"):
+    partition = check_partition_mode(cfg.mesh.partition)
+    fam = models.family(cfg)
+    kind = models.data_kind(cfg)
+    if kind != fam.inputs:
         raise ValueError(
-            f"model {cfg.model.name!r} on dataset {cfg.data.dataset!r}: "
-            f"dataset 'tokens' feeds model 'afmoe' and nothing else does")
+            f"model {cfg.model.name!r} takes {fam.inputs} and dataset "
+            f"{cfg.data.dataset!r} holds {kind}: "
+            + "; ".join(f"a dataset of {k} feeds model {models.feeds(k)}"
+                        for k in (kind, fam.inputs)))
     if cfg.optim.grad_clip_norm and cfg.optim.optimizer != "adamw":
         raise ValueError("optim.grad_clip_norm is applied before adamw "
                          "only; sgd and momentum take none")
-    if tokens:
+    bad = []
+    if fam.inputs == "tokens":
         refused = [
             ("model.sync_bn=false on a multi-chip data axis (the "
              "shard_map step would route each shard's tokens apart)",
              per_replica_bn),
-            ("mesh.partition=zero1 (no rule shards expert or attention "
-             "leaves yet)", partition != "replicated"),
-            ("model.fused_blocks / model.fused_epilogue (ResNet kernels)",
-             cfg.model.fused_blocks or cfg.model.fused_epilogue != "off"),
             ("optim.label_smoothing", cfg.optim.label_smoothing != 0.0),
             ("optim.use_pallas_xent=on (the kernel one-hots class "
              "labels; the token loss never consults it or its probe)",
@@ -116,9 +115,10 @@ def check_step_config(cfg, data_axis: int) -> None:
              cfg.optim.optimizer != "adamw"),
         ]
         bad = [what for what, is_set in refused if is_set]
-        if bad:
-            raise ValueError("a token model does not train with: "
-                             + "; ".join(bad))
+    bad += fam.refuses(cfg, data_axis)
+    if bad:
+        raise ValueError(f"model {cfg.model.name!r} does not train with: "
+                         + "; ".join(bad))
     if partition == "zero1" and per_replica_bn:
         raise ValueError(
             "mesh.partition=zero1 on a multi-chip data axis requires "
@@ -133,7 +133,7 @@ def check_step_config(cfg, data_axis: int) -> None:
             "model.sync_bn=false (per-replica BN via shard_map — the "
             "reference's BN semantics); global-batch sync-BN is not "
             "implemented for the fused kernels")
-    if (getattr(cfg.model, "fused_epilogue", "off") != "off"
+    if (cfg.model.fused_epilogue != "off"
             and data_axis > 1 and not per_replica_bn):
         raise ValueError(
             "model.fused_epilogue on a multi-chip data axis requires "
@@ -148,14 +148,16 @@ def make_train_step(model, optim_cfg, schedule, num_classes: int,
                     mesh: Optional[Mesh] = None,
                     grad_axis: Optional[str] = None,
                     xent_probe_batch: int = 128,
-                    partitioner=None, tokens: bool = False):
+                    partitioner=None):
     """Returns ``train_step(state, images, labels) -> (state, metrics)``.
 
-    ``tokens``: the inputs are ``(B, S)`` ids and the labels the next ids.
-    The loss is ``token_xent``; no augmentation, no L2 term and no xent
-    probe are part of that path, and the metrics also carry the model's
-    routing counters (models/afmoe.py::COUNTERS, meaned over its expert
-    layers) and the ``tokens`` of the step.
+    What a batch is, is the model's family's to say
+    (``models.family_of(model).inputs``). Of ``tokens``: the inputs are
+    ``(B, S)`` ids and the labels the next ids. The loss is
+    ``token_xent``; no augmentation, no L2 term and no xent probe are
+    part of that path, and the metrics also carry the ``tokens`` of the
+    step. Whatever the kind, the metrics carry the family's ``counters``,
+    each meaned over the layers that count it.
 
     ``images`` may be raw uint8 (augment_fn applied on device) or
     pre-processed floats (augment_fn=None).
@@ -175,6 +177,8 @@ def make_train_step(model, optim_cfg, schedule, num_classes: int,
     """
     from tpu_resnet.parallel import zero
 
+    fam = models.family_of(model)
+    tokens = fam.inputs == "tokens"
     tx = build_optimizer(optim_cfg, schedule)
     apply_update = zero.make_update_fn(tx, partitioner)
     if base_rng is None:
@@ -190,7 +194,7 @@ def make_train_step(model, optim_cfg, schedule, num_classes: int,
     # dispatch lives in ops.make_pallas_xent.
     from tpu_resnet.ops import (ensure_xent_probe, is_tpu_backend,
                                 make_pallas_xent)
-    mode = str(getattr(optim_cfg, "use_pallas_xent", "off")).lower()
+    mode = str(optim_cfg.use_pallas_xent).lower()
     mode = {"true": "on", "1": "on", "yes": "on",
             "false": "off", "0": "off", "no": "off"}.get(mode, mode)
     if mode not in ("on", "off", "auto"):
@@ -207,7 +211,7 @@ def make_train_step(model, optim_cfg, schedule, num_classes: int,
     if use_pallas:
         _pallas_xent = make_pallas_xent(mesh if grad_axis is None else None)
 
-    collections = ["batch_stats"] + (["counters"] if tokens else [])
+    collections = ["batch_stats"] + (["counters"] if fam.counters else [])
 
     def train_step(state: TrainState, images, labels):
         rng = jax.random.fold_in(base_rng, state.step)
@@ -275,12 +279,12 @@ def make_train_step(model, optim_cfg, schedule, num_classes: int,
                 "learning_rate": schedule(state.step),
                 "grad_norm": optax.global_norm(grads),
             }
+            counted = flatten_dict(new_model_state.get("counters", {}))
+            for name in fam.counters:
+                layers = [v for k, v in counted.items() if k[-1] == name]
+                if layers:  # a model of dense layers routes nothing
+                    metrics[name] = jnp.mean(jnp.stack(layers))
             if tokens:
-                counters = flatten_dict(new_model_state.get("counters", {}))
-                for name in COUNTERS:
-                    layers = [v for k, v in counters.items() if k[-1] == name]
-                    if layers:  # a model of dense layers routes nothing
-                        metrics[name] = jnp.mean(jnp.stack(layers))
                 metrics["tokens"] = jnp.float32(labels.size)
         return new_state, metrics
 
