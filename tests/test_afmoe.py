@@ -163,31 +163,54 @@ def test_the_bias_chooses_and_does_not_weigh_and_gets_no_gradient():
 @pytest.mark.parametrize("slack", [2.0, 0.25])
 def test_the_shares_add_up_to_the_uncut_layer(slack):
     """Every share's routed part plus the shared expert once is what the
-    uncut reference gives for the whole layer; at slack 0.25 the fast path
-    holds a quarter of an even load and the rest goes beyond it."""
+    uncut reference gives for the whole layer, and so are the gradients
+    with respect to ``x`` and the three expert matrices; at slack 2 the
+    buffer holds every share's assignments, at 0.25 a quarter of an even
+    total, and the rest goes through the tiers beyond it."""
     x = jax.random.normal(jax.random.PRNGKey(0), (2, 32, 64))
+    cot = jax.random.normal(jax.random.PRNGKey(3), (2, 32, 64))
     whole = afmoe.ExpertLayer(32, 16, (0, 16), 4, 1, 2.826, 0.001, 2.0,
                               jnp.float32)
-    v = whole.init(jax.random.PRNGKey(1), x, False)
+    p = whole.init(jax.random.PRNGKey(1), x, False)["params"]
     bias = 0.2 * jax.random.normal(jax.random.PRNGKey(2), (16,))
-    p = v["params"]
+    model = dict(MODEL, experts_first=0, experts_held=16)
+    stacks = ("gate", "up", "down")
+
     flat_p = {k: jnp.asarray(a) for k, a in flat(p).items()}
-    want, _ = ref.experts(
-        flat_p, bias, x, dict(MODEL, experts_first=0, experts_held=16),
-        "none")
-    shared = afmoe.SwiGLU(32, jnp.float32).apply({"params": p["shared"]}, x)
-    total = shared
-    for first in range(0, 16, 4):
-        share = afmoe.ExpertLayer(32, 16, (first, 4), 4, 1, 2.826, 0.001,
-                                  slack, jnp.float32)
-        cut = dict(p, **{k: p[k][first:first + 4]
-                         for k in ("gate", "up", "down")})
-        got, state = share.apply(
-            {"params": cut, "batch_stats": {"expert_bias": bias}}, x, False,
-            mutable=["counters"])
-        assert float(state["counters"]["moe_dropped_frac"]) == 0.0
-        total = total + got - shared
-    np.testing.assert_allclose(total, want, atol=2e-5)
+
+    def reference(x, experts):
+        return ref.experts(dict(flat_p, **experts), bias, x, model,
+                           "none")[0]
+
+    def shares(x, experts):
+        shared = afmoe.SwiGLU(32, jnp.float32).apply(
+            {"params": p["shared"]}, x)
+        total, dropped = shared, []
+        for first in range(0, 16, 4):
+            share = afmoe.ExpertLayer(32, 16, (first, 4), 4, 1, 2.826,
+                                      0.001, slack, jnp.float32)
+            cut = dict(p, **{k: experts[k][first:first + 4]
+                             for k in stacks})
+            got, state = share.apply(
+                {"params": cut, "batch_stats": {"expert_bias": bias}}, x,
+                False, mutable=["counters"])
+            dropped.append(state["counters"]["moe_dropped_frac"])
+            assert share._rows(64, 8) == (128 if slack == 2.0 else 16)
+            total = total + got - shared
+        return total, dropped
+
+    experts = {k: p[k] for k in stacks}
+    got, dropped = shares(x, experts)
+    assert all(float(d) == 0.0 for d in dropped)
+    np.testing.assert_allclose(got, reference(x, experts), atol=2e-5)
+    got_grads = jax.grad(lambda x, e: jnp.sum(shares(x, e)[0] * cot),
+                         argnums=(0, 1))(x, experts)
+    want_grads = jax.grad(lambda x, e: jnp.sum(reference(x, e) * cot),
+                          argnums=(0, 1))(x, experts)
+    np.testing.assert_allclose(got_grads[0], want_grads[0], atol=2e-5)
+    for k in stacks:
+        np.testing.assert_allclose(got_grads[1][k], want_grads[1][k],
+                                   atol=2e-5, err_msg=k)
 
 
 # ------------------------------------------------ against explicit loops

@@ -27,14 +27,23 @@ The equations (``d`` hidden size; RMSNorm with a weight everywhere):
   step's routing, ``b += c - mean(c)`` with ``c = load_balance_coeff *
   sign(mean(n) - n)``, ``n`` the assignments per expert.
 
-No token is dropped: the assignments that fall on a held expert are ranked
-within it; the first ``fast_rows`` ranks of every expert are one batched
-product (gather by expert, three grouped matrix products, weighted
-scatter-add), and whatever ranks beyond them exist are computed by the
-same code, ``fast_rows`` at a time, under a ``lax.cond`` that is false
-while no expert overflows. The counters say what happened
-(``moe_dropped_frac`` reads 0 by that construction and is counted from the
-dispatch tables all the same).
+No token is dropped, and the layer does the work its routing fills: one
+stable sort of the ``N * top_k`` assignments by their expert here puts
+those that fall on a held expert first, grouped by expert and in token
+order within an expert. The first ``rows`` of them (``rows_slack`` x what
+an even routing sends to all held experts together, in whole tiles) are one
+buffer: a gather by the sorted order, three grouped products whose work
+follows the groups' sizes (``ops/grouped.py``, which also says how its path
+is chosen and what its kernel never writes: the buffer's rows past the
+last assignment come back 0, in the result and in every gradient, so none
+of them reaches the weighted scatter-add), and the scatter-add. Whatever
+sorted positions lie beyond the buffer go through the same code a tier of
+``rows`` at a time, only the tiers that hold an assignment, under a
+``lax.cond`` that is false while the held experts together take no more
+than the buffer. The counters say what happened (``moe_dropped_frac`` reads
+0 by that construction and is counted from the groups' sizes all the same;
+``moe_overflow_frac`` says whether the tiers beyond ran,
+``moe_rows_filled_frac`` how much of the buffer carried an assignment).
 
 Attention never builds a ``(B, H, S, S)`` score tensor. On one TPU chip, at
 heads of a multiple of 128 and sequences its blocks divide, it is one fused
@@ -60,10 +69,12 @@ from jax.ad_checkpoint import checkpoint_name
 
 from tpu_resnet.ops.attention import (attention_path, fused_attention,
                                       key_blocks)
+from tpu_resnet.ops.grouped import grouped_dot, grouped_path, row_tile
 
 # layer kinds: what F is, and which mask attention takes
 LAYER_KINDS = ("dense_sliding", "dense_full", "moe_sliding", "moe_full")
-COUNTERS = ("moe_dropped_frac", "moe_load_max_over_mean", "moe_here_frac")
+COUNTERS = ("moe_dropped_frac", "moe_load_max_over_mean", "moe_here_frac",
+            "moe_overflow_frac", "moe_rows_filled_frac")
 
 _init = nn.initializers.normal(0.02)
 _f32 = jnp.float32
@@ -235,12 +246,16 @@ class ExpertLayer(nn.Module):
     shared: int                        # shared experts (their width adds)
     route_scale: float
     balance_coeff: float
-    fast_slack: float
+    rows_slack: float
     dtype: Any
 
-    def _fast_rows(self, n: int) -> int:
-        even = n * self.top_k / self.experts_total
-        return min(n, -(-int(math.ceil(self.fast_slack * even)) // 8) * 8)
+    def _rows(self, n: int, tile: int) -> int:
+        """The buffer's rows for ``n`` tokens: ``rows_slack`` x what an
+        even routing sends to the experts held here all together, in whole
+        tiles, and no more than every assignment there is."""
+        even = n * self.top_k * self.experts_held[1] / self.experts_total
+        return -(-min(n * self.top_k, math.ceil(self.rows_slack * even))
+                 // tile) * tile
 
     @nn.compact
     def __call__(self, x, train: bool):
@@ -254,6 +269,9 @@ class ExpertLayer(nn.Module):
         w_gate = self.param("gate", _init, (count, d, self.width), _f32)
         w_up = self.param("up", _init, (count, d, self.width), _f32)
         w_down = self.param("down", _init, (count, self.width, d), _f32)
+        path = grouped_path(jax.default_backend(), jax.device_count())
+        rows = self._rows(n, row_tile(path))
+        tiers = -(-n * k // rows)
 
         with jax.named_scope("router"):
             scores = jax.nn.sigmoid(jnp.dot(
@@ -266,67 +284,67 @@ class ExpertLayer(nn.Module):
                 * self.route_scale                     # (N, k) float32
 
         with jax.named_scope("dispatch"):
-            # every assignment, in token order: its expert here (``count``
-            # = not held), and its rank among that expert's assignments
+            # every assignment's expert here (``count`` = not held); one
+            # stable sort puts the held ones first, grouped by expert, in
+            # token order within an expert
             local = (chosen - first).reshape(-1)
-            held = (local >= 0) & (local < count)
-            expert = jnp.where(held, local, count)
-            onehot = (expert[:, None] == jnp.arange(count)[None, :]
-                      ).astype(jnp.int32)              # (N*k, count)
-            rank = jnp.take_along_axis(
-                jnp.cumsum(onehot, axis=0), jnp.minimum(
-                    expert, count - 1)[:, None], axis=1)[:, 0] - 1
-            load = jnp.sum(onehot, axis=0)             # per held expert
-            token = jnp.arange(n * k, dtype=jnp.int32) // k
+            expert = jnp.where((local >= 0) & (local < count), local, count)
+            load = jnp.sum(expert[:, None] == jnp.arange(count)[None, :],
+                           axis=0, dtype=jnp.int32)    # per held expert
+            ends = jnp.cumsum(load)
+            here_n = ends[-1]
+            order = jnp.pad(jnp.argsort(expert, stable=True),
+                            (0, tiers * rows - n * k))
 
-        def ranks(lo, rows: int, xb, weight, w_gate, w_up, w_down):
-            """The partial result of the assignments ranked ``lo .. lo +
-            rows`` in their expert, and how many those were."""
+        def tier(lo, xb, weight, w_gate, w_up, w_down):
+            """The partial result of the sorted positions ``lo .. lo +
+            rows``, and how many of them hold an assignment. The positions
+            past ``here_n`` lie past the groups' sum: the products leave
+            them 0, in the result and in every gradient."""
             with jax.named_scope("dispatch"):
-                here = held & (rank >= lo) & (rank < lo + rows)
-                slot = jnp.where(here, expert * rows + rank - lo,
-                                 count * rows)
-                src = jnp.zeros((count * rows + 1,), jnp.int32
-                                ).at[slot].set(token)[:-1]
-                w_of = jnp.zeros((count * rows + 1,), _f32
-                                 ).at[slot].set(weight.reshape(-1))[:-1]
-                xs = jnp.take(xb, src, axis=0).reshape(count, rows, d)
+                at = jax.lax.dynamic_slice_in_dim(order, lo, rows)
+                token = at // k
+                sizes = (jnp.clip(ends, lo, lo + rows)
+                         - jnp.clip(ends - load, lo, lo + rows))
+                xs = jnp.take(xb, token, axis=0)
             with jax.named_scope("experts"):
                 def mm(a, w, out=self.dtype):
-                    return jnp.einsum("erd,edf->erf", a.astype(self.dtype),
-                                      w.astype(self.dtype),
-                                      preferred_element_type=_f32
-                                      ).astype(out)
+                    return grouped_dot(a.astype(self.dtype),
+                                       w.astype(self.dtype), sizes, out,
+                                       path)
 
                 hidden = (checkpoint_name(mm(xs, w_gate), "experts"),
                           checkpoint_name(mm(xs, w_up), "experts"))
                 y = mm(jax.nn.silu(hidden[0]) * hidden[1], w_down, _f32)
             with jax.named_scope("combine"):
-                y = y.reshape(count * rows, d) * w_of[:, None]
-                return (jnp.zeros((n, d), _f32).at[src].add(y),
-                        jnp.sum(here))
+                y = y * jnp.take(weight.reshape(-1), at)[:, None]
+                return (jnp.zeros((n, d), _f32).at[token].add(y),
+                        jnp.sum(sizes))
 
-        fast = self._fast_rows(n)
         operands = (x.astype(self.dtype), weight, w_gate, w_up, w_down)
-        out, computed = ranks(0, fast, *operands)
-        if fast < n:
-            # The ranks beyond, ``fast`` of every expert at a time, as
-            # far as some expert has them. Recomputed backward: a cond
-            # hands on the residuals of both its branches.
+        out, computed = tier(0, *operands)
+        if tiers > 1:
+            # The positions beyond, a tier at a time and only the tiers
+            # that hold an assignment. Recomputed backward, as a whole (a
+            # cond hands on the residuals of both its branches: 3.5 GB of
+            # temporaries in the benchmark's cell) and a tier at a time
+            # within (the scan would stack every tier's: 0.9 GB).
             def nothing(*_):
                 return jnp.zeros((n, d), _f32), jnp.zeros((), jnp.int32)
 
             @jax.checkpoint
             def beyond(*operands):
-                def tier(acc, lo):
-                    more, also = ranks(lo, fast, *operands)
+                def one(acc, lo):
+                    more, also = jax.lax.cond(
+                        lo < here_n, jax.checkpoint(tier), nothing, lo,
+                        *operands)
                     return (acc[0] + more, acc[1] + also), None
 
                 return jax.lax.scan(
-                    tier, (jnp.zeros((n, d), _f32), jnp.zeros((), jnp.int32)),
-                    jnp.arange(1, -(-n // fast), dtype=jnp.int32) * fast)[0]
+                    one, nothing(), jnp.arange(1, tiers, dtype=jnp.int32)
+                    * rows)[0]
 
-            more, also = jax.lax.cond(jnp.max(load) > fast, beyond, nothing,
+            more, also = jax.lax.cond(here_n > rows, beyond, nothing,
                                       *operands)
             out, computed = out + more, computed + also
 
@@ -336,13 +354,16 @@ class ExpertLayer(nn.Module):
                                    name="shared")(x)
 
         with jax.named_scope("router"):
-            here_n = jnp.sum(load).astype(_f32)
+            here = here_n.astype(_f32)
             for name, value in (
-                    ("moe_dropped_frac", (here_n - computed.astype(_f32))
-                     / jnp.maximum(here_n, 1.0)),
+                    ("moe_dropped_frac", (here - computed.astype(_f32))
+                     / jnp.maximum(here, 1.0)),
                     ("moe_load_max_over_mean", jnp.max(load).astype(_f32)
-                     * count / jnp.maximum(here_n, 1.0)),
-                    ("moe_here_frac", here_n / (n * k))):
+                     * count / jnp.maximum(here, 1.0)),
+                    ("moe_here_frac", here / (n * k)),
+                    ("moe_overflow_frac", (here_n > rows).astype(_f32)),
+                    ("moe_rows_filled_frac",
+                     jnp.minimum(here, rows) / rows)):
                 self.sow("counters", name, value, init_fn=lambda: 0.0,
                          reduce_fn=lambda old, new: new)
             if train and not self.is_initializing():
@@ -377,7 +398,7 @@ class Layer(nn.Module):
                 f = ExpertLayer(m.expert_width, m.experts_total,
                                 tuple(m.experts_held), m.top_k, m.shared,
                                 m.route_scale, m.balance_coeff,
-                                m.fast_slack, m.dtype, name="moe")(x, train)
+                                m.rows_slack, m.dtype, name="moe")(x, train)
         return h + RMSNorm(m.eps, name="post_mlp_norm")(f)
 
 
@@ -404,12 +425,13 @@ class Arch:
     eps: float = 1e-5
     route_scale: float = 2.826
     balance_coeff: float = 0.001
-    # the rows of each held expert computed outside the cond, over the
-    # rows an even routing gives it. A fresh router's busiest held expert
-    # takes about 2 to 3 x an even share (PERF.md section 6): at 4 the
-    # overflow path is rare, and the fast path's cost does not turn on
-    # the routing.
-    fast_slack: float = 4.0
+    # the rows of the one buffer that holds the assignments of all held
+    # experts, over the rows an even routing sends them together. Their
+    # sum wanders far less than the busiest of them: a fresh router's held
+    # share reads 0.64 to 1.36 x even (PERF.md section 6), so at 2 the
+    # overflow tiers are rare; the products' cost follows the rows filled,
+    # the gather's and the scatter-add's the buffer's.
+    rows_slack: float = 2.0
     attn_block: int = 256              # queries a block of the scan path
     remat: bool = False                # each layer's backward keeps _KEEP
     dtype: Any = jnp.bfloat16
