@@ -4,6 +4,7 @@ S 32, 16 experts top-4, vocabulary 128; layers dense-sliding, 3 x
 expert-sliding, expert-full), in float32 against the benchmark's plain
 reference (benchmarks/reference/afmoe.py) and against explicit loops."""
 
+import hashlib
 import json
 import math
 import os
@@ -20,7 +21,8 @@ from benchmarks.reference import afmoe as ref
 from tpu_resnet.config import load_config
 from tpu_resnet.data import device_data
 from tpu_resnet.data.tokens import load_tokens, write_tokens
-from tpu_resnet.models import afmoe, build_model, sample_input
+from tpu_resnet.models import (afmoe, build_model, sample_input,
+                               transformer)
 from tpu_resnet.models.afmoe import Afmoe, Arch
 from tpu_resnet.programs import spell
 from tpu_resnet.train import schedule as sched_lib
@@ -195,7 +197,8 @@ def test_the_shares_add_up_to_the_uncut_layer(slack):
                 {"params": cut, "batch_stats": {"expert_bias": bias}}, x,
                 False, mutable=["counters"])
             dropped.append(state["counters"]["moe_dropped_frac"])
-            assert share._rows(64, 8) == (128 if slack == 2.0 else 16)
+            assert transformer.buffer_rows(64, 4, 4, 16, slack, 8) == (
+                128 if slack == 2.0 else 16)
             total = total + got - shared
         return total, dropped
 
@@ -349,6 +352,50 @@ def test_ten_steps_of_the_program_follow_the_reference():
     assert metrics["tokens"] == 8 * 32
     assert 0 < float(metrics["moe_here_frac"]) < 1
     assert float(metrics["moe_load_max_over_mean"]) >= 1
+
+
+# The parent's numbers (commit bef082f, before the dispatch, attention by
+# path, RMSNorm and the rotary embedding moved to models/transformer.py):
+# the same program gives them to the digit.
+PARENTS = [
+    dict(loss=4.846383094787598, grad_norm=2.5898404121398926,
+         moe_here_frac=0.214111328125, precision=0.03125,
+         moe_load_max_over_mean=1.8290661573410034,
+         moe_rows_filled_frac=0.42822265625, learning_rate=0.0),
+    dict(loss=4.844571113586426, grad_norm=2.623225688934326,
+         moe_here_frac=0.203857421875, precision=0.015625,
+         moe_load_max_over_mean=1.8786640167236328,
+         moe_rows_filled_frac=0.40771484375,
+         learning_rate=1.50000019516483e-07),
+    dict(loss=4.828547954559326, grad_norm=2.678814172744751,
+         moe_here_frac=0.215576171875, precision=0.02734375,
+         moe_load_max_over_mean=1.6989960670471191,
+         moe_rows_filled_frac=0.43115234375,
+         learning_rate=3.00000039032966e-07),
+]
+
+
+def test_the_tiny_program_gives_the_parents_numbers_to_the_digit():
+    cfg = load_config("trinity_mini_ep16", overrides=TINY)
+    model = build_model(cfg)
+    schedule = sched_lib.build_schedule(cfg.optim, cfg.train)
+    state = init_state(model, cfg.optim, schedule, jax.random.PRNGKey(3),
+                       sample_input(cfg))
+    names = sorted("/".join(p.key for p in path) for path, _ in
+                   jax.tree_util.tree_leaves_with_path(state.params))
+    assert len(names) == 89 and hashlib.sha256(
+        "\n".join(names).encode()).hexdigest() == (
+        "d624dcb14fb95a60a5e29f1abbb92e65d5f7bf7b09bbf0bf638e5d9e14334dca")
+    step = jax.jit(make_train_step(model, cfg.optim, schedule,
+                                   cfg.data.num_classes))
+    for seed, want in enumerate(PARENTS):
+        state, metrics = step(state, *tokens(seed, batch=8))
+        got = {k: float(v) for k, v in metrics.items()}
+        assert got == dict(want, tokens=256.0, moe_dropped_frac=0.0,
+                           moe_overflow_frac=0.0), (seed, got)
+    total = sum(float(jnp.sum(jnp.abs(leaf)))
+                for leaf in jax.tree_util.tree_leaves(state.params))
+    assert total == pytest.approx(5383.480966567993, rel=1e-9)
 
 
 # --------------------------------------------------------- data and config

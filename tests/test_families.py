@@ -119,7 +119,7 @@ def test_a_family_is_fed_by_its_kind_of_data_only(toy, preset, overrides,
         check_step_config(cfg, 1)
     said = str(err.value)
     assert repr(model) in said and repr(dataset) in said
-    assert "feeds model 'afmoe', 'toy_tokens'" in said
+    assert "feeds model 'afmoe', 'sdar_moe', 'toy_tokens'" in said
     assert "feeds model 'mlp', 'resnet'" in said
 
 
@@ -345,7 +345,8 @@ def test_no_file_outside_models_names_a_family():
     name outside ``models/`` and ``config.py``: what a family is, is asked
     of its record."""
     names = set(models._FAMILIES)
-    modules = {f"tpu_resnet.models.{n}" for n in ("afmoe", "mlp", "resnet")}
+    modules = {f"tpu_resnet.models.{n}" for n in (
+        "afmoe", "mlp", "resnet", "sdar_moe", "transformer")}
     found = []
     for dirpath, _, files in os.walk(os.path.join(REPO, "tpu_resnet")):
         for fn in files:

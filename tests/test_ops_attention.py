@@ -1,9 +1,11 @@
 """The fused attention kernel (ops/attention.py) on the CPU, in Pallas'
 interpret mode, held to the scan it replaces on a TPU
-(models/afmoe.py::blocked_attention): the same contract, the same masks,
-forward and in all three gradients; the tiles its mask tables visit against
-a count from the dense mask; the choice between the two paths; and what
-``train()`` says of it at start-up."""
+(models/transformer.py::blocked_attention): the same contract, the same
+masks (causal, windowed, and the three-part mask of training by diffusion
+over blocks, each within documents), forward and in all three gradients,
+and both against the dense three-part mask; the tiles its mask tables
+visit against a count from the dense mask; the choice between the two
+paths; and what ``train()`` says of it at start-up."""
 
 import json
 
@@ -12,9 +14,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from benchmarks.reference.sdar_moe import mask as dense_mask
 from tpu_resnet.config import load_config
 from tpu_resnet.data.tokens import write_tokens
-from tpu_resnet.models import afmoe
+from tpu_resnet.models import afmoe, transformer
 from tpu_resnet.ops import attention
 
 S, D, KV, G, B = 512, 128, 2, 2, 2
@@ -191,7 +194,8 @@ def test_the_model_through_the_kernel_equals_the_model_through_the_scan(
 
     with jax.default_matmul_precision("highest"):
         want = jax.value_and_grad(loss)(params)
-        monkeypatch.setattr(afmoe, "attention_path", lambda *_: "kernel")
+        monkeypatch.setattr(transformer, "attention_path",
+                            lambda *_: "kernel")
         got = jax.value_and_grad(loss)(params)
     np.testing.assert_allclose(got[0], want[0], rtol=1e-6)
     for a, b in zip(jax.tree_util.tree_leaves(got[1]),
@@ -237,3 +241,79 @@ def test_the_cells_kernels_compile_for_a_v5e(one_chip, window):
         shaped(b, s, dtype=jnp.int32)).compile().as_text()
     assert "splash_mqa_fwd_segmented_residuals" in text
     assert "splash_mqa_dkv_segmented_no_residuals" in text
+
+
+# ------------------------------------- the three-part mask (block diffusion)
+L_CLEAN, BLK = 256, 4          # 512 positions: a noised copy, a clean one
+DIFFUSION = attention.BlockDiffusion(L_CLEAN, BLK)
+
+
+def _dense_attention(q, k, v, doc, ok):
+    """Every score of the ``S x S`` square under the dense mask ``ok``
+    and the documents, in float32."""
+    see = ok[None] & (doc[:, :, None] == doc[:, None, :])
+    sc = jnp.einsum("bqhgd,bkhd->bhgqk", q, k) / np.sqrt(q.shape[-1])
+    pr = jax.nn.softmax(jnp.where(see[:, None, None], sc, -jnp.inf), -1)
+    return jnp.einsum("bhgqk,bkhd->bqhgd", pr, v)
+
+
+def test_allows_is_the_definition_and_leaves_its_count_live():
+    q, k = np.arange(2 * L_CLEAN)[:, None], np.arange(2 * L_CLEAN)[None, :]
+    want = dense_mask(L_CLEAN, BLK)
+    np.testing.assert_array_equal(DIFFUSION.allows(q, k), want)
+    np.testing.assert_array_equal(
+        np.asarray(DIFFUSION.allows(jnp.asarray(q), jnp.asarray(k))), want)
+    assert want.sum() == L_CLEAN * L_CLEAN + L_CLEAN * BLK
+    # nothing sees a noisy key of another block; the clean copy none
+    assert not want[L_CLEAN:, :L_CLEAN].any()
+    assert want[:L_CLEAN, :L_CLEAN].sum() == L_CLEAN * BLK
+
+
+@pytest.mark.parametrize("path", ["kernel", "scan"])
+def test_both_paths_equal_the_dense_three_part_mask_with_documents(path):
+    q, k, v, _, weight = _inputs()
+    # both copies carry the clean text's documents, which begin inside
+    # blocks (5, 130), at a block's first position (128) and last (63)
+    starts = np.zeros((B, L_CLEAN), np.int32)
+    starts[0, [0, 5, 63, 128, 130]] = 1
+    starts[1, [0, 200]] = 1
+    doc = jnp.asarray(np.tile(np.cumsum(starts, axis=1), (1, 2)))
+    ok = jnp.asarray(dense_mask(L_CLEAN, BLK))
+
+    def run(fn):
+        def loss(q, k, v):
+            out = fn(q, k, v)
+            return jnp.sum(out.astype(jnp.float32) * weight), out
+
+        (_, out), grads = jax.jit(jax.value_and_grad(
+            loss, argnums=(0, 1, 2), has_aux=True))(q, k, v)
+        return np.asarray(out, np.float32), [np.asarray(g) for g in grads]
+
+    with jax.default_matmul_precision("highest"):
+        want_out, want_grads = run(
+            lambda q, k, v: _dense_attention(q, k, v, doc, ok))
+        if path == "kernel":
+            out, grads = run(lambda q, k, v: attention.fused_attention(
+                q, k, v, doc, DIFFUSION, jnp.float32, interpret=True,
+                blocks=attention.block_sizes(BLOCK, BLOCK, BLOCK)))
+        else:
+            out, grads = run(lambda q, k, v: transformer.blocked_attention(
+                q, k, v, doc, DIFFUSION, BLOCK, jnp.float32))
+    np.testing.assert_allclose(out, want_out, atol=2e-5)
+    for got, want in zip(grads, want_grads):
+        np.testing.assert_allclose(got, want, atol=1e-4 * np.abs(want).max())
+
+
+def test_key_blocks_of_the_three_part_mask_at_the_cells_shapes():
+    """At 4,096 clean ids and tiles of 1,024 the kernel visits 24 of 64
+    tiles: 10 block causal, 10 offset block causal, 4 on the noisy
+    diagonal (ISSUE 34), which is the dense mask's count."""
+    length = 4096
+    mask = attention.BlockDiffusion(length, 4)
+    assert attention.key_blocks(2 * length, mask, 8) == (24, 64)
+    bq = attention.BLOCKS.block_q
+    tiles = dense_mask(length, 4).reshape(
+        2 * length // bq, bq, 2 * length // bq, bq).any(axis=(1, 3))
+    assert int(tiles.sum()) == 24
+    with pytest.raises(ValueError, match="positions, not 4096"):
+        attention.key_blocks(length, mask, 8)

@@ -11,7 +11,7 @@ import numpy as np
 import pytest
 from jax.experimental.pallas import tpu as pltpu
 
-from tpu_resnet.models import afmoe
+from tpu_resnet.models import afmoe, transformer
 from tpu_resnet.ops import grouped
 
 # rows, contraction and columns that the tiles (32, 16, 16) do not divide
@@ -187,12 +187,13 @@ def test_the_counters_read_what_a_hand_count_gives(slack, tiers):
     x = jax.random.normal(jax.random.PRNGKey(0), (4, 16, D))
     bias = jnp.zeros((TOTAL,)).at[2].set(10.0)
     layer = _layer(held, slack)
-    assert layer._rows(64, 8) == (96 if slack == 2.0 else 24)
+    rows = transformer.buffer_rows(64, TOP_K, 3, TOTAL, slack, 8)
+    assert rows == (96 if slack == 2.0 else 24)
     for b in (None, bias):
         out, counters, params = _apply(layer, x, b)
         want, assignments = _by_hand(params, x, held, b)
         np.testing.assert_allclose(out.reshape(-1, D), want, atol=2e-5)
-        here, rows = len(assignments), layer._rows(64, 8)
+        here = len(assignments)
         load = np.bincount([e for e, _ in assignments], minlength=3)
         assert counters["moe_dropped_frac"] == 0.0
         assert counters["moe_here_frac"] == pytest.approx(here / 128)
@@ -219,7 +220,7 @@ def test_the_sorted_dispatch_keeps_token_order_within_an_expert():
         return grouped.grouped_dot(lhs, rhs, sizes, out, path)
 
     with pytest.MonkeyPatch.context() as patch:
-        patch.setattr(afmoe, "grouped_dot", spy)
+        patch.setattr(transformer, "grouped_dot", spy)
         with jax.disable_jit():
             layer.apply({"params": params, "batch_stats": {
                 "expert_bias": jnp.zeros((TOTAL,))}}, x, False)
@@ -247,7 +248,7 @@ def test_an_empty_tier_runs_no_product():
         return grouped.grouped_dot(lhs, rhs, sizes, out, path)
 
     with pytest.MonkeyPatch.context() as patch:
-        patch.setattr(afmoe, "grouped_dot", counted)
+        patch.setattr(transformer, "grouped_dot", counted)
         out = jax.jit(lambda p: layer.apply(
             {"params": p, "batch_stats": {
                 "expert_bias": jnp.zeros((TOTAL,))}}, x, False))(params)
@@ -257,3 +258,19 @@ def test_an_empty_tier_runs_no_product():
     assert 24 < here < 128 - 24            # some tiers run, some do not
     assert len(calls) == 3 * -(-here // 24)
     assert sorted(calls)[-1] == 24 and sum(calls) == 3 * here
+
+
+@pytest.mark.parametrize("k, n, forward, d_lhs, d_rhs", [
+    (2048, 1024, (256, 1024, 1024), (256, 1024, 1024), (256, 1024, 1024)),
+    (1024, 2048, (256, 1024, 1024), (256, 1024, 1024), (256, 1024, 1024)),
+    # experts 768 wide: no tile of 1,024 a quarter of which is padding
+    (2048, 768, (256, 1024, 768), (256, 768, 1024), (256, 1024, 768)),
+    (768, 2048, (256, 768, 1024), (256, 1024, 768), (256, 768, 1024)),
+])
+def test_tiles_take_a_smaller_contraction_or_width_whole(k, n, forward,
+                                                         d_lhs, d_rhs):
+    """The three kernels' tiles for ``(rows, k) x (groups, k, n)``: the
+    Trinity cell's shapes keep the swept tiling; the backward product for
+    ``lhs`` contracts over ``n`` and writes ``k`` columns."""
+    assert grouped._fit(grouped.TILING, k, n) == forward == d_rhs
+    assert grouped._fit(grouped.TILING, n, k) == d_lhs

@@ -163,7 +163,9 @@ class ModelConfig:
     (e.g. WRN-28-10 = resnet_size 28, width 10).
     """
 
-    name: str = "resnet"  # resnet | mlp | afmoe (its fields: AfmoeConfig)
+    # resnet | mlp | afmoe (its fields: AfmoeConfig) | sdar_moe
+    # (SdarMoeConfig)
+    name: str = "resnet"
     resnet_size: int = 50
     width_multiplier: int = 1
     # bf16 compute on the MXU with fp32 params/BN stats. "float32" for
@@ -239,6 +241,32 @@ class AfmoeConfig:
     rms_eps: float = 1e-5
     route_scale: float = 2.826
     balance_coeff: float = 0.001
+
+
+@dataclasses.dataclass
+class SdarMoeConfig:
+    """The fields of ``model.name=sdar_moe`` (the family's module has the
+    equations, at ``Arch``): a sparse-expert decoder trained by diffusion
+    over blocks, as one chip of an expert-parallel group holds it. The
+    defaults are the published widths of the preset's source; the
+    vocabulary's rows held here are ``data.vocab_size`` (the last is the
+    mask id) and ``data.seq_len`` counts a sequence's clean ids: the model
+    is fed twice as many positions."""
+
+    layers: int = 4
+    hidden: int = 2048
+    heads: int = 32
+    kv_heads: int = 4
+    head_dim: int = 128
+    expert_width: int = 768
+    experts_total: int = 128   # the router's width
+    experts_first: int = 0     # the routed experts this chip holds:
+    experts_held: int = 16     # experts_first .. experts_first + held
+    top_k: int = 8
+    rope_theta: float = 1e6
+    rms_eps: float = 1e-6
+    block_length: int = 4      # positions a block of the diffusion
+    t_min: float = 1e-3        # a block's noise level is U(t_min, 1]
 
 
 @dataclasses.dataclass
@@ -751,6 +779,8 @@ class RunConfig:
     data: DataConfig = dataclasses.field(default_factory=DataConfig)
     model: ModelConfig = dataclasses.field(default_factory=ModelConfig)
     afmoe: AfmoeConfig = dataclasses.field(default_factory=AfmoeConfig)
+    sdar_moe: SdarMoeConfig = dataclasses.field(
+        default_factory=SdarMoeConfig)
     optim: OptimConfig = dataclasses.field(default_factory=OptimConfig)
     mesh: MeshConfig = dataclasses.field(default_factory=MeshConfig)
     train: TrainConfig = dataclasses.field(default_factory=TrainConfig)
@@ -906,6 +936,19 @@ def _trinity_mini_ep16() -> RunConfig:
     return cfg
 
 
+def _sdar_30b_a3b_chat() -> RunConfig:
+    """SDAR-30B-A3B-Chat (JetLM, ``sdar_moe``) as one of eight chips that
+    share each layer: 16 of the 128 routed experts and an eighth of the
+    vocabulary held here, four of the 48 layers; trained by diffusion over
+    blocks of 4 (a noised and a clean copy of each sequence of 4,096 ids,
+    the loss on the masked positions), AdamW as the other token preset."""
+    cfg = _trinity_mini_ep16()
+    cfg.data.vocab_size = 18_992
+    cfg.model.name = "sdar_moe"
+    cfg.train.global_batch_size = 1
+    return cfg
+
+
 # The supported config space (these presets × mesh/dtype/fused/remat/
 # engine variations) is certified statically: tpu_resnet/analysis/
 # configmatrix.py traces the compiled train/eval program of every
@@ -920,6 +963,7 @@ PRESETS = {
     "imagenet": _imagenet,
     "smoke": _smoke,
     "trinity_mini_ep16": _trinity_mini_ep16,
+    "sdar_30b_a3b_chat": _sdar_30b_a3b_chat,
 }
 
 

@@ -3,8 +3,9 @@
 A family is a plain record (``Family``) under its ``cfg.model.name`` in
 one dict: how to build the module from a ``RunConfig``, what a batch of
 it is (``inputs``: ``images`` or ``tokens``), how its part of a program
-key is spelled, its model FLOPs, the step metrics it counts itself, what
-it refuses to train with and what it says once at start-up. The train
+key is spelled, its model FLOPs, the step metrics it counts itself, its
+training objective where that is not the kind's own, what it refuses to
+train with and what it says once at start-up. The train
 step, the loop, the program registry and the FLOP accounting ask here
 and name no family; a new one costs ``models/<family>.py``, its fields
 and preset in ``config.py``, and one ``register`` below.
@@ -16,13 +17,14 @@ registry sketch (reference models/__init__.py:1-21)."""
 from __future__ import annotations
 
 import dataclasses
-from typing import Callable, Dict, Tuple
+from typing import Callable, Dict, Optional, Tuple
 
 import jax.numpy as jnp
 
-from tpu_resnet.models import afmoe, mlp, resnet
+from tpu_resnet.models import afmoe, mlp, resnet, sdar_moe
 from tpu_resnet.models.afmoe import Afmoe
 from tpu_resnet.models.mlp import MLP
+from tpu_resnet.models.sdar_moe import SdarMoe
 from tpu_resnet.models.resnet import (
     ResNetV2,
     cifar_resnet_v2,
@@ -33,6 +35,7 @@ __all__ = [
     "Afmoe",
     "MLP",
     "ResNetV2",
+    "SdarMoe",
     "cifar_resnet_v2",
     "imagenet_resnet_v2",
     "Family",
@@ -68,6 +71,14 @@ class Family:
     # step metrics the model counts itself: names in its ``counters``
     # collection, each meaned over the layers that sow it
     counters: Tuple[str, ...] = ()
+    # objective(model, rng, inputs, labels) -> (fed, score), or None: the
+    # model is fed the batch's inputs and the loss is the kind's own (of
+    # tokens: the next id's cross-entropy on ``labels``, ``precision`` the
+    # share of positions whose largest logit is the label's). ``rng`` is
+    # the step's key, ``fold_in(base_rng, state.step)``; ``fed`` is what
+    # the model is applied to; ``score(logits) -> (loss, metrics)``, the
+    # metrics holding ``precision`` and whatever else the step reports.
+    objective: Optional[Callable] = None
     # refuses(cfg, data_axis) -> what of cfg the family does not train
     # with, in words (train/step.py::check_step_config raises on any)
     refuses: Callable = lambda cfg, data_axis: []
@@ -124,7 +135,9 @@ def build_model(cfg):
 def sample_input(cfg):
     """What a fresh state's weights are drawn on: one image of the data
     set's size or, for a token model, one short sequence of ids (no
-    leaf's shape depends on the length)."""
+    leaf's shape depends on the length, in either token family: of
+    ``sdar_moe`` the eight ids are a noised and a clean copy of one block
+    of four)."""
     if family(cfg).inputs == "tokens":
         return jnp.zeros((1, 8), jnp.int32)
     size = cfg.data.resolved_image_size
@@ -152,3 +165,9 @@ register(Family("afmoe", "tokens", Afmoe, afmoe.build, afmoe.spell,
                 train_flops_per_example=afmoe.train_flops_per_example,
                 counters=afmoe.COUNTERS, refuses=afmoe.refuses,
                 startup_events=afmoe.startup_events))
+register(Family("sdar_moe", "tokens", SdarMoe, sdar_moe.build,
+                sdar_moe.spell,
+                train_flops_per_example=sdar_moe.train_flops_per_example,
+                counters=sdar_moe.COUNTERS, objective=sdar_moe.objective,
+                refuses=sdar_moe.refuses,
+                startup_events=sdar_moe.startup_events))

@@ -27,33 +27,16 @@ The equations (``d`` hidden size; RMSNorm with a weight everywhere):
   step's routing, ``b += c - mean(c)`` with ``c = load_balance_coeff *
   sign(mean(n) - n)``, ``n`` the assignments per expert.
 
-No token is dropped, and the layer does the work its routing fills: one
-stable sort of the ``N * top_k`` assignments by their expert here puts
-those that fall on a held expert first, grouped by expert and in token
-order within an expert. The first ``rows`` of them (``rows_slack`` x what
-an even routing sends to all held experts together, in whole tiles) are one
-buffer: a gather by the sorted order, three grouped products whose work
-follows the groups' sizes (``ops/grouped.py``, which also says how its path
-is chosen and what its kernel never writes: the buffer's rows past the
-last assignment come back 0, in the result and in every gradient, so none
-of them reaches the weighted scatter-add), and the scatter-add. Whatever
-sorted positions lie beyond the buffer go through the same code a tier of
-``rows`` at a time, only the tiers that hold an assignment, under a
-``lax.cond`` that is false while the held experts together take no more
-than the buffer. The counters say what happened (``moe_dropped_frac`` reads
-0 by that construction and is counted from the groups' sizes all the same;
-``moe_overflow_frac`` says whether the tiers beyond ran,
-``moe_rows_filled_frac`` how much of the buffer carried an assignment).
-
-Attention never builds a ``(B, H, S, S)`` score tensor. On one TPU chip, at
-heads of a multiple of 128 and sequences its blocks divide, it is one fused
-kernel that keeps each tile of scores on the chip (``ops/attention.py``,
-which also says how the path is chosen); everywhere else a block of
-queries at a time against the keys its mask can reach, as a scan whose
-body is under ``jax.checkpoint`` (``blocked_attention``). Parameters, the
-residual stream, norms, router, softmax and logits are float32; matrix
-products take ``dtype`` operands (bf16), accumulate in float32 and hand on
-``dtype``.
+The dispatch of the router's choices to the held experts (one sorted
+buffer, grouped products, overflow tiers, the five ``moe_*`` counters),
+attention by path (one fused kernel on a TPU chip, a scan over blocks of
+queries everywhere else), RMSNorm, the rotary embedding and the products'
+numerics are ``models/transformer.py``'s, shared with the other token
+family; this module keeps what is Trinity's own: the sigmoid router with
+its ``expert_bias``, the shared expert, the attention gate, four norms a
+layer, windows. Parameters, the residual stream, norms, router, softmax
+and logits are float32; matrix products take ``dtype`` operands (bf16),
+accumulate in float32 and hand on ``dtype``.
 """
 
 from __future__ import annotations
@@ -65,118 +48,14 @@ from typing import Any, Dict, List, Tuple
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
-from jax.ad_checkpoint import checkpoint_name
 
-from tpu_resnet.ops.attention import (attention_path, fused_attention,
-                                      key_blocks)
-from tpu_resnet.ops.grouped import grouped_dot, grouped_path, row_tile
+from tpu_resnet.models.transformer import (  # noqa: F401  (re-exported)
+    COUNTERS, RMSNorm, _dot, _f32, _init, _KEEP, _reach, attend,
+    blocked_attention, dispatch_experts, refuses, rotary, sow_counters)
+from tpu_resnet.ops.attention import attention_path, key_blocks
 
 # layer kinds: what F is, and which mask attention takes
 LAYER_KINDS = ("dense_sliding", "dense_full", "moe_sliding", "moe_full")
-COUNTERS = ("moe_dropped_frac", "moe_load_max_over_mean", "moe_here_frac",
-            "moe_overflow_frac", "moe_rows_filled_frac")
-
-_init = nn.initializers.normal(0.02)
-_f32 = jnp.float32
-# What ``remat`` keeps of a layer for its backward pass: the results of its
-# matrix products and of attention; norms, rotary, gates and activations
-# are computed again.
-_KEEP = jax.checkpoint_policies.save_from_both_policies(
-    jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
-    jax.checkpoint_policies.save_only_these_names("attention", "experts"))
-
-
-def _dot(x, w, dtype, out=None):
-    """``x @ w`` over the last axis of ``x`` and the first of ``w``:
-    operands in ``dtype``, accumulation in float32, the result in ``out``
-    (``dtype`` unless said)."""
-    return jax.lax.dot_general(
-        x.astype(dtype), w.astype(dtype),
-        (((x.ndim - 1,), (0,)), ((), ())), preferred_element_type=_f32
-    ).astype(out or dtype)
-
-
-class RMSNorm(nn.Module):
-    eps: float = 1e-5
-
-    @nn.compact
-    def __call__(self, x):
-        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],),
-                           _f32)
-        x = x.astype(_f32)
-        return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True)
-                                 + self.eps) * scale
-
-
-def rotary(x, theta: float):
-    """Rotate-half rotary embedding over the whole head; ``x`` is
-    ``(B, S, H, D)`` float32, positions count from the sequence's start."""
-    d = x.shape[-1]
-    inv = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=_f32) / d))
-    ang = jnp.arange(x.shape[1], dtype=_f32)[:, None] * inv[None, :]
-    cos = jnp.concatenate([jnp.cos(ang)] * 2, -1)[None, :, None, :]
-    sin = jnp.concatenate([jnp.sin(ang)] * 2, -1)[None, :, None, :]
-    x1, x2 = x[..., : d // 2], x[..., d // 2:]
-    return x * cos + jnp.concatenate([-x2, x1], -1) * sin
-
-
-def _reach(seq_len: int, window: int, block: int) -> int:
-    """The positions before a block's first query that the scan's every
-    block takes keys from: the window, or on a full layer everything
-    before the last block."""
-    return min(window, seq_len - block) if window else seq_len - block
-
-
-def blocked_attention(q, k, v, doc, window: int, block: int, dtype):
-    """Causal attention within documents, ``window`` > 0 for a sliding
-    layer. ``q`` is ``(B, S, KV, G, D)`` (G query heads a key/value head),
-    ``k`` and ``v`` ``(B, S, KV, D)``, ``doc`` ``(B, S)``. Returns ``(B, S,
-    KV, G, D)`` in ``dtype``.
-
-    A ``lax.scan`` over blocks of queries. Every block takes the same
-    number of keys, ``reach + block``: the ``reach`` positions before its
-    first query that a mask can let it see (the window, or on a full layer
-    everything before the last block) and its own. Keys and documents are
-    padded in front by ``reach`` (document -1, which no query belongs to),
-    so that the first blocks take that many too: one shape, one body, the
-    scores of one block alive at a time."""
-    b, s, kv, g, d = q.shape
-    block = min(block, s)
-    if s % block:
-        raise ValueError(f"sequence length {s} is not a multiple of the "
-                         f"attention block {block}")
-    reach = _reach(s, window, block)
-    span = reach + block
-    scale = 1.0 / math.sqrt(d)
-    front = ((0, 0), (reach, 0))
-    kp = jnp.pad(k.astype(dtype), front + ((0, 0), (0, 0)))
-    vp = jnp.pad(v.astype(dtype), front + ((0, 0), (0, 0)))
-    docp = jnp.pad(doc, front, constant_values=-1)
-
-    @jax.checkpoint
-    def one(qb, doc_q, q0):
-        kb, vb, doc_k = (jax.lax.dynamic_slice_in_dim(a, q0, span, axis=1)
-                         for a in (kp, vp, docp))
-        sc = jnp.einsum("bqhgd,bkhd->bhgqk", qb, kb,
-                        preferred_element_type=_f32)
-        qi = q0 + jnp.arange(block)[:, None]
-        kj = q0 - reach + jnp.arange(span)[None, :]
-        ok = kj <= qi
-        if window:
-            ok &= qi - kj < window
-        ok = ok[None] & (doc_q[:, :, None] == doc_k[:, None, :])
-        sc = jnp.where(ok[:, None, None], sc * scale, -1e30)
-        p = jax.nn.softmax(sc, axis=-1)
-        return jnp.einsum("bhgqk,bkhd->bqhgd", p.astype(dtype), vb,
-                          preferred_element_type=_f32).astype(dtype)
-
-    nb = s // block
-    _, out = jax.lax.scan(
-        lambda _, x: (None, one(*x)), None,
-        (jnp.moveaxis(q.astype(dtype).reshape(b, nb, block, kv, g, d), 1, 0),
-         jnp.moveaxis(doc.reshape(b, nb, block), 1, 0),
-         jnp.arange(nb, dtype=jnp.int32) * block))
-    return jnp.moveaxis(out, 0, 1).reshape(b, s, kv, g, d)
 
 
 class Attention(nn.Module):
@@ -207,14 +86,7 @@ class Attention(nn.Module):
             if self.window:
                 q, k = rotary(q, self.rope_theta), rotary(k, self.rope_theta)
         with jax.named_scope("scores"):
-            q = q.reshape(b, s, kv, h // kv, hd)
-            if attention_path(jax.default_backend(), jax.device_count(),
-                              hd, s) == "kernel":
-                out = fused_attention(q, k, v, doc, self.window, self.dtype)
-            else:
-                out = blocked_attention(q, k, v, doc, self.window,
-                                        self.block, self.dtype)
-            out = checkpoint_name(out.reshape(b, s, h * hd), "attention")
+            out = attend(q, k, v, doc, self.window, self.block, self.dtype)
         with jax.named_scope("gate_out"):
             out = out * jax.nn.sigmoid(gate)
             return _dot(out, self.param("wo", _init, (h * hd, d), _f32),
@@ -249,29 +121,18 @@ class ExpertLayer(nn.Module):
     rows_slack: float
     dtype: Any
 
-    def _rows(self, n: int, tile: int) -> int:
-        """The buffer's rows for ``n`` tokens: ``rows_slack`` x what an
-        even routing sends to the experts held here all together, in whole
-        tiles, and no more than every assignment there is."""
-        even = n * self.top_k * self.experts_held[1] / self.experts_total
-        return -(-min(n * self.top_k, math.ceil(self.rows_slack * even))
-                 // tile) * tile
-
     @nn.compact
     def __call__(self, x, train: bool):
         shape = x.shape
         x = x.reshape(-1, shape[-1])                   # (N, d) float32
-        n, d = x.shape
-        first, count = self.experts_held
+        d = x.shape[1]
+        count = self.experts_held[1]
         k, total = self.top_k, self.experts_total
         bias = self.variable("batch_stats", "expert_bias",
                              lambda: jnp.zeros((total,), _f32))
         w_gate = self.param("gate", _init, (count, d, self.width), _f32)
         w_up = self.param("up", _init, (count, d, self.width), _f32)
         w_down = self.param("down", _init, (count, self.width, d), _f32)
-        path = grouped_path(jax.default_backend(), jax.device_count())
-        rows = self._rows(n, row_tile(path))
-        tiers = -(-n * k // rows)
 
         with jax.named_scope("router"):
             scores = jax.nn.sigmoid(jnp.dot(
@@ -283,70 +144,10 @@ class ExpertLayer(nn.Module):
             weight = s / (jnp.sum(s, -1, keepdims=True) + 1e-20) \
                 * self.route_scale                     # (N, k) float32
 
-        with jax.named_scope("dispatch"):
-            # every assignment's expert here (``count`` = not held); one
-            # stable sort puts the held ones first, grouped by expert, in
-            # token order within an expert
-            local = (chosen - first).reshape(-1)
-            expert = jnp.where((local >= 0) & (local < count), local, count)
-            load = jnp.sum(expert[:, None] == jnp.arange(count)[None, :],
-                           axis=0, dtype=jnp.int32)    # per held expert
-            ends = jnp.cumsum(load)
-            here_n = ends[-1]
-            order = jnp.pad(jnp.argsort(expert, stable=True),
-                            (0, tiers * rows - n * k))
-
-        def tier(lo, xb, weight, w_gate, w_up, w_down):
-            """The partial result of the sorted positions ``lo .. lo +
-            rows``, and how many of them hold an assignment. The positions
-            past ``here_n`` lie past the groups' sum: the products leave
-            them 0, in the result and in every gradient."""
-            with jax.named_scope("dispatch"):
-                at = jax.lax.dynamic_slice_in_dim(order, lo, rows)
-                token = at // k
-                sizes = (jnp.clip(ends, lo, lo + rows)
-                         - jnp.clip(ends - load, lo, lo + rows))
-                xs = jnp.take(xb, token, axis=0)
-            with jax.named_scope("experts"):
-                def mm(a, w, out=self.dtype):
-                    return grouped_dot(a.astype(self.dtype),
-                                       w.astype(self.dtype), sizes, out,
-                                       path)
-
-                hidden = (checkpoint_name(mm(xs, w_gate), "experts"),
-                          checkpoint_name(mm(xs, w_up), "experts"))
-                y = mm(jax.nn.silu(hidden[0]) * hidden[1], w_down, _f32)
-            with jax.named_scope("combine"):
-                y = y * jnp.take(weight.reshape(-1), at)[:, None]
-                return (jnp.zeros((n, d), _f32).at[token].add(y),
-                        jnp.sum(sizes))
-
-        operands = (x.astype(self.dtype), weight, w_gate, w_up, w_down)
-        out, computed = tier(0, *operands)
-        if tiers > 1:
-            # The positions beyond, a tier at a time and only the tiers
-            # that hold an assignment. Recomputed backward, as a whole (a
-            # cond hands on the residuals of both its branches: 3.5 GB of
-            # temporaries in the benchmark's cell) and a tier at a time
-            # within (the scan would stack every tier's: 0.9 GB).
-            def nothing(*_):
-                return jnp.zeros((n, d), _f32), jnp.zeros((), jnp.int32)
-
-            @jax.checkpoint
-            def beyond(*operands):
-                def one(acc, lo):
-                    more, also = jax.lax.cond(
-                        lo < here_n, jax.checkpoint(tier), nothing, lo,
-                        *operands)
-                    return (acc[0] + more, acc[1] + also), None
-
-                return jax.lax.scan(
-                    one, nothing(), jnp.arange(1, tiers, dtype=jnp.int32)
-                    * rows)[0]
-
-            more, also = jax.lax.cond(here_n > rows, beyond, nothing,
-                                      *operands)
-            out, computed = out + more, computed + also
+        out, counters = dispatch_experts(
+            x, chosen, weight, w_gate, w_up, w_down, experts_total=total,
+            experts_held=self.experts_held, rows_slack=self.rows_slack,
+            dtype=self.dtype)
 
         if self.shared:
             with jax.named_scope("shared"):
@@ -354,18 +155,7 @@ class ExpertLayer(nn.Module):
                                    name="shared")(x)
 
         with jax.named_scope("router"):
-            here = here_n.astype(_f32)
-            for name, value in (
-                    ("moe_dropped_frac", (here - computed.astype(_f32))
-                     / jnp.maximum(here, 1.0)),
-                    ("moe_load_max_over_mean", jnp.max(load).astype(_f32)
-                     * count / jnp.maximum(here, 1.0)),
-                    ("moe_here_frac", here / (n * k)),
-                    ("moe_overflow_frac", (here_n > rows).astype(_f32)),
-                    ("moe_rows_filled_frac",
-                     jnp.minimum(here, rows) / rows)):
-                self.sow("counters", name, value, init_fn=lambda: 0.0,
-                         reduce_fn=lambda old, new: new)
+            sow_counters(self, counters)
             if train and not self.is_initializing():
                 per_expert = jnp.sum(jax.nn.one_hot(
                     chosen.reshape(-1), total, dtype=_f32), axis=0)
@@ -562,18 +352,6 @@ def train_flops_per_example(cfg, xla_counted: bool = True) -> float:
     """Counted from the shapes: XLA's count of the lowered step would
     hold what attention recomputes backward."""
     return train_flops_per_sequence(build(cfg).arch, cfg.data.seq_len)
-
-
-def refuses(cfg, data_axis: int):
-    """What of ``cfg`` this family does not train with, beside what no
-    token model does (train/step.py::check_step_config)."""
-    refused = [
-        ("mesh.partition=zero1 (no rule shards expert or attention "
-         "leaves yet)", cfg.mesh.partition != "replicated"),
-        ("model.fused_blocks / model.fused_epilogue (ResNet kernels)",
-         cfg.model.fused_blocks or cfg.model.fused_epilogue != "off"),
-    ]
-    return [what for what, is_set in refused if is_set]
 
 
 def startup_events(model: Afmoe, cfg):

@@ -1,0 +1,308 @@
+"""What the token families share (``afmoe``, ``sdar_moe``): the numerics of
+a matrix product, RMSNorm, the rotary embedding by position ids, attention
+by whichever path the backend and the shapes give, and the dispatch of an
+expert layer's assignments to the experts this chip holds. A family keeps
+what is its own: its router, its norms' places, its masks, its objective.
+
+**The dispatch** (``dispatch_experts``). A family's router hands over
+``(x, chosen, weight)``: for each of ``N`` tokens the ``top_k`` experts
+chosen of ``experts_total`` and their weights. No token is dropped, and
+the layer does the work its routing fills: one stable sort of the ``N *
+top_k`` assignments by their expert here puts those that fall on a held
+expert (``experts_held = (first, count)``) first, grouped by expert and in
+token order within an expert. The first ``rows`` of them (``rows_slack`` x
+what an even routing sends to all held experts together, in whole tiles)
+are one buffer: a gather by the sorted order, three grouped products whose
+work follows the groups' sizes (``ops/grouped.py``, which also says how
+its path is chosen and what its kernel never writes: the buffer's rows
+past the last assignment come back 0, in the result and in every gradient,
+so none of them reaches the weighted scatter-add), and the scatter-add.
+Whatever sorted positions lie beyond the buffer go through the same code a
+tier of ``rows`` at a time, only the tiers that hold an assignment, under
+a ``lax.cond`` that is false while the held experts together take no more
+than the buffer. What the absent experts would add is left out and that
+partial result goes on. The counters say what happened
+(``moe_dropped_frac`` reads 0 by that construction and is counted from the
+groups' sizes all the same; ``moe_overflow_frac`` says whether the tiers
+beyond ran, ``moe_rows_filled_frac`` how much of the buffer carried an
+assignment).
+
+**Attention** (``attend``) never builds a ``(B, H, S, S)`` score tensor.
+On one TPU chip, at heads of a multiple of 128 and sequences its blocks
+divide, it is one fused kernel that keeps each tile of scores on the chip
+(``ops/attention.py``, which also says how the path is chosen and what a
+mask is); everywhere else a block of queries at a time against the keys
+its mask can reach, as a scan whose body is under ``jax.checkpoint``
+(``blocked_attention``).
+"""
+
+from __future__ import annotations
+
+import math
+from typing import Dict, Tuple
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
+
+from tpu_resnet.ops.attention import (BlockDiffusion, attention_path,
+                                      fused_attention)
+from tpu_resnet.ops.grouped import grouped_dot, grouped_path, row_tile
+
+COUNTERS = ("moe_dropped_frac", "moe_load_max_over_mean", "moe_here_frac",
+            "moe_overflow_frac", "moe_rows_filled_frac")
+
+_init = nn.initializers.normal(0.02)
+_f32 = jnp.float32
+# What ``remat`` keeps of a layer for its backward pass: the results of its
+# matrix products and of attention; norms, rotary, gates and activations
+# are computed again.
+_KEEP = jax.checkpoint_policies.save_from_both_policies(
+    jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
+    jax.checkpoint_policies.save_only_these_names("attention", "experts"))
+
+
+def _dot(x, w, dtype, out=None):
+    """``x @ w`` over the last axis of ``x`` and the first of ``w``:
+    operands in ``dtype``, accumulation in float32, the result in ``out``
+    (``dtype`` unless said)."""
+    return jax.lax.dot_general(
+        x.astype(dtype), w.astype(dtype),
+        (((x.ndim - 1,), (0,)), ((), ())), preferred_element_type=_f32
+    ).astype(out or dtype)
+
+
+class RMSNorm(nn.Module):
+    eps: float = 1e-5
+
+    @nn.compact
+    def __call__(self, x):
+        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],),
+                           _f32)
+        x = x.astype(_f32)
+        return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True)
+                                 + self.eps) * scale
+
+
+def rotary(x, theta: float, positions=None):
+    """Rotate-half rotary embedding over the whole head; ``x`` is
+    ``(B, S, H, D)`` float32. ``positions`` are ``(B, S)`` position ids;
+    without them positions count from the sequence's start."""
+    d = x.shape[-1]
+    inv = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=_f32) / d))
+    if positions is None:
+        positions = jnp.arange(x.shape[1])[None]
+    ang = positions.astype(_f32)[:, :, None] * inv
+    cos = jnp.concatenate([jnp.cos(ang)] * 2, -1)[:, :, None, :]
+    sin = jnp.concatenate([jnp.sin(ang)] * 2, -1)[:, :, None, :]
+    x1, x2 = x[..., : d // 2], x[..., d // 2:]
+    return x * cos + jnp.concatenate([-x2, x1], -1) * sin
+
+
+# --------------------------------------------------------------- attention
+def _reach(seq_len: int, window: int, block: int) -> int:
+    """The positions before a block's first query that the scan's every
+    block takes keys from: the window, or on a full layer everything
+    before the last block."""
+    return min(window, seq_len - block) if window else seq_len - block
+
+
+def blocked_attention(q, k, v, doc, mask, block: int, dtype):
+    """Attention within documents under ``mask`` (``ops/attention.py``: a
+    window, 0 for all that went before, or ``BlockDiffusion``). ``q`` is
+    ``(B, S, KV, G, D)`` (G query heads a key/value head), ``k`` and ``v``
+    ``(B, S, KV, D)``, ``doc`` ``(B, S)``. Returns ``(B, S, KV, G, D)`` in
+    ``dtype``.
+
+    A ``lax.scan`` over blocks of queries. Under a causal mask every block
+    takes the same number of keys, ``reach + block``: the ``reach``
+    positions before its first query that a mask can let it see (the
+    window, or on a full layer everything before the last block) and its
+    own. Keys and documents are padded in front by ``reach`` (document -1,
+    which no query belongs to), so that the first blocks take that many
+    too: one shape, one body, the scores of one block alive at a time.
+    Under ``BlockDiffusion`` a noisy query sees clean keys that stand
+    behind it, so every block takes all the keys."""
+    b, s, kv, g, d = q.shape
+    block = min(block, s)
+    if s % block:
+        raise ValueError(f"sequence length {s} is not a multiple of the "
+                         f"attention block {block}")
+    diffusion = isinstance(mask, BlockDiffusion)
+    reach = 0 if diffusion else _reach(s, mask, block)
+    span = s if diffusion else reach + block
+    scale = 1.0 / math.sqrt(d)
+    front = ((0, 0), (reach, 0))
+    kp = jnp.pad(k.astype(dtype), front + ((0, 0), (0, 0)))
+    vp = jnp.pad(v.astype(dtype), front + ((0, 0), (0, 0)))
+    docp = jnp.pad(doc, front, constant_values=-1)
+
+    @jax.checkpoint
+    def one(qb, doc_q, q0):
+        first = 0 if diffusion else q0       # of the span, in the padding
+        kb, vb, doc_k = (jax.lax.dynamic_slice_in_dim(a, first, span, axis=1)
+                         for a in (kp, vp, docp))
+        sc = jnp.einsum("bqhgd,bkhd->bhgqk", qb, kb,
+                        preferred_element_type=_f32)
+        qi = q0 + jnp.arange(block)[:, None]
+        kj = first - reach + jnp.arange(span)[None, :]
+        if diffusion:
+            ok = mask.allows(qi, kj)
+        else:
+            ok = kj <= qi
+            if mask:
+                ok &= qi - kj < mask
+        ok = ok[None] & (doc_q[:, :, None] == doc_k[:, None, :])
+        sc = jnp.where(ok[:, None, None], sc * scale, -1e30)
+        p = jax.nn.softmax(sc, axis=-1)
+        return jnp.einsum("bhgqk,bkhd->bqhgd", p.astype(dtype), vb,
+                          preferred_element_type=_f32).astype(dtype)
+
+    nb = s // block
+    _, out = jax.lax.scan(
+        lambda _, x: (None, one(*x)), None,
+        (jnp.moveaxis(q.astype(dtype).reshape(b, nb, block, kv, g, d), 1, 0),
+         jnp.moveaxis(doc.reshape(b, nb, block), 1, 0),
+         jnp.arange(nb, dtype=jnp.int32) * block))
+    return jnp.moveaxis(out, 0, 1).reshape(b, s, kv, g, d)
+
+
+def attend(q, k, v, doc, mask, block: int, dtype):
+    """``blocked_attention``'s contract by whichever path
+    ``attention_path`` gives here; the result is named ``attention`` for a
+    ``remat`` policy. ``q`` is ``(B, S, H, D)``; returns ``(B, S, H * D)``."""
+    b, s, h, hd = q.shape
+    kv = k.shape[2]
+    q = q.reshape(b, s, kv, h // kv, hd)
+    if attention_path(jax.default_backend(), jax.device_count(), hd,
+                      s) == "kernel":
+        out = fused_attention(q, k, v, doc, mask, dtype)
+    else:
+        out = blocked_attention(q, k, v, doc, mask, block, dtype)
+    return checkpoint_name(out.reshape(b, s, h * hd), "attention")
+
+
+# ----------------------------------------------------------------- experts
+def buffer_rows(n: int, top_k: int, held: int, total: int, slack: float,
+                tile: int) -> int:
+    """The buffer's rows for ``n`` tokens: ``slack`` x what an even
+    routing sends to the ``held`` experts here all together, in whole
+    tiles, and no more than every assignment there is."""
+    even = n * top_k * held / total
+    return -(-min(n * top_k, math.ceil(slack * even)) // tile) * tile
+
+
+def dispatch_experts(x, chosen, weight, w_gate, w_up, w_down, *,
+                     experts_total: int, experts_held: Tuple[int, int],
+                     rows_slack: float, dtype
+                     ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    """The partial result of the experts held here and what the routing
+    did (``COUNTERS``), see the module docstring. ``x`` is ``(N, d)``
+    float32, ``chosen`` and ``weight`` ``(N, top_k)``; the three weights
+    are ``(count, d, width)``, ``(count, d, width)`` and ``(count, width,
+    d)`` float32."""
+    n, d = x.shape
+    k = chosen.shape[1]
+    first, count = experts_held
+    path = grouped_path(jax.default_backend(), jax.device_count())
+    rows = buffer_rows(n, k, count, experts_total, rows_slack,
+                       row_tile(path))
+    tiers = -(-n * k // rows)
+
+    with jax.named_scope("dispatch"):
+        # every assignment's expert here (``count`` = not held); one
+        # stable sort puts the held ones first, grouped by expert, in
+        # token order within an expert
+        local = (chosen - first).reshape(-1)
+        expert = jnp.where((local >= 0) & (local < count), local, count)
+        load = jnp.sum(expert[:, None] == jnp.arange(count)[None, :],
+                       axis=0, dtype=jnp.int32)    # per held expert
+        ends = jnp.cumsum(load)
+        here_n = ends[-1]
+        order = jnp.pad(jnp.argsort(expert, stable=True),
+                        (0, tiers * rows - n * k))
+
+    def tier(lo, xb, weight, w_gate, w_up, w_down):
+        """The partial result of the sorted positions ``lo .. lo +
+        rows``, and how many of them hold an assignment. The positions
+        past ``here_n`` lie past the groups' sum: the products leave
+        them 0, in the result and in every gradient."""
+        with jax.named_scope("dispatch"):
+            at = jax.lax.dynamic_slice_in_dim(order, lo, rows)
+            token = at // k
+            sizes = (jnp.clip(ends, lo, lo + rows)
+                     - jnp.clip(ends - load, lo, lo + rows))
+            xs = jnp.take(xb, token, axis=0)
+        with jax.named_scope("experts"):
+            def mm(a, w, out=dtype):
+                return grouped_dot(a.astype(dtype), w.astype(dtype), sizes,
+                                   out, path)
+
+            hidden = (checkpoint_name(mm(xs, w_gate), "experts"),
+                      checkpoint_name(mm(xs, w_up), "experts"))
+            y = mm(jax.nn.silu(hidden[0]) * hidden[1], w_down, _f32)
+        with jax.named_scope("combine"):
+            y = y * jnp.take(weight.reshape(-1), at)[:, None]
+            return (jnp.zeros((n, d), _f32).at[token].add(y),
+                    jnp.sum(sizes))
+
+    operands = (x.astype(dtype), weight, w_gate, w_up, w_down)
+    out, computed = tier(0, *operands)
+    if tiers > 1:
+        # The positions beyond, a tier at a time and only the tiers
+        # that hold an assignment. Recomputed backward, as a whole (a
+        # cond hands on the residuals of both its branches: 3.5 GB of
+        # temporaries in the benchmark's cell) and a tier at a time
+        # within (the scan would stack every tier's: 0.9 GB).
+        def nothing(*_):
+            return jnp.zeros((n, d), _f32), jnp.zeros((), jnp.int32)
+
+        @jax.checkpoint
+        def beyond(*operands):
+            def one(acc, lo):
+                more, also = jax.lax.cond(
+                    lo < here_n, jax.checkpoint(tier), nothing, lo,
+                    *operands)
+                return (acc[0] + more, acc[1] + also), None
+
+            return jax.lax.scan(
+                one, nothing(), jnp.arange(1, tiers, dtype=jnp.int32)
+                * rows)[0]
+
+        more, also = jax.lax.cond(here_n > rows, beyond, nothing,
+                                  *operands)
+        out, computed = out + more, computed + also
+
+    with jax.named_scope("router"):
+        here = here_n.astype(_f32)
+        counters = {
+            "moe_dropped_frac": (here - computed.astype(_f32))
+            / jnp.maximum(here, 1.0),
+            "moe_load_max_over_mean": jnp.max(load).astype(_f32) * count
+            / jnp.maximum(here, 1.0),
+            "moe_here_frac": here / (n * k),
+            "moe_overflow_frac": (here_n > rows).astype(_f32),
+            "moe_rows_filled_frac": jnp.minimum(here, rows) / rows,
+        }
+    return out, counters
+
+
+def refuses(cfg, data_axis: int):
+    """What of ``cfg`` neither token family trains with, beside what no
+    token model does (train/step.py::check_step_config)."""
+    refused = [
+        ("mesh.partition=zero1 (no rule shards expert or attention "
+         "leaves yet)", cfg.mesh.partition != "replicated"),
+        ("model.fused_blocks / model.fused_epilogue (ResNet kernels)",
+         cfg.model.fused_blocks or cfg.model.fused_epilogue != "off"),
+    ]
+    return [what for what, is_set in refused if is_set]
+
+
+def sow_counters(module: nn.Module, counters: Dict[str, jax.Array]) -> None:
+    """A layer's counters into the module's ``counters`` collection, where
+    the train step finds a family's ``Family.counters``."""
+    for name in COUNTERS:
+        module.sow("counters", name, counters[name], init_fn=lambda: 0.0,
+                   reduce_fn=lambda old, new: new)

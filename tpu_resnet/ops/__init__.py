@@ -4,8 +4,8 @@ same kernel code runs (slowly) on CPU in tests. The image model's ops
 compile-time A/B probe (ops/autotune.py): such a lowering rides only where
 it measured a win over XLA, because there the two stand within a few
 percent of each other. Attention (ops/attention.py) and the experts'
-grouped products (ops/grouped.py), both imported by the afmoe family's
-module and not re-exported here, are chosen by backend and shape alone: the
+grouped products (ops/grouped.py), both imported by what the token
+families share (models/transformer.py) and not re-exported here, are chosen by backend and shape alone: the
 paths they replace send every score through HBM several times and multiply
 four padded rows for each one filled, so no timing could choose otherwise,
 and a probe would cost a compile of each side at start-up."""

@@ -5,7 +5,8 @@ group 1's, ...), every group has a matrix of its own in ``rhs``, and row
 fall short of the rows: the rows past it belong to no group, and they are
 0 in the result and in the gradient with respect to ``lhs``, and add
 nothing to the gradient with respect to ``rhs``. That is the product of
-the token model's routed experts (models/afmoe.py::ExpertLayer): one buffer
+a token model's routed experts (models/transformer.py::dispatch_experts,
+which both token families' expert layers call): one buffer
 of assignments sorted by expert, sized to what the held experts take
 together and not to the busiest one times their number.
 
@@ -50,8 +51,11 @@ import jax
 import jax.numpy as jnp
 from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm, tgmm
 
-# Rows, contraction and columns of a tile, for all three kernels. Fixed
-# after a sweep on the chip at the cell's shapes (PERF.md section 5).
+# Rows, contraction and columns of a tile, for all three kernels: the most
+# of each. Fixed after a sweep on the chip at the Trinity cell's shapes
+# (PERF.md section 5). A product whose contraction or columns are fewer
+# takes them whole (``_fit``): experts 768 wide would otherwise fill three
+# quarters of a tile of 1,024 and multiply the rest as padding.
 TILING = (256, 1024, 1024)
 
 
@@ -73,9 +77,16 @@ def _zero_past(out, group_sizes):
     return jnp.where(inside[:, None], out, 0)
 
 
+def _fit(tiling, contraction: int, columns: int):
+    """``tiling`` with its contraction and columns no more than the
+    product's own."""
+    return (tiling[0], min(tiling[1], contraction), min(tiling[2], columns))
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
 def _kernel_dot(lhs, rhs, group_sizes, out_dtype, tiling, interpret):
-    return _zero_past(gmm(lhs, rhs, group_sizes, out_dtype, tiling,
+    return _zero_past(gmm(lhs, rhs, group_sizes, out_dtype,
+                          _fit(tiling, *rhs.shape[1:]),
                           interpret=interpret), group_sizes)
 
 
@@ -87,11 +98,13 @@ def _kernel_fwd(lhs, rhs, group_sizes, out_dtype, tiling, interpret):
 def _kernel_bwd(out_dtype, tiling, interpret, residuals, grad):
     lhs, rhs, group_sizes = residuals
     grad = grad.astype(lhs.dtype)
+    k, n = rhs.shape[1:]
     d_lhs = _zero_past(gmm(
-        grad, rhs, group_sizes, lhs.dtype, tiling, transpose_rhs=True,
-        interpret=interpret), group_sizes)
-    d_rhs = tgmm(lhs.swapaxes(0, 1), grad, group_sizes, rhs.dtype, tiling,
-                 num_actual_groups=rhs.shape[0], interpret=interpret)
+        grad, rhs, group_sizes, lhs.dtype, _fit(tiling, n, k),
+        transpose_rhs=True, interpret=interpret), group_sizes)
+    d_rhs = tgmm(lhs.swapaxes(0, 1), grad, group_sizes, rhs.dtype,
+                 _fit(tiling, k, n), num_actual_groups=rhs.shape[0],
+                 interpret=interpret)
     return d_lhs, d_rhs, None
 
 

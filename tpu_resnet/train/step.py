@@ -154,10 +154,13 @@ def make_train_step(model, optim_cfg, schedule, num_classes: int,
     What a batch is, is the model's family's to say
     (``models.family_of(model).inputs``). Of ``tokens``: the inputs are
     ``(B, S)`` ids and the labels the next ids. The loss is
-    ``token_xent``; no augmentation, no L2 term and no xent probe are
-    part of that path, and the metrics also carry the ``tokens`` of the
-    step. Whatever the kind, the metrics carry the family's ``counters``,
-    each meaned over the layers that count it.
+    ``token_xent`` on the batch as fed unless the family has an
+    ``objective`` of its own, which is then asked, with the step's key,
+    what the model is fed and how its logits score (``Family.objective``);
+    no augmentation, no L2 term and no xent probe are part of that path,
+    and the metrics also carry the ``tokens`` of the step (the ids of the
+    batch). Whatever the kind, the metrics carry the family's
+    ``counters``, each meaned over the layers that count it.
 
     ``images`` may be raw uint8 (augment_fn applied on device) or
     pre-processed floats (augment_fn=None).
@@ -230,11 +233,17 @@ def make_train_step(model, optim_cfg, schedule, num_classes: int,
                 images = augment_fn(rng, images)
 
         def loss_fn(params):
+            fed, score = images, None
             with jax.named_scope("forward"):
+                if fam.objective is not None:
+                    fed, score = fam.objective(model, rng, images, labels)
                 logits, new_model_state = model.apply(
                     {"params": params, "batch_stats": state.batch_stats},
-                    images, train=True, mutable=collections)
+                    fed, train=True, mutable=collections)
             with jax.named_scope("loss"):
+                if score is not None:
+                    loss, scored = score(logits)
+                    return loss, (scored, new_model_state)
                 if tokens:
                     return token_xent(logits, labels), (logits,
                                                         new_model_state)
@@ -248,12 +257,15 @@ def make_train_step(model, optim_cfg, schedule, num_classes: int,
                     params, optim_cfg.weight_decay_on_bn)
                 return xent + penalty, (logits, new_model_state)
 
-        (loss, (logits, new_model_state)), grads = jax.value_and_grad(
+        # ``out``: the logits or, where the family's objective scored
+        # them, its metrics (``precision`` among them)
+        (loss, (out, new_model_state)), grads = jax.value_and_grad(
             loss_fn, has_aux=True)(state.params)
         new_batch_stats = new_model_state["batch_stats"]
+        scored = {} if fam.objective is None else dict(out)
         with jax.named_scope("metrics"):
-            precision = jnp.mean(
-                (jnp.argmax(logits, axis=-1) == labels).astype(jnp.float32))
+            precision = scored.pop("precision") if scored else jnp.mean(
+                (jnp.argmax(out, axis=-1) == labels).astype(jnp.float32))
         if grad_axis is not None:
             # Explicit ICI all-reduces (the shard_map analog of what XLA
             # emits on the jit path): average grads; average the EMA stats
@@ -286,6 +298,7 @@ def make_train_step(model, optim_cfg, schedule, num_classes: int,
                     metrics[name] = jnp.mean(jnp.stack(layers))
             if tokens:
                 metrics["tokens"] = jnp.float32(labels.size)
+            metrics.update(scored)
         return new_state, metrics
 
     return train_step
