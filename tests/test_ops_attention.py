@@ -3,16 +3,20 @@ interpret mode, held to the scan it replaces on a TPU
 (models/transformer.py::blocked_attention): the same contract, the same
 masks (causal, windowed, and the three-part mask of training by diffusion
 over blocks, each within documents), forward and in all three gradients,
-and both against the dense three-part mask; the tiles its mask tables
-visit against a count from the dense mask; the choice between the two
-paths; and what ``train()`` says of it at start-up."""
+and both against the dense three-part mask (under it the kernel path is
+two parts: the kernel over the clean keys, the noisy diagonal beside it);
+the tiles its mask tables visit against a count from the dense mask; the
+choice between the two paths; that a causal mask and a window still trace
+the program they traced; and what ``train()`` says of it at start-up."""
 
 import json
+import math
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental.pallas.ops.tpu import splash_attention as splash
 
 from benchmarks.reference.sdar_moe import mask as dense_mask
 from tpu_resnet.config import load_config
@@ -41,25 +45,27 @@ def _inputs():
     return q, k, v, jnp.asarray(np.cumsum(starts, axis=1)), weight
 
 
+def _run(fn, q, k, v, weight):
+    """``(output, [dq, dk, dv])`` of ``fn(q, k, v)``, the gradients those
+    of one weighted sum of the output."""
+    def loss(q, k, v):
+        out = fn(q, k, v)
+        return jnp.sum(out.astype(jnp.float32) * weight), out
+
+    (_, out), grads = jax.jit(jax.value_and_grad(
+        loss, argnums=(0, 1, 2), has_aux=True))(q, k, v)
+    return np.asarray(out, np.float32), [np.asarray(g) for g in grads]
+
+
 def _both(window, dtype):
-    """``(output, (dq, dk, dv))`` of the scan and of the kernel, the
-    gradients those of one weighted sum of the output."""
+    """``(output, (dq, dk, dv))`` of the scan and of the kernel."""
     q, k, v, doc, weight = _inputs()
-
-    def run(fn):
-        def loss(q, k, v):
-            out = fn(q, k, v)
-            return jnp.sum(out.astype(jnp.float32) * weight), out
-
-        (_, out), grads = jax.jit(jax.value_and_grad(
-            loss, argnums=(0, 1, 2), has_aux=True))(q, k, v)
-        return np.asarray(out, np.float32), [np.asarray(g) for g in grads]
-
-    return (run(lambda q, k, v: afmoe.blocked_attention(
-                q, k, v, doc, window, BLOCK, dtype)),
-            run(lambda q, k, v: attention.fused_attention(
+    return (_run(lambda q, k, v: afmoe.blocked_attention(
+                q, k, v, doc, window, BLOCK, dtype), q, k, v, weight),
+            _run(lambda q, k, v: attention.fused_attention(
                 q, k, v, doc, window, dtype, interpret=True,
-                blocks=attention.block_sizes(BLOCK, BLOCK, BLOCK))))
+                blocks=attention.block_sizes(BLOCK, BLOCK, BLOCK)),
+                q, k, v, weight))
 
 
 @WINDOWS
@@ -221,26 +227,77 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-@pytest.mark.parametrize("window", [2048, 0], ids=["sliding", "full"])
-def test_the_cells_kernels_compile_for_a_v5e(one_chip, window):
-    """Mosaic takes the forward and the backward kernel at the cell's
+@pytest.mark.parametrize("batch, mask", [
+    (2, 2048), (2, 0), (1, attention.BlockDiffusion(4096, 4))],
+    ids=["sliding", "full", "block_diffusion"])
+def test_the_cells_kernels_compile_for_a_v5e(one_chip, batch, mask):
+    """Mosaic takes the forward and the backward kernel at the cells'
     shapes and the blocks fixed in the module (what interpret mode cannot
-    show: tiling, VMEM). A compile, not a run."""
-    b, s, kv, g, d = 2, 4096, 4, 8, 128
+    show: tiling, VMEM); under the three-part mask the kernel over the
+    clean keys with its bound a row, beside the diagonal. A compile, not a
+    run."""
+    kv, g, d = 4, 8, 128
+    s = 4096 if isinstance(mask, int) else 2 * mask.clean_len
 
     def shaped(*shape, dtype=jnp.float32):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     def loss(q, k, v, doc):
         return jnp.sum(attention.fused_attention(
-            q, k, v, doc, window, jnp.bfloat16, interpret=False
+            q, k, v, doc, mask, jnp.bfloat16, interpret=False
         ).astype(jnp.float32))
 
     text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
-        shaped(b, s, kv, g, d), shaped(b, s, kv, d), shaped(b, s, kv, d),
-        shaped(b, s, dtype=jnp.int32)).compile().as_text()
+        shaped(batch, s, kv, g, d), shaped(batch, s, kv, d),
+        shaped(batch, s, kv, d), shaped(batch, s, dtype=jnp.int32)
+    ).compile().as_text()
     assert "splash_mqa_fwd_segmented_residuals" in text
     assert "splash_mqa_dkv_segmented_no_residuals" in text
+
+
+def _the_parents_path(q, k, v, doc, window, dtype, block):
+    """``fused_attention`` under an ``int`` mask as it stood before the
+    three-part mask had a path of its own (PR 31), written out."""
+    b, s, kv, g, d = q.shape
+    shape = (s, s)
+    one = (splash.LocalMask(shape, (window - 1, 0), 0) if window
+           else splash.CausalMask(shape))
+    with jax.ensure_compile_time_eval():
+        kernel = splash.make_splash_mqa_single_device(
+            splash.MultiHeadMask([one] * g),
+            block_sizes=attention.block_sizes(block, block, block),
+            interpret=True)
+    q = (q * (1.0 / math.sqrt(d))).astype(dtype)
+    per_head = jax.vmap(kernel, in_axes=(0, 0, 0, None))
+    out = jax.vmap(per_head)(
+        jnp.transpose(q, (0, 2, 3, 1, 4)),
+        jnp.transpose(k.astype(dtype), (0, 2, 1, 3)),
+        jnp.transpose(v.astype(dtype), (0, 2, 1, 3)),
+        splash.SegmentIds(doc, doc))
+    return jnp.transpose(out, (0, 3, 1, 2, 4))
+
+
+@WINDOWS
+def test_an_int_mask_traces_the_program_it_traced(window):
+    """The causal mask and the window keep the one kernel call through
+    JAX's own ``custom_vjp``: forward and gradients, the jaxpr is the
+    parent's to the letter."""
+    q, k, v, doc, weight = _inputs()
+
+    def traced(fn):
+        def loss(q, k, v):
+            out = fn(q, k, v)
+            return jnp.sum(out.astype(jnp.float32) * weight), out
+        return str(jax.make_jaxpr(jax.value_and_grad(
+            loss, argnums=(0, 1, 2), has_aux=True))(q, k, v))
+
+    got = traced(lambda q, k, v: attention.fused_attention(
+        q, k, v, doc, window, jnp.bfloat16, interpret=True,
+        blocks=attention.block_sizes(BLOCK, BLOCK, BLOCK)))
+    want = traced(lambda q, k, v: _the_parents_path(
+        q, k, v, doc, window, jnp.bfloat16, BLOCK))
+    assert "splash_mqa_fwd" in want and "splash_mqa_dkv" in want
+    assert got == want
 
 
 # ------------------------------------- the three-part mask (block diffusion)
@@ -281,13 +338,7 @@ def test_both_paths_equal_the_dense_three_part_mask_with_documents(path):
     ok = jnp.asarray(dense_mask(L_CLEAN, BLK))
 
     def run(fn):
-        def loss(q, k, v):
-            out = fn(q, k, v)
-            return jnp.sum(out.astype(jnp.float32) * weight), out
-
-        (_, out), grads = jax.jit(jax.value_and_grad(
-            loss, argnums=(0, 1, 2), has_aux=True))(q, k, v)
-        return np.asarray(out, np.float32), [np.asarray(g) for g in grads]
+        return _run(fn, q, k, v, weight)
 
     with jax.default_matmul_precision("highest"):
         want_out, want_grads = run(
@@ -304,16 +355,112 @@ def test_both_paths_equal_the_dense_three_part_mask_with_documents(path):
         np.testing.assert_allclose(got, want, atol=1e-4 * np.abs(want).max())
 
 
+def _documents(length, starts):
+    """Document ids of the clean text with a document begun at each of
+    ``starts`` (a row a batch entry), for both copies."""
+    begun = np.zeros((len(starts), length), np.int32)
+    for row, at in enumerate(starts):
+        begun[row, [0, *at]] = 1
+    return np.tile(np.cumsum(begun, axis=1), (1, 2))
+
+
+# (clean ids, block of the diffusion, tile, where documents begin): blocks
+# of 4, of 8 and of 3 (no power of two); documents that begin on a block's
+# first position (a noisy row there sees no clean key at all), inside
+# blocks and on a block's last; one document over the whole sequence; and
+# tiles of which some are empty, some partial and some whole (at 256 clean
+# ids and tiles of 128: the noisy rows 0-127 see part of the clean keys
+# 0-127 and none of 128-255, the rows 128-255 all of the first and part of
+# the second).
+TWO_PARTS = pytest.mark.parametrize("length, block, tile, starts", [
+    (256, 4, 128, [[5, 63, 128, 130], [200]]),
+    (256, 4, 128, [[], []]),
+    (256, 8, 128, [[8, 100, 128, 135], [64]]),
+    (384, 3, 128, [[3, 100, 129, 255, 256], [300]]),
+    (512, 4, 256, [[4, 255, 256, 300], []]),
+    (512, 8, 128, [[128, 256, 384], [7, 8, 9]]),
+], ids=["blocks_of_4", "one_document", "blocks_of_8", "blocks_of_3",
+        "tiles_of_256", "documents_on_tile_edges"])
+
+
+@TWO_PARTS
+def test_two_parts_equal_the_scan_output_and_gradients(length, block, tile,
+                                                       starts):
+    """The kernel over the clean keys joined with the noisy diagonal,
+    under its own backward pass, against ``blocked_attention`` with
+    ``BlockDiffusion.allows``: output and all three gradients, every
+    number finite (a noisy row in a document's first block sees no clean
+    key: the join's weight removes what the kernel says of it)."""
+    mask = attention.BlockDiffusion(length, block)
+    keys = jax.random.split(jax.random.PRNGKey(length + block), 4)
+    shape = (len(starts), 2 * length, KV, G, D)
+    q, weight = (jax.random.normal(key, shape, jnp.float32)
+                 for key in keys[:2])
+    k, v = (jax.random.normal(key, shape[:3] + (D,), jnp.float32)
+            for key in keys[2:])
+    doc = jnp.asarray(_documents(length, starts))
+
+    def run(fn):
+        return _run(fn, q, k, v, weight)
+
+    with jax.default_matmul_precision("highest"):
+        want_out, want_grads = run(
+            lambda q, k, v: transformer.blocked_attention(
+                q, k, v, doc, mask, tile, jnp.float32))
+        out, grads = run(lambda q, k, v: attention.fused_attention(
+            q, k, v, doc, mask, jnp.float32, interpret=True,
+            blocks=attention.block_sizes(tile, tile, tile)))
+    assert all(np.isfinite(a).all() for a in (out, *grads))
+    np.testing.assert_allclose(out, want_out, atol=2e-5)
+    for got, want in zip(grads, want_grads):
+        np.testing.assert_allclose(got, want, atol=1e-4 * np.abs(want).max())
+
+
+@TWO_PARTS
+def test_the_bound_mask_is_allows_on_the_clean_columns(length, block, tile,
+                                                       starts):
+    """The kernel's mask, one comparison with a bound a row, in its dense
+    form: ``allows`` on the clean keys; the rest of ``allows`` is the noisy
+    diagonal, ``block`` entries a noisy row; and the kernel's own table
+    visits the tiles the dense form leaves non-empty."""
+    mask = attention.BlockDiffusion(length, block)
+    rows = np.arange(2 * length)[:, None]
+    want = mask.allows(rows, np.arange(2 * length)[None, :])
+    bound = attention._CleanKeysMask(mask)
+    assert bound.shape == (2 * length, length)
+    dense = bound[:, :]
+    np.testing.assert_array_equal(dense, want[:, length:])
+    np.testing.assert_array_equal(
+        dense, np.arange(length)[None, :] < attention.clean_bounds(mask)[
+            :, None])
+    assert not want[length:, :length].any()
+    assert (want[:length, :length].sum(axis=1) == block).all()
+    assert attention.diagonal_rows(mask) == length
+    table = np.asarray(attention._kernel(
+        2 * length, mask, G, attention.block_sizes(tile, tile, tile), True
+    ).fwd_mask_info.block_mask)[0]
+    tiles = dense.reshape(2 * length // tile, tile, length // tile, tile)
+    assert np.count_nonzero(table) == int(tiles.any(axis=(1, 3)).sum())
+    assert 0 < np.count_nonzero(table) < tiles.shape[0] * tiles.shape[2]
+    assert tiles.all(axis=(1, 3)).any()       # empty, partial and whole
+
+
 def test_key_blocks_of_the_three_part_mask_at_the_cells_shapes():
-    """At 4,096 clean ids and tiles of 1,024 the kernel visits 24 of 64
-    tiles: 10 block causal, 10 offset block causal, 4 on the noisy
-    diagonal (ISSUE 34), which is the dense mask's count."""
+    """At 4,096 clean ids and tiles of 1,024 the kernel steps through 20
+    of the 32 tiles of every query by the clean keys (10 for the noisy
+    rows, 10 for the clean), which is the dense mask's count there; the 4
+    tiles of the noisy diagonal that the whole square's 24 of 64 held
+    (PR 34) are ``diagonal_rows`` outside the kernel."""
     length = 4096
     mask = attention.BlockDiffusion(length, 4)
-    assert attention.key_blocks(2 * length, mask, 8) == (24, 64)
+    assert attention.key_blocks(2 * length, mask, 8) == (20, 32)
+    assert attention.diagonal_rows(mask) == length
+    assert attention.diagonal_rows(2048) == attention.diagonal_rows(0) == 0
     bq = attention.BLOCKS.block_q
     tiles = dense_mask(length, 4).reshape(
         2 * length // bq, bq, 2 * length // bq, bq).any(axis=(1, 3))
     assert int(tiles.sum()) == 24
+    assert int(tiles[:, length // bq:].sum()) == 20
+    assert int(np.trace(tiles[:length // bq, :length // bq])) == 4
     with pytest.raises(ValueError, match="positions, not 4096"):
         attention.key_blocks(length, mask, 8)

@@ -17,7 +17,8 @@ from benchmarks.reference import sdar_moe as ref
 from tpu_resnet import models
 from tpu_resnet.config import load_config
 from tpu_resnet.data.tokens import write_tokens
-from tpu_resnet.models import build_model, sample_input, sdar_moe
+from tpu_resnet.models import (build_model, sample_input, sdar_moe,
+                               transformer)
 from tpu_resnet.models.sdar_moe import Arch, SdarMoe
 from tpu_resnet.programs import spell
 from tpu_resnet.train import schedule as sched_lib
@@ -236,6 +237,41 @@ def test_the_clean_copy_is_unmoved_by_the_noisy_ids():
     np.testing.assert_allclose(c[:, 8:12], a[:, 8:12], atol=1e-6)
 
 
+def test_the_model_through_the_two_parts_equals_the_model_through_the_scan(
+        monkeypatch):
+    """Steered in the test, as one chip would choose: the model's loss on
+    the masked positions and its gradients with the attention as
+    the kernel over the clean keys and the diagonal beside it (interpret
+    mode, the module's own tiles of 1,024), against the scan the CPU
+    takes."""
+    arch = dataclasses.replace(ARCH, layers=1, heads=2, kv_heads=1,
+                               head_dim=128)
+    length = 1024
+    rng = np.random.default_rng(2)
+    x0 = rng.integers(1, 127, (1, length))
+    x0[0, [0, 4, 77, 512, 900]] = 0     # documents on and off a block's edge
+    x0 = jnp.asarray(x0, jnp.int32)
+    xt, masked, _ = sdar_moe.noise(step_key(3), x0, 4, 0.001, 127)
+    fed = jnp.concatenate([xt, x0], axis=1)
+    model = SdarMoe(arch)
+    params = weights(arch)
+
+    def loss(params):
+        logits = model.apply({"params": params}, fed)
+        assert logits.shape == (1, length, 128)
+        picked = jnp.take_along_axis(logits, x0[..., None], -1)[..., 0]
+        return jnp.sum((jax.nn.logsumexp(logits, -1) - picked) * masked
+                       ) / length
+
+    want = jax.value_and_grad(loss)(params)
+    monkeypatch.setattr(transformer, "attention_path", lambda *_: "kernel")
+    got = jax.value_and_grad(loss)(params)
+    np.testing.assert_allclose(got[0], want[0], rtol=1e-6)
+    for a, b in zip(jax.tree_util.tree_leaves(got[1]),
+                    jax.tree_util.tree_leaves(want[1])):
+        np.testing.assert_allclose(a, b, atol=2e-5 * np.abs(b).max())
+
+
 # --------------------------------------------------------------- the family
 def test_preset_states_the_published_widths_and_spells_its_program():
     cfg = load_config("sdar_30b_a3b_chat")
@@ -257,6 +293,36 @@ def test_preset_states_the_published_widths_and_spells_its_program():
     # and values, 0.159 T for the head: 1.49 T multiply-adds, x 6
     assert fam.train_flops_per_example(cfg) == pytest.approx(8.95e12,
                                                              rel=2e-3)
+
+
+@pytest.mark.parametrize("backend, devices, said", [
+    ("tpu", 1, dict(path="kernel", key_blocks_visited=20,
+                    key_blocks_total=32, diagonal_rows=4096)),
+    ("tpu", 4, dict(path="scan", key_blocks_visited=32 * 32,
+                    key_blocks_total=32 * 32, diagonal_rows=0)),
+    ("cpu", 8, dict(path="scan", key_blocks_visited=32 * 32,
+                    key_blocks_total=32 * 32, diagonal_rows=0)),
+], ids=["one_chip", "four_chips", "cpu"])
+def test_attention_paths_of_the_preset(backend, devices, said):
+    """On one chip the kernel steps through 20 of the 32 tiles of every
+    query by the clean keys and leaves the 4,096 noisy rows' own blocks to
+    the product beside it; the scan takes every block of 256 queries
+    against all 8,192 keys."""
+    arch = build_model(load_config("sdar_30b_a3b_chat")).arch
+    rows = sdar_moe.attention_paths(arch, 4096, backend, devices)
+    assert rows == [dict(layer=i, kind="block_diffusion", **said)
+                    for i in range(4)]
+
+
+def test_half_a_tile_of_clean_ids_takes_the_scan():
+    """The kernel's tiles of 1,024 have to divide the clean copy, whose
+    keys are all the kernel is given: 512 clean ids fed as 1,024 positions
+    take the scan."""
+    arch = build_model(load_config("sdar_30b_a3b_chat")).arch
+    assert sdar_moe.attention_paths(arch, 512, "tpu", 1)[0]["path"] == "scan"
+    assert sdar_moe.attention_paths(arch, 1024, "tpu", 1)[0] == dict(
+        layer=0, kind="block_diffusion", path="kernel",
+        key_blocks_visited=2, key_blocks_total=2, diagonal_rows=1024)
 
 
 @pytest.mark.parametrize("overrides,words", [
@@ -306,4 +372,4 @@ def test_tiny_preset_trains_through_train_and_says_its_path(tmp_path):
     assert len(said) == 1 and len(said[0]["layers"]) == 2
     assert said[0]["layers"][0] == dict(
         layer=0, kind="block_diffusion", path="scan",
-        key_blocks_visited=1, key_blocks_total=1)
+        key_blocks_visited=1, key_blocks_total=1, diagonal_rows=0)

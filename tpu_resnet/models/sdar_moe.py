@@ -63,7 +63,7 @@ from tpu_resnet.models.transformer import (COUNTERS,  # noqa: F401
                                            dispatch_experts, rotary,
                                            sow_counters)
 from tpu_resnet.ops.attention import (BlockDiffusion, attention_path,
-                                      key_blocks)
+                                      diagonal_rows, key_blocks)
 
 def _embed_init(key, shape, dtype=_f32):
     """The embedding, drawn at the scale a trained one has beside the
@@ -298,18 +298,22 @@ def attention_paths(model: Arch, seq_len: int, backend: str,
     """For each layer, the ``path`` its attention takes over the ``2 x
     seq_len`` positions on ``devices`` of ``backend`` and, in tiles of
     queries by keys, ``key_blocks_visited`` of ``key_blocks_total``: the
-    kernel's from its own mask table, the scan's every block against all
-    the keys."""
+    kernel's from its own mask table, which is over the clean keys alone,
+    with the ``diagonal_rows`` it leaves to the product of a block by a
+    block beside it (``ops/attention.py``); the scan's every block against
+    all the keys, and no such rows."""
     fed = 2 * seq_len
-    path = attention_path(backend, devices, model.head_dim, fed)
+    mask = BlockDiffusion(seq_len, model.block_length)
+    path = attention_path(backend, devices, model.head_dim, seq_len)
     if path == "kernel":
-        visited, total = key_blocks(
-            fed, BlockDiffusion(seq_len, model.block_length),
-            model.heads // model.kv_heads)
+        visited, total = key_blocks(fed, mask, model.heads // model.kv_heads)
+        beside = diagonal_rows(mask)
     else:
         visited = total = (fed // min(model.attn_block, fed)) ** 2
+        beside = 0
     return [dict(layer=i, kind="block_diffusion", path=path,
-                 key_blocks_visited=visited, key_blocks_total=total)
+                 key_blocks_visited=visited, key_blocks_total=total,
+                 diagonal_rows=beside)
             for i in range(model.layers)]
 
 

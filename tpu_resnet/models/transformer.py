@@ -175,8 +175,11 @@ def attend(q, k, v, doc, mask, block: int, dtype):
     b, s, h, hd = q.shape
     kv = k.shape[2]
     q = q.reshape(b, s, kv, h // kv, hd)
+    # the kernel's blocks have to divide the keys it is given, which under
+    # the three-part mask are the clean copy's alone
+    keys = mask.clean_len if isinstance(mask, BlockDiffusion) else s
     if attention_path(jax.default_backend(), jax.device_count(), hd,
-                      s) == "kernel":
+                      keys) == "kernel":
         out = fused_attention(q, k, v, doc, mask, dtype)
     else:
         out = blocked_attention(q, k, v, doc, mask, block, dtype)
