@@ -398,6 +398,42 @@ def test_the_tiny_program_gives_the_parents_numbers_to_the_digit():
     assert total == pytest.approx(5383.480966567993, rel=1e-9)
 
 
+# The parent's ``expert_bias`` after the same three steps (commit 5608166,
+# before the sigmoid router and its bias update moved to
+# models/transformer.py, where ``lfm2_moe`` calls them too).
+PARENTS_BIAS = {
+    "layer_1/moe/expert_bias": (
+        [0, 1, 2, 3, 4, 6, 8, 9, 12, 14, 15], 0.0018749998416751623,
+        -0.004124999977648258),
+    "layer_4/moe/expert_bias": (
+        [0, 2, 3, 4, 5, 6, 8, 12, 13, 15], 0.0022499999031424522,
+        -0.003750000149011612),
+}
+
+
+def test_the_moved_router_gives_the_parents_bias_to_the_digit():
+    cfg = load_config("trinity_mini_ep16", overrides=TINY)
+    model = build_model(cfg)
+    schedule = sched_lib.build_schedule(cfg.optim, cfg.train)
+    state = init_state(model, cfg.optim, schedule, jax.random.PRNGKey(3),
+                       sample_input(cfg))
+    step = jax.jit(make_train_step(model, cfg.optim, schedule,
+                                   cfg.data.num_classes))
+    for seed in range(3):
+        state, _ = step(state, *tokens(seed, batch=8))
+    bias = flat(state.batch_stats)
+    assert sorted(bias) == [f"layer_{i}/moe/expert_bias" for i in (1, 2, 3,
+                                                                    4)]
+    for key, (up, high, low) in PARENTS_BIAS.items():
+        want = [high if i in up else low for i in range(16)]
+        assert [float(b) for b in bias[key]] == want, key
+    assert sum(float(np.sum(np.abs(b.astype(np.float64))))
+               for b in bias.values()) == 0.1557499974151142
+    # one router, one bias rule in the tree: the family calls the shared
+    assert afmoe.sigmoid_router is transformer.sigmoid_router
+    assert afmoe.balanced_bias is transformer.balanced_bias
+
+
 # --------------------------------------------------------- data and config
 def test_token_file_is_cut_into_consecutive_sequences(tmp_path):
     ids = np.arange(3 * 5 + 2) % 7

@@ -119,7 +119,7 @@ def test_a_family_is_fed_by_its_kind_of_data_only(toy, preset, overrides,
         check_step_config(cfg, 1)
     said = str(err.value)
     assert repr(model) in said and repr(dataset) in said
-    assert "feeds model 'afmoe', 'sdar_moe', 'toy_tokens'" in said
+    assert "feeds model 'afmoe', 'lfm2_moe', 'sdar_moe', 'toy_tokens'" in said
     assert "feeds model 'mlp', 'resnet'" in said
 
 
@@ -316,7 +316,7 @@ def test_register_refuses_an_unknown_kind_of_input():
 def test_unknown_model_and_unknown_module_are_said():
     cfg = load_config("smoke", overrides=["model.name=resnext"])
     with pytest.raises(ValueError, match="unknown model 'resnext'.*'afmoe', "
-                                         "'mlp', 'resnet'"):
+                                         "'lfm2_moe', 'mlp', 'resnet'"):
         build_model(cfg)
     with pytest.raises(ValueError, match="no registered model family "
                                          "builds a ToyTokens"):

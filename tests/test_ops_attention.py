@@ -122,7 +122,10 @@ def test_the_scans_blocks_are_its_uniform_span():
     ("gpu", 1, 128, 4096, "scan"),
     ("tpu", 4, 128, 4096, "scan"),        # one jit over four chips
     ("tpu", 1, 16, 32, "scan"),           # the tiny preset
-    ("tpu", 1, 64, 4096, "scan"),         # heads under the 128 lanes
+    ("tpu", 1, 64, 4096, "kernel"),       # heads of half the lanes
+    ("tpu", 4, 64, 4096, "scan"),
+    ("tpu", 1, 32, 4096, "scan"),         # a quarter: never run on the chip
+    ("tpu", 1, 192, 4096, "scan"),
     ("tpu", 1, 128, 4000, "scan"),        # a length the blocks do not divide
     ("tpu", 1, 128, 256, "scan"),
 ])
@@ -227,16 +230,18 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-@pytest.mark.parametrize("batch, mask", [
-    (2, 2048), (2, 0), (1, attention.BlockDiffusion(4096, 4))],
-    ids=["sliding", "full", "block_diffusion"])
-def test_the_cells_kernels_compile_for_a_v5e(one_chip, batch, mask):
+@pytest.mark.parametrize("batch, mask, heads", [
+    (2, 2048, (4, 8, 128)), (2, 0, (4, 8, 128)),
+    (1, attention.BlockDiffusion(4096, 4), (4, 8, 128)),
+    (2, 0, (8, 4, 64))],
+    ids=["sliding", "full", "block_diffusion", "full_heads_of_64"])
+def test_the_cells_kernels_compile_for_a_v5e(one_chip, batch, mask, heads):
     """Mosaic takes the forward and the backward kernel at the cells'
     shapes and the blocks fixed in the module (what interpret mode cannot
     show: tiling, VMEM); under the three-part mask the kernel over the
-    clean keys with its bound a row, beside the diagonal. A compile, not a
-    run."""
-    kv, g, d = 4, 8, 128
+    clean keys with its bound a row, beside the diagonal; at heads of 64
+    the kernel as it is, nothing padded. A compile, not a run."""
+    kv, g, d = heads
     s = 4096 if isinstance(mask, int) else 2 * mask.clean_len
 
     def shaped(*shape, dtype=jnp.float32):
@@ -298,6 +303,38 @@ def test_an_int_mask_traces_the_program_it_traced(window):
         q, k, v, doc, window, jnp.bfloat16, BLOCK))
     assert "splash_mqa_fwd" in want and "splash_mqa_dkv" in want
     assert got == want
+
+
+# ------------------------------------------------------------ heads of 64
+@pytest.mark.parametrize("path", ["kernel", "scan"])
+def test_heads_of_64_equal_dense_attention_with_documents(path):
+    """At a head of 64 (``lfm2_moe``'s) the kernel as it is, in interpret
+    mode, and the scan both give dense attention's numbers within
+    documents, forward and in the three gradients."""
+    keys = jax.random.split(jax.random.PRNGKey(7), 4)
+    kv, g, d = 4, 2, 64
+    q = jax.random.normal(keys[0], (B, S, kv, g, d), jnp.float32)
+    k, v = (jax.random.normal(key, (B, S, kv, d), jnp.float32)
+            for key in keys[1:3])
+    weight = jax.random.normal(keys[3], (B, S, kv, g, d), jnp.float32)
+    doc = _inputs()[3]
+    pos = np.arange(S)
+    causal = jnp.asarray(pos[None, :] <= pos[:, None])
+    with jax.default_matmul_precision("highest"):
+        want_out, want_grads = _run(
+            lambda q, k, v: _dense_attention(q, k, v, doc, causal),
+            q, k, v, weight)
+        if path == "kernel":
+            out, grads = _run(lambda q, k, v: attention.fused_attention(
+                q, k, v, doc, 0, jnp.float32, interpret=True,
+                blocks=attention.block_sizes(BLOCK, BLOCK, BLOCK)),
+                q, k, v, weight)
+        else:
+            out, grads = _run(lambda q, k, v: transformer.blocked_attention(
+                q, k, v, doc, 0, BLOCK, jnp.float32), q, k, v, weight)
+    np.testing.assert_allclose(out, want_out, atol=2e-5)
+    for got, want in zip(grads, want_grads):
+        np.testing.assert_allclose(got, want, atol=1e-4 * np.abs(want).max())
 
 
 # ------------------------------------- the three-part mask (block diffusion)

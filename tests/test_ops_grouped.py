@@ -266,6 +266,10 @@ def test_an_empty_tier_runs_no_product():
     # experts 768 wide: no tile of 1,024 a quarter of which is padding
     (2048, 768, (256, 1024, 768), (256, 768, 1024), (256, 1024, 768)),
     (768, 2048, (256, 768, 1024), (256, 1024, 768), (256, 768, 1024)),
+    # experts 1,536 wide: one tile of half again the tiling's, where two of
+    # 1,024 would be a quarter padding and a masked remainder
+    (2048, 1536, (256, 1024, 1536), (256, 1536, 1024), (256, 1024, 1536)),
+    (1536, 2048, (256, 1536, 1024), (256, 1024, 1536), (256, 1536, 1024)),
 ])
 def test_tiles_take_a_smaller_contraction_or_width_whole(k, n, forward,
                                                          d_lhs, d_rhs):
@@ -274,3 +278,51 @@ def test_tiles_take_a_smaller_contraction_or_width_whole(k, n, forward,
     ``lhs`` contracts over ``n`` and writes ``k`` columns."""
     assert grouped._fit(grouped.TILING, k, n) == forward == d_rhs
     assert grouped._fit(grouped.TILING, n, k) == d_lhs
+
+
+@pytest.mark.parametrize("size, tile", [
+    (1024, 1024), (2048, 1024), (768, 768), (1536, 1536), (2560, 1280),
+    (3072, 1024), (11776, 512), (1100, 1024), (64, 64)])
+def test_a_tile_divides_the_size_it_is_cut_to(size, tile):
+    """``_fit_one``: whole under the tile, the tile where it divides, else
+    the largest multiple of 128 that divides and is no more than half
+    again the tile; where nothing divides (1,100), the tile, masked."""
+    assert grouped._fit_one(1024, size) == tile
+
+
+@pytest.mark.parametrize("k, n", [(128, 1536), (1536, 128)],
+                         ids=["columns", "contraction"])
+def test_experts_1536_wide_equal_a_loop_over_the_groups(k, n):
+    """The kernel (interpret mode) at the width of ``lfm2_moe``'s experts,
+    as columns and as contraction, under the module's own tiling: the
+    result and both gradients against a loop, NaN in every row no group
+    owns."""
+    sizes, rows = [20, 0, 30], 64
+    rng = np.random.default_rng(1)
+    lhs = rng.normal(size=(rows, k)).astype(np.float32)
+    lhs[sum(sizes):] = np.nan
+    rhs = rng.normal(size=(3, k, n)).astype(np.float32)
+    weight = rng.normal(size=(rows, n)).astype(np.float32)
+    assert grouped._fit(grouped.TILING, k, n)[1:] == (k, n)
+
+    def loss(lhs, rhs):
+        out = grouped.grouped_dot(
+            lhs, rhs, jnp.asarray(sizes, jnp.int32), jnp.float32, "kernel",
+            tiling=(32,) + grouped.TILING[1:], interpret=INTERPRET)
+        return jnp.sum(out * weight), out
+
+    with jax.default_matmul_precision("highest"):
+        (_, out), (d_lhs, d_rhs) = jax.jit(jax.value_and_grad(
+            loss, argnums=(0, 1), has_aux=True))(jnp.asarray(lhs),
+                                                 jnp.asarray(rhs))
+    lo = 0
+    for g, size in enumerate(sizes):
+        at = slice(lo, lo + size)
+        np.testing.assert_allclose(out[at], lhs[at] @ rhs[g], rtol=1e-4,
+                                   atol=1e-3)
+        np.testing.assert_allclose(d_lhs[at], weight[at] @ rhs[g].T,
+                                   rtol=1e-4, atol=1e-3)
+        np.testing.assert_allclose(d_rhs[g], lhs[at].T @ weight[at],
+                                   rtol=1e-4, atol=1e-3)
+        lo += size
+    assert not np.asarray(out[lo:]).any() and not np.asarray(d_lhs[lo:]).any()

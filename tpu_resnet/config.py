@@ -164,7 +164,7 @@ class ModelConfig:
     """
 
     # resnet | mlp | afmoe (its fields: AfmoeConfig) | sdar_moe
-    # (SdarMoeConfig)
+    # (SdarMoeConfig) | lfm2_moe (Lfm2MoeConfig)
     name: str = "resnet"
     resnet_size: int = 50
     width_multiplier: int = 1
@@ -267,6 +267,36 @@ class SdarMoeConfig:
     rms_eps: float = 1e-6
     block_length: int = 4      # positions a block of the diffusion
     t_min: float = 1e-3        # a block's noise level is U(t_min, 1]
+
+
+@dataclasses.dataclass
+class Lfm2MoeConfig:
+    """The fields of ``model.name=lfm2_moe`` (the family's module has the
+    equations, at ``Arch``): a decoder whose layers mix tokens by a gated
+    short convolution or by full attention, with a dense MLP or routed
+    experts behind either, as one chip of an expert-parallel group holds
+    it. The defaults are the published widths of the preset's source; the
+    rows of the tied vocabulary held here are ``data.vocab_size``."""
+
+    # each layer's kind, in order: dense_conv | dense_full | moe_conv |
+    # moe_full
+    layers: tuple = ("dense_conv", "moe_full", "moe_conv", "moe_conv",
+                     "moe_conv")
+    hidden: int = 2048
+    heads: int = 32
+    kv_heads: int = 8
+    head_dim: int = 64
+    conv_taps: int = 3         # conv_L_cache: positions a filter reaches
+    dense_width: int = 11776
+    expert_width: int = 1536
+    experts_total: int = 64    # the router's width
+    experts_first: int = 0     # the routed experts this chip holds:
+    experts_held: int = 8      # experts_first .. experts_first + held
+    top_k: int = 4
+    rope_theta: float = 1e6
+    rms_eps: float = 1e-5
+    route_scale: float = 1.0
+    balance_coeff: float = 0.001
 
 
 @dataclasses.dataclass
@@ -781,6 +811,8 @@ class RunConfig:
     afmoe: AfmoeConfig = dataclasses.field(default_factory=AfmoeConfig)
     sdar_moe: SdarMoeConfig = dataclasses.field(
         default_factory=SdarMoeConfig)
+    lfm2_moe: Lfm2MoeConfig = dataclasses.field(
+        default_factory=Lfm2MoeConfig)
     optim: OptimConfig = dataclasses.field(default_factory=OptimConfig)
     mesh: MeshConfig = dataclasses.field(default_factory=MeshConfig)
     train: TrainConfig = dataclasses.field(default_factory=TrainConfig)
@@ -949,6 +981,18 @@ def _sdar_30b_a3b_chat() -> RunConfig:
     return cfg
 
 
+def _lfm2_24b_a2b_ep8() -> RunConfig:
+    """LFM2-24B-A2B (LiquidAI, ``lfm2_moe``) as one of eight chips that
+    share each layer: 8 of the 64 routed experts and an eighth of the tied
+    vocabulary held here, published layers 1-5 (a conv layer with the
+    dense MLP, then one period of attention, conv, conv, conv over routed
+    experts); next-token cross-entropy, AdamW as the other token presets."""
+    cfg = _trinity_mini_ep16()
+    cfg.data.vocab_size = 8_192
+    cfg.model.name = "lfm2_moe"
+    return cfg
+
+
 # The supported config space (these presets × mesh/dtype/fused/remat/
 # engine variations) is certified statically: tpu_resnet/analysis/
 # configmatrix.py traces the compiled train/eval program of every
@@ -964,6 +1008,7 @@ PRESETS = {
     "smoke": _smoke,
     "trinity_mini_ep16": _trinity_mini_ep16,
     "sdar_30b_a3b_chat": _sdar_30b_a3b_chat,
+    "lfm2_24b_a2b_ep8": _lfm2_24b_a2b_ep8,
 }
 
 

@@ -21,8 +21,10 @@ from typing import Callable, Dict, Optional, Tuple
 
 import jax.numpy as jnp
 
-from tpu_resnet.models import afmoe, mlp, resnet, sdar_moe
+from tpu_resnet.models import (afmoe, lfm2_moe, mlp, resnet, sdar_moe,
+                               transformer)
 from tpu_resnet.models.afmoe import Afmoe
+from tpu_resnet.models.lfm2_moe import Lfm2Moe
 from tpu_resnet.models.mlp import MLP
 from tpu_resnet.models.sdar_moe import SdarMoe
 from tpu_resnet.models.resnet import (
@@ -33,6 +35,7 @@ from tpu_resnet.models.resnet import (
 
 __all__ = [
     "Afmoe",
+    "Lfm2Moe",
     "MLP",
     "ResNetV2",
     "SdarMoe",
@@ -135,9 +138,9 @@ def build_model(cfg):
 def sample_input(cfg):
     """What a fresh state's weights are drawn on: one image of the data
     set's size or, for a token model, one short sequence of ids (no
-    leaf's shape depends on the length, in either token family: of
-    ``sdar_moe`` the eight ids are a noised and a clean copy of one block
-    of four)."""
+    leaf's shape depends on the length, in any token family: a conv
+    layer's filter of ``lfm2_moe`` is ``(hidden, taps)``; of ``sdar_moe``
+    the eight ids are a noised and a clean copy of one block of four)."""
     if family(cfg).inputs == "tokens":
         return jnp.zeros((1, 8), jnp.int32)
     size = cfg.data.resolved_image_size
@@ -146,15 +149,17 @@ def sample_input(cfg):
 
 def require_image_model(cfg, what: str) -> None:
     """Evaluation, serving and export carry image classifiers only: a
-    token model has no evaluation split, no cache for its attention and
-    no serving path (ROADMAP B-I)."""
+    token model has no evaluation split, no cache for its attention or
+    for a conv layer's last positions, and no serving path (ROADMAP
+    B-I)."""
     if family(cfg).inputs != "images" or data_kind(cfg) != "images":
         raise NotImplementedError(
             f"{what} is not supported for a token model "
             f"(model.name={cfg.model.name!r}, data.dataset="
             f"{cfg.data.dataset!r}): only `train` carries it; evaluation "
             f"needs a held-out token split and serving a cache for "
-            f"attention, and neither exists yet")
+            f"attention's keys and values and for a conv layer's last "
+            f"positions, and neither exists yet")
 
 
 register(Family("resnet", "images", ResNetV2, resnet.build, resnet.spell,
@@ -171,3 +176,8 @@ register(Family("sdar_moe", "tokens", SdarMoe, sdar_moe.build,
                 counters=sdar_moe.COUNTERS, objective=sdar_moe.objective,
                 refuses=sdar_moe.refuses,
                 startup_events=sdar_moe.startup_events))
+register(Family("lfm2_moe", "tokens", Lfm2Moe, lfm2_moe.build,
+                lfm2_moe.spell,
+                train_flops_per_example=lfm2_moe.train_flops_per_example,
+                counters=lfm2_moe.COUNTERS, refuses=transformer.refuses,
+                startup_events=lfm2_moe.startup_events))

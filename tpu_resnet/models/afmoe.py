@@ -17,24 +17,27 @@ The equations (``d`` hidden size; RMSNorm with a weight everywhere):
   same document and, on sliding layers, ``i - j < window``;
   ``out = (softmax V) * sigmoid(g)``; ``Attn = out Wo``. A document begins
   at every id 0: ``doc = cumsum(ids == 0)``.
-- expert layer: ``s = sigmoid(x Wr)`` in float32 over ALL
-  ``experts_total``; chosen = top-k of ``s + b`` (``b`` is the layer's
-  ``expert_bias``, state without a gradient); ``w = s[chosen] / (sum
-  s[chosen] + 1e-20) * route_scale``; ``F(x) = Shared(x) + sum w_e
-  Expert_e(x)`` over the chosen experts THIS CHIP HOLDS
+- expert layer: ``models/transformer.py``'s sigmoid router (``s =
+  sigmoid(x Wr)`` in float32 over ALL ``experts_total``; chosen = top-k of
+  ``s + b``, ``b`` the layer's ``expert_bias``, state without a gradient;
+  ``w = s[chosen] / (sum s[chosen] + eps) * scale``) with this family's
+  two numbers, ``eps`` 1e-20 and ``route_scale`` 2.826; ``F(x) = Shared(x)
+  + sum w_e Expert_e(x)`` over the chosen experts THIS CHIP HOLDS
   (``experts_held = (first, count)``). What the absent experts would add
   is left out and that partial result goes on. In training, after the
   step's routing, ``b += c - mean(c)`` with ``c = load_balance_coeff *
-  sign(mean(n) - n)``, ``n`` the assignments per expert.
+  sign(mean(n) - n)``, ``n`` the assignments per expert (the same module's
+  ``balanced_bias``).
 
-The dispatch of the router's choices to the held experts (one sorted
+The sigmoid router and its bias update (shared with ``lfm2_moe`` since PR
+36), the dispatch of the router's choices to the held experts (one sorted
 buffer, grouped products, overflow tiers, the five ``moe_*`` counters),
 attention by path (one fused kernel on a TPU chip, a scan over blocks of
-queries everywhere else), RMSNorm, the rotary embedding and the products'
-numerics are ``models/transformer.py``'s, shared with the other token
-family; this module keeps what is Trinity's own: the sigmoid router with
-its ``expert_bias``, the shared expert, the attention gate, four norms a
-layer, windows. Parameters, the residual stream, norms, router, softmax
+queries everywhere else), RMSNorm, SwiGLU, the rotary embedding and the
+products' numerics are ``models/transformer.py``'s, shared with the other
+token families; this module keeps what is Trinity's own: the shared
+expert beside the routed ones, the attention gate, four norms a layer,
+windows, the embedding's ``sqrt(d)``. Parameters, the residual stream, norms, router, softmax
 and logits are float32; matrix products take ``dtype`` operands (bf16),
 accumulate in float32 and hand on ``dtype``.
 """
@@ -50,8 +53,9 @@ import jax
 import jax.numpy as jnp
 
 from tpu_resnet.models.transformer import (  # noqa: F401  (re-exported)
-    COUNTERS, RMSNorm, _dot, _f32, _init, _KEEP, _reach, attend,
-    blocked_attention, dispatch_experts, refuses, rotary, sow_counters)
+    COUNTERS, RMSNorm, SwiGLU, _dot, _f32, _init, _KEEP, _reach, attend,
+    balanced_bias, blocked_attention, dispatch_experts, refuses, rotary,
+    sigmoid_router, sow_counters)
 from tpu_resnet.ops.attention import attention_path, key_blocks
 
 # layer kinds: what F is, and which mask attention takes
@@ -93,22 +97,6 @@ class Attention(nn.Module):
                         self.dtype)
 
 
-class SwiGLU(nn.Module):
-    width: int
-    dtype: Any
-
-    @nn.compact
-    def __call__(self, x):
-        d = x.shape[-1]
-        gate = _dot(x, self.param("gate", _init, (d, self.width), _f32),
-                    self.dtype)
-        up = _dot(x, self.param("up", _init, (d, self.width), _f32),
-                  self.dtype)
-        return _dot(jax.nn.silu(gate) * up,
-                    self.param("down", _init, (self.width, d), _f32),
-                    self.dtype)
-
-
 class ExpertLayer(nn.Module):
     """The routed experts this chip holds, and the shared one."""
     width: int
@@ -135,14 +123,9 @@ class ExpertLayer(nn.Module):
         w_down = self.param("down", _init, (count, self.width, d), _f32)
 
         with jax.named_scope("router"):
-            scores = jax.nn.sigmoid(jnp.dot(
+            chosen, weight = sigmoid_router(           # (N, k) float32
                 x, self.param("router", _init, (d, total), _f32),
-                precision=jax.lax.Precision.HIGHEST))
-            _, chosen = jax.lax.top_k(
-                scores + jax.lax.stop_gradient(bias.value), k)
-            s = jnp.take_along_axis(scores, chosen, axis=-1)
-            weight = s / (jnp.sum(s, -1, keepdims=True) + 1e-20) \
-                * self.route_scale                     # (N, k) float32
+                bias.value, k, eps=1e-20, scale=self.route_scale)
 
         out, counters = dispatch_experts(
             x, chosen, weight, w_gate, w_up, w_down, experts_total=total,
@@ -157,11 +140,8 @@ class ExpertLayer(nn.Module):
         with jax.named_scope("router"):
             sow_counters(self, counters)
             if train and not self.is_initializing():
-                per_expert = jnp.sum(jax.nn.one_hot(
-                    chosen.reshape(-1), total, dtype=_f32), axis=0)
-                c = self.balance_coeff * jnp.sign(
-                    jnp.mean(per_expert) - per_expert)
-                bias.value = bias.value + c - jnp.mean(c)
+                bias.value = balanced_bias(bias.value, chosen,
+                                           self.balance_coeff)
         return out.reshape(shape)
 
 

@@ -1,8 +1,19 @@
-"""What the token families share (``afmoe``, ``sdar_moe``): the numerics of
-a matrix product, RMSNorm, the rotary embedding by position ids, attention
-by whichever path the backend and the shapes give, and the dispatch of an
+"""What the token families share (``afmoe``, ``sdar_moe``, ``lfm2_moe``):
+the numerics of a matrix product, RMSNorm, the SwiGLU MLP, the rotary
+embedding by position ids, attention by whichever path the backend and the
+shapes give, the sigmoid router with its bias, and the dispatch of an
 expert layer's assignments to the experts this chip holds. A family keeps
-what is its own: its router, its norms' places, its masks, its objective.
+what is its own: its token mixers, its norms' places, its masks, its
+objective, a router of another kind.
+
+**The sigmoid router** (``sigmoid_router``, ``balanced_bias``; ``afmoe``
+and ``lfm2_moe`` call it, each with its two numbers): ``s = sigmoid(x
+Wr)`` in float32 over ALL ``experts_total``; chosen = top-k of ``s + b``,
+``b`` the layer's ``expert_bias``, state without a gradient that chooses
+and does not weigh; ``w = s[chosen] / (sum s[chosen] + eps) * scale``. In
+training, after the step's routing, ``b += c - mean(c)`` with ``c = coeff
+* sign(mean(n) - n)``, ``n`` the assignments per expert (the
+auxiliary-loss-free balancing of Wang et al. 2024, arXiv:2408.15664).
 
 **The dispatch** (``dispatch_experts``). A family's router hands over
 ``(x, chosen, weight)``: for each of ``N`` tokens the ``top_k`` experts
@@ -28,8 +39,8 @@ beyond ran, ``moe_rows_filled_frac`` how much of the buffer carried an
 assignment).
 
 **Attention** (``attend``) never builds a ``(B, H, S, S)`` score tensor.
-On one TPU chip, at heads of a multiple of 128 and sequences its blocks
-divide, it is one fused kernel that keeps each tile of scores on the chip
+On one TPU chip, at heads of a multiple of 128, or of 64, and sequences
+its blocks divide, it is one fused kernel that keeps each tile of scores on the chip
 (``ops/attention.py``, which also says how the path is chosen and what a
 mask is); everywhere else a block of queries at a time against the keys
 its mask can reach, as a scan whose body is under ``jax.checkpoint``
@@ -39,7 +50,7 @@ its mask can reach, as a scan whose body is under ``jax.checkpoint``
 from __future__ import annotations
 
 import math
-from typing import Dict, Tuple
+from typing import Any, Dict, Tuple
 
 import flax.linen as nn
 import jax
@@ -83,6 +94,23 @@ class RMSNorm(nn.Module):
         x = x.astype(_f32)
         return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True)
                                  + self.eps) * scale
+
+
+class SwiGLU(nn.Module):
+    """``(silu(x W_gate) * (x W_up)) W_down``, no bias."""
+    width: int
+    dtype: Any
+
+    @nn.compact
+    def __call__(self, x):
+        d = x.shape[-1]
+        gate = _dot(x, self.param("gate", _init, (d, self.width), _f32),
+                    self.dtype)
+        up = _dot(x, self.param("up", _init, (d, self.width), _f32),
+                  self.dtype)
+        return _dot(jax.nn.silu(gate) * up,
+                    self.param("down", _init, (self.width, d), _f32),
+                    self.dtype)
 
 
 def rotary(x, theta: float, positions=None):
@@ -187,6 +215,29 @@ def attend(q, k, v, doc, mask, block: int, dtype):
 
 
 # ----------------------------------------------------------------- experts
+def sigmoid_router(x, w_router, bias, top_k: int, *, eps: float,
+                   scale: float):
+    """``(chosen, weight)``, both ``(N, top_k)``, of ``x`` ``(N, d)``
+    float32 under the router ``w_router`` ``(d, experts_total)`` and the
+    layer's ``bias`` (the module docstring). The product is at
+    ``HIGHEST``: a near-tie turns on its last bits."""
+    scores = jax.nn.sigmoid(jnp.dot(
+        x, w_router, precision=jax.lax.Precision.HIGHEST))
+    _, chosen = jax.lax.top_k(scores + jax.lax.stop_gradient(bias), top_k)
+    s = jnp.take_along_axis(scores, chosen, axis=-1)
+    return chosen, s / (jnp.sum(s, -1, keepdims=True) + eps) * scale
+
+
+def balanced_bias(bias, chosen, coeff: float):
+    """The router's ``bias`` after a step that routed ``chosen``: one
+    ``coeff`` up for an expert under the mean load, one down for one over
+    it, the mean taken off."""
+    per_expert = jnp.sum(jax.nn.one_hot(
+        chosen.reshape(-1), bias.shape[0], dtype=_f32), axis=0)
+    c = coeff * jnp.sign(jnp.mean(per_expert) - per_expert)
+    return bias + c - jnp.mean(c)
+
+
 def buffer_rows(n: int, top_k: int, held: int, total: int, slack: float,
                 tile: int) -> int:
     """The buffer's rows for ``n`` tokens: ``slack`` x what an even

@@ -20,7 +20,12 @@ is the model's contract (``models/transformer.py::blocked_attention``,
 which stays the path of every other backend and shape and the reference of
 the tests), the masks, the block sizes, and the choice between the two
 paths as a pure function of what the code can see (``attention_path``:
-backend, devices, head size, sequence length). There is
+backend, devices, head size, sequence length). Heads of 128 columns and
+heads of 64 both take the kernel as it is: JAX's kernel lays a head under
+the 128 lanes out itself, and on the chip that ran 1.3% faster than the
+same head padded with zero columns to 128 (one layer at 2 x 4,096 x 8 x 4
+x 64: 9.55 against 9.68 ms forward and backward, the scan 53.2; PERF.md
+section 5 at PR 36), so nothing is padded. There is
 no start-up probe here (``ops/autotune.py``): the scan sends every score
 through HBM several times and takes five times the kernel's time at the
 shapes the kernel takes (257 against 46 ms a step of the benchmark's
@@ -169,12 +174,15 @@ def attention_path(backend: str, devices: int, head_dim: int,
                    seq_len: int) -> str:
     """``kernel`` or ``scan``, from the backend's name, the number of its
     devices and the shapes alone: the kernel where Mosaic compiles it (a
-    TPU, heads a multiple of the 128 lanes, a sequence its blocks divide)
-    and the step is one device's program; the scan everywhere else. Over
+    TPU, heads a multiple of the 128 lanes or of 64, half of them, the
+    two sizes the kernel was run at on the chip; a sequence its blocks
+    divide) and the step is one device's program; the scan everywhere
+    else. Over
     several devices the step is one auto-partitioned ``jit``, which refuses
     a Mosaic kernel ("cannot be automatically partitioned"): there the
     kernel waits for a ``shard_map`` over the batch (ROADMAP B-I 4)."""
-    if (backend == "tpu" and devices == 1 and head_dim % 128 == 0
+    if (backend == "tpu" and devices == 1
+            and (head_dim % 128 == 0 or head_dim == 64)
             and seq_len % max(BLOCKS.block_q, BLOCKS.block_kv) == 0):
         return "kernel"
     return "scan"

@@ -51,11 +51,15 @@ import jax
 import jax.numpy as jnp
 from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm, tgmm
 
-# Rows, contraction and columns of a tile, for all three kernels: the most
-# of each. Fixed after a sweep on the chip at the Trinity cell's shapes
-# (PERF.md section 5). A product whose contraction or columns are fewer
-# takes them whole (``_fit``): experts 768 wide would otherwise fill three
-# quarters of a tile of 1,024 and multiply the rest as padding.
+# Rows, contraction and columns of a tile, for all three kernels. Fixed
+# after a sweep on the chip at the Trinity cell's shapes (experts 1,024
+# wide; PERF.md section 5). ``_fit`` cuts it to a product's own sizes, and
+# the benchmark's three expert widths take it so: 1,024 as it stands; 768
+# whole (a tile of 1,024 would be a quarter padding); 1,536 whole too, one
+# tile of half again the tiling's, where two of 1,024 would be a quarter
+# padding and a masked remainder (one layer's three products at 8 groups
+# of 1,536, forward and backward: 2.51 ms so, 2.16 as two tiles of 768,
+# 1.99 whole; PERF.md section 5 at PR 36).
 TILING = (256, 1024, 1024)
 
 
@@ -77,10 +81,23 @@ def _zero_past(out, group_sizes):
     return jnp.where(inside[:, None], out, 0)
 
 
+def _fit_one(tile: int, size: int) -> int:
+    """A tile that divides ``size``: the size whole where the tile covers
+    it, the tile where it divides, and otherwise the largest multiple of
+    the 128 lanes that divides and is no more than half again the tile
+    (1,536 under a tile of 1,024: 1,536; 2,560: 1,280). Where nothing
+    divides, the tile, and the kernel masks the remainder."""
+    if size <= tile or size % tile == 0:
+        return min(tile, size)
+    return max((t for t in range(128, tile + tile // 2 + 1, 128)
+                if size % t == 0), default=tile)
+
+
 def _fit(tiling, contraction: int, columns: int):
-    """``tiling`` with its contraction and columns no more than the
-    product's own."""
-    return (tiling[0], min(tiling[1], contraction), min(tiling[2], columns))
+    """``tiling`` with its contraction and columns cut to the product's
+    own (``_fit_one``)."""
+    return (tiling[0], _fit_one(tiling[1], contraction),
+            _fit_one(tiling[2], columns))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
