@@ -54,8 +54,8 @@ import jax.numpy as jnp
 
 from tpu_resnet.models.transformer import (  # noqa: F401  (re-exported)
     COUNTERS, RMSNorm, SwiGLU, _dot, _f32, _init, _KEEP, _reach, attend,
-    balanced_bias, blocked_attention, dispatch_experts, refuses, rotary,
-    sigmoid_router, sow_counters)
+    balanced_bias, blocked_attention, dispatch_experts, expert_paths,
+    refuses, rotary, sigmoid_router, sow_counters)
 from tpu_resnet.ops.attention import attention_path, key_blocks
 
 # layer kinds: what F is, and which mask attention takes
@@ -336,7 +336,9 @@ def train_flops_per_example(cfg, xla_counted: bool = True) -> float:
 
 def startup_events(model: Afmoe, cfg):
     """Static, so said once: the path each layer's attention takes here
-    and the key blocks its mask leaves (docs/OBSERVABILITY.md)."""
+    and the key blocks its mask leaves, and the expert layers' paths
+    (docs/OBSERVABILITY.md)."""
+    backend, devices = jax.default_backend(), jax.device_count()
     return {"attention_path": {"layers": attention_paths(
-        model.arch, cfg.data.seq_len, jax.default_backend(),
-        jax.device_count())}}
+        model.arch, cfg.data.seq_len, backend, devices)},
+            "expert_path": expert_paths(backend, devices)}

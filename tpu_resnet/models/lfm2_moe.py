@@ -376,10 +376,11 @@ def train_flops_per_example(cfg, xla_counted: bool = True) -> float:
 
 
 def startup_events(model: Lfm2Moe, cfg):
-    """Static, so said once: the mixer of every layer, and the path each
-    attention layer takes here with the key blocks its mask leaves
-    (docs/OBSERVABILITY.md)."""
+    """Static, so said once: the mixer of every layer, the path each
+    attention layer takes here with the key blocks its mask leaves, and
+    the expert layers' paths (docs/OBSERVABILITY.md)."""
+    backend, devices = jax.default_backend(), jax.device_count()
     return {"token_mixers": {"layers": token_mixers(model.arch)},
             "attention_path": {"layers": attention_paths(
-                model.arch, cfg.data.seq_len, jax.default_backend(),
-                jax.device_count())}}
+                model.arch, cfg.data.seq_len, backend, devices)},
+            "expert_path": transformer.expert_paths(backend, devices)}

@@ -358,8 +358,9 @@ def refuses(cfg, data_axis: int):
 
 def startup_events(model: SdarMoe, cfg):
     """Static, so said once: the path each layer's attention takes here
-    and the key blocks the three-part mask leaves
-    (docs/OBSERVABILITY.md)."""
+    and the key blocks the three-part mask leaves, and the expert layers'
+    paths (docs/OBSERVABILITY.md)."""
+    backend, devices = jax.default_backend(), jax.device_count()
     return {"attention_path": {"layers": attention_paths(
-        model.arch, cfg.data.seq_len, jax.default_backend(),
-        jax.device_count())}}
+        model.arch, cfg.data.seq_len, backend, devices)},
+            "expert_path": transformer.expert_paths(backend, devices)}

@@ -23,11 +23,18 @@ top_k`` assignments by their expert here puts those that fall on a held
 expert (``experts_held = (first, count)``) first, grouped by expert and in
 token order within an expert. The first ``rows`` of them (``rows_slack`` x
 what an even routing sends to all held experts together, in whole tiles)
-are one buffer: a gather by the sorted order, three grouped products whose
-work follows the groups' sizes (``ops/grouped.py``, which also says how
-its path is chosen and what its kernel never writes: the buffer's rows
-past the last assignment come back 0, in the result and in every gradient,
-so none of them reaches the weighted scatter-add), and the scatter-add.
+are one buffer: a gather of the tokens by the sorted order, three grouped
+products whose work follows the groups' sizes (``ops/grouped.py``, which
+also says how its path is chosen and what its kernel never writes: the
+buffer's rows past the last assignment come back 0, in the result and in
+every gradient), the weighting, and the sum of each token's rows back into
+its token. The gather and that sum are one transposed pair
+(``ops/rows_to_tokens.py``, which also says how their path is chosen): on
+one TPU chip the sum, forward in the combine and backward in the
+dispatch, is a kernel that reads only the rows an assignment filled, in
+the runs an expert's rows make within a tile of tokens; elsewhere it is
+the scatter-add. ``expert_paths`` names both paths, and each family says
+them once, as the event ``expert_path``.
 Whatever sorted positions lie beyond the buffer go through the same code a
 tier of ``rows`` at a time, only the tiers that hold an assignment, under
 a ``lax.cond`` that is false while the held experts together take no more
@@ -57,6 +64,7 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
+from tpu_resnet.ops import rows_to_tokens
 from tpu_resnet.ops.attention import (BlockDiffusion, attention_path,
                                       fused_attention)
 from tpu_resnet.ops.grouped import grouped_dot, grouped_path, row_tile
@@ -247,6 +255,16 @@ def buffer_rows(n: int, top_k: int, held: int, total: int, slack: float,
     return -(-min(n * top_k, math.ceil(slack * even)) // tile) * tile
 
 
+def expert_paths(backend: str, devices: int) -> Dict[str, str]:
+    """The paths of the expert layer's two kinds of work here: its grouped
+    products (``ops/grouped.py``) and the sum of its buffer's rows into
+    their tokens (``ops/rows_to_tokens.py``). What ``train()`` says once,
+    as the event ``expert_path``, for every family that calls
+    ``dispatch_experts``."""
+    return {"products": grouped_path(backend, devices),
+            "rows_to_tokens": rows_to_tokens.rows_path(backend, devices)}
+
+
 def dispatch_experts(x, chosen, weight, w_gate, w_up, w_down, *,
                      experts_total: int, experts_held: Tuple[int, int],
                      rows_slack: float, dtype
@@ -259,7 +277,8 @@ def dispatch_experts(x, chosen, weight, w_gate, w_up, w_down, *,
     n, d = x.shape
     k = chosen.shape[1]
     first, count = experts_held
-    path = grouped_path(jax.default_backend(), jax.device_count())
+    paths = expert_paths(jax.default_backend(), jax.device_count())
+    path = paths["products"]
     rows = buffer_rows(n, k, count, experts_total, rows_slack,
                        row_tile(path))
     tiers = -(-n * k // rows)
@@ -287,7 +306,10 @@ def dispatch_experts(x, chosen, weight, w_gate, w_up, w_down, *,
             token = at // k
             sizes = (jnp.clip(ends, lo, lo + rows)
                      - jnp.clip(ends - load, lo, lo + rows))
-            xs = jnp.take(xb, token, axis=0)
+            plan = rows_to_tokens.plan(
+                token, n, paths["rows_to_tokens"], expert=expert,
+                ends=ends, load=load, lo=lo, here=here_n)
+            xs = rows_to_tokens.dispatch(xb, plan)
         with jax.named_scope("experts"):
             def mm(a, w, out=dtype):
                 return grouped_dot(a.astype(dtype), w.astype(dtype), sizes,
@@ -298,8 +320,7 @@ def dispatch_experts(x, chosen, weight, w_gate, w_up, w_down, *,
             y = mm(jax.nn.silu(hidden[0]) * hidden[1], w_down, _f32)
         with jax.named_scope("combine"):
             y = y * jnp.take(weight.reshape(-1), at)[:, None]
-            return (jnp.zeros((n, d), _f32).at[token].add(y),
-                    jnp.sum(sizes))
+            return rows_to_tokens.combine(y, plan), jnp.sum(sizes)
 
     operands = (x.astype(dtype), weight, w_gate, w_up, w_down)
     out, computed = tier(0, *operands)

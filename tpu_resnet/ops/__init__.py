@@ -3,12 +3,14 @@ same kernel code runs (slowly) on CPU in tests. The image model's ops
 (epilogue, fused blocks, softmax-xent) are guarded in the hot path by the
 compile-time A/B probe (ops/autotune.py): such a lowering rides only where
 it measured a win over XLA, because there the two stand within a few
-percent of each other. Attention (ops/attention.py) and the experts'
-grouped products (ops/grouped.py), both imported by what the token
+percent of each other. Attention (ops/attention.py), the experts'
+grouped products (ops/grouped.py) and the sum of the experts' rows into
+their tokens (ops/rows_to_tokens.py), all imported by what the token
 families share (models/transformer.py) and not re-exported here, are chosen by backend and shape alone: the
-paths they replace send every score through HBM several times and multiply
-four padded rows for each one filled, so no timing could choose otherwise,
-and a probe would cost a compile of each side at start-up."""
+paths they replace send every score through HBM several times, multiply
+four padded rows for each one filled, and scatter every row of a buffer
+one at a time, so no timing could choose otherwise, and a probe would cost
+a compile of each side at start-up."""
 
 from tpu_resnet.ops import autotune
 from tpu_resnet.ops.epilogue import (

@@ -6,9 +6,13 @@ fall short of the rows: the rows past it belong to no group, and they are
 0 in the result and in the gradient with respect to ``lhs``, and add
 nothing to the gradient with respect to ``rhs``. That is the product of
 a token model's routed experts (models/transformer.py::dispatch_experts,
-which both token families' expert layers call): one buffer
+which every token family's expert layers call): one buffer
 of assignments sorted by expert, sized to what the held experts take
-together and not to the busiest one times their number.
+together and not to the busiest one times their number. The buffer's rows
+are gathered from their tokens before the products and summed back into
+them after (``ops/rows_to_tokens.py``: on one TPU chip a kernel that reads
+only the rows an assignment filled, everywhere else the scatter-add; its
+path is chosen as this module's is, by backend and device count).
 
 Two paths, one contract (``grouped_path``: a pure function of the backend's
 name and the number of its devices, like ``ops/attention.py``'s; no probe:
