@@ -446,7 +446,8 @@ def test_startup_is_a_span_tree(span_run):
 def test_one_compile_span_per_program_with_its_step(span_run):
     spans = span_run["spans"]
     compiles = [s for s in spans if s["span"] == "compile"
-                and "program" in s]
+                and "program" in s
+                and s["during"] != "process.before_train"]
     (legacy,) = [s for s in spans if s["span"] == "compile"
                  and "program" not in s]
     chunks = [s for s in compiles if s["program"] == "jit(chunk)"]
@@ -482,9 +483,11 @@ def test_phase_spans_nest_and_add_up(span_run):
                       key=lambda s: s["mono_ns"])
         assert {"train.dispatch", "train.device_wait"} <= \
             {s["span"] for s in kids}
-        assert all(s["span"].startswith("train.") or s["span"] == "compile"
+        assert all(s["span"].startswith("train.")
+                   or s["span"] in ("compile", "trace", "lower")
                    for s in kids)
-        phases = [s for s in kids if s["span"] != "compile"]
+        phases = [s for s in kids
+                  if s["span"] not in ("compile", "trace", "lower")]
         assert phases[-1]["span"] == "train.device_wait"  # it ends it
         edge = interval["mono_ns"]
         for s in phases:  # in order, inside the parent, never overlapping

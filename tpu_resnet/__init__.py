@@ -30,4 +30,11 @@ Subpackages
 ``tools``       checkpoint inspector, predict, FLOP/param analysis
 """
 
+import time as _time
+
+# The first line of the package's import, on the clock every recorder
+# reads: ``process.import`` starts here (obs/breakdown.py), and so does
+# ``process.before_train`` where the process's own start cannot be read.
+IMPORT_NS = _time.monotonic_ns()
+
 __version__ = "0.1.0"

@@ -12,9 +12,10 @@ package provides them as first-class artifacts of every run:
 ``breakdown``   StepBreakdown — the train loop's span recorder: every
                 phase of an iteration (``train.data_wait``,
                 ``train.dispatch``, ``train.device_wait``, the log
-                boundary, checkpoints), start-up and every compile as
-                spans with ``id`` and ``parent`` on the monotonic clock,
-                and per-interval sums for ``metrics.jsonl``.
+                boundary, checkpoints), start-up, the process's time
+                before ``train()`` and every trace, lowering and compile
+                as spans with ``id`` and ``parent`` on the monotonic
+                clock, and per-interval sums for ``metrics.jsonl``.
 ``spans``       SpanTracer — structured event spans (run, compile,
                 checkpoint save/restore, eval pass, profiler trace
                 window) appended to ``events.jsonl``, wall-clocked and

@@ -42,13 +42,27 @@ pending list (``keep=True``) that the loop writes out at its log
 boundaries, never to the ring a long run overwrites. A process-wide
 ``jax.monitoring`` listener turns every backend compile or cache load into
 a ``compile`` span that names the step, the program and the phase it fell
-in; a recompile in the middle of a run is one line of ``events.jsonl``.
+in, and every jaxpr trace and lowering to MLIR into a ``trace`` or
+``lower`` span the same way; a recompile in the middle of a run is a few
+lines of ``events.jsonl``.
+
+What came before ``train()`` is two more durable spans, from the same
+clock: ``process.before_train`` (the process's start, read from
+``/proc``, to the entry into ``train()``; obs/spans.py::process_start) and
+``process.import`` beneath it (the first line of ``tpu_resnet/__init__``
+to the end of ``train/loop.py``'s module body, where ``package_imported``
+is called and the listeners are registered). A compile made while no
+recorder listens (the benchmark's planting of its checkpoint, a compile
+between two ``train()`` calls of one process) is a ``compile`` span under
+the next ``process.before_train`` and counts in
+``before_train_compile_sec``, never in ``compile_load_sec``.
 
 ``interval()`` drains the sums of the interval that just closed into the
 run's ``metrics.jsonl`` record. The first dispatch — which pays XLA
 tracing + compilation — is reported separately as ``compile_seconds`` and
 excluded from the first interval so throughput numbers are never polluted
-by compile time.
+by compile time. Each interval's record also carries ``process_age_sec``,
+the seconds from the process's start to the interval's synced end.
 """
 
 from __future__ import annotations
@@ -59,13 +73,15 @@ import time
 from contextlib import contextmanager
 from typing import Dict, List, Optional, Tuple
 
-from tpu_resnet.obs.spans import next_span_id
+from tpu_resnet.obs.spans import next_span_id, process_start
 
 # name, start_ns, end_ns, id, parent, step, steps, attrs
 Span = Tuple[str, int, int, int, Optional[int], Optional[int], int,
              Optional[dict]]
 
-_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_TIMED_EVENTS = {"/jax/core/compile/backend_compile_duration": "compile",
+                 "/jax/core/compile/jaxpr_trace_duration": "trace",
+                 "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower"}
 _CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
 
 # The recorder the process-wide jax.monitoring listeners feed. train() runs
@@ -73,18 +89,75 @@ _CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
 # listeners are registered once and look the recorder up here.
 _current: Optional["StepBreakdown"] = None
 _listening = False
+_imported_ns: Optional[int] = None  # where process.import ends
+
+
+class _BeforeTrain:
+    """The compiles heard while no recorder listens, each a ``compile``
+    span under the ``process.before_train`` span (``id``) that the next
+    recorder writes; bounded like the ring."""
+
+    def __init__(self):
+        self.id = next_span_id()
+        self.spans: collections.deque = collections.deque(maxlen=4096)
+        self.compile_sec = 0.0
+        self.cache_hit = False
+
+    def compile(self, seconds: float, program: Optional[str]) -> None:
+        end_ns = time.monotonic_ns()
+        self.compile_sec += seconds
+        hit, self.cache_hit = self.cache_hit, False
+        self.spans.append((
+            "compile", end_ns - int(seconds * 1e9), end_ns, next_span_id(),
+            self.id, None, 0,
+            {"seconds": round(seconds, 4), "program": program,
+             "cache_hit": hit, "during": "process.before_train"}))
+
+
+_before = _BeforeTrain()
 
 
 def _on_event(event: str, **_) -> None:
-    rec = _current
-    if rec is not None and event == _CACHE_HIT_EVENT:
-        rec._on_cache_hit()
+    if event == _CACHE_HIT_EVENT:
+        rec = _current
+        if rec is not None:
+            rec._on_cache_hit()
+        else:
+            _before.cache_hit = True
 
 
 def _on_duration(event: str, duration: float, **kw) -> None:
+    kind = _TIMED_EVENTS.get(event)
+    if kind is None:
+        return
     rec = _current
-    if rec is not None and event == _COMPILE_EVENT:
-        rec._on_compile(duration, kw.get("fun_name"))
+    if rec is not None:
+        rec._on_timed(kind, duration, kw.get("fun_name"))
+    elif kind == "compile":
+        _before.compile(duration, kw.get("fun_name"))
+
+
+def listen() -> None:
+    """Register the process-wide ``jax.monitoring`` listeners (once)."""
+    global _listening
+    if not _listening:
+        import jax
+
+        jax.monitoring.register_event_listener(_on_event)
+        jax.monitoring.register_event_duration_secs_listener(_on_duration)
+        _listening = True
+
+
+def package_imported() -> None:
+    """The end of the package's import (``train/loop.py``'s module body):
+    ``process.import`` ends here, and from here on the listeners hear the
+    compiles made before ``train()``. The package's ``__init__`` stays
+    free of jax (hostenv, chip_smoke's parent), so they cannot start
+    earlier."""
+    global _imported_ns
+    if _imported_ns is None:
+        _imported_ns = time.monotonic_ns()
+    listen()
 
 
 class _Open:
@@ -102,7 +175,8 @@ class StepBreakdown:
     def __init__(self, ring: int = 4096):
         import jax
 
-        global _current, _listening
+        global _current, _before
+        entry_ns = time.monotonic_ns()
         self._annotate = jax.profiler.TraceAnnotation
         self._annotate_step = jax.profiler.StepTraceAnnotation
         self._block = jax.block_until_ready
@@ -110,19 +184,38 @@ class StepBreakdown:
         self.compile_seconds: Optional[float] = None
         self.startup_sec: Optional[float] = None
         self.compile_load_sec = 0.0
+        self.trace_lower_sec = 0.0
         self.compile_parent: Optional[int] = None
         self._pending: List[Span] = []
         self._stack: List[_Open] = []
+        # the outermost trace, lower and compile spans heard so far, as
+        # (start_ns, end_ns, span): one that encloses earlier ones (a jit
+        # traced inside another's trace) counts only its own time
+        self._outer: collections.deque = collections.deque(maxlen=ring)
         self._thread = threading.get_ident()
         self._cache_hit = False
         self._closed: Optional[Dict[str, float]] = None
-        self._open_interval(time.monotonic_ns(), None)
+        self._start_ns, source = process_start()
+        self._open_interval(entry_ns, None)
         self._stall_from: Optional[int] = None
-        if not _listening:
-            jax.monitoring.register_event_listener(_on_event)
-            jax.monitoring.register_event_duration_secs_listener(
-                _on_duration)
-            _listening = True
+        listen()
+        # What came before: the process's start to this entry, the
+        # package's import beneath it, the compiles heard meanwhile.
+        before, _before = _before, _BeforeTrain()
+        self.before_train_sec = (entry_ns - self._start_ns) / 1e9
+        self.before_train_compile_sec = before.compile_sec
+        self.import_sec: Optional[float] = None
+        self._pending.append((
+            "process.before_train", self._start_ns, entry_ns, before.id,
+            None, None, 0, {"process_start": source}))
+        if _imported_ns is not None:
+            from tpu_resnet import IMPORT_NS
+
+            self.import_sec = (_imported_ns - IMPORT_NS) / 1e9
+            self._pending.append((
+                "process.import", IMPORT_NS, _imported_ns, next_span_id(),
+                before.id, None, 0, None))
+        self._pending.extend(before.spans)
         _current = self
 
     # ------------------------------------------------------------- spans
@@ -245,25 +338,51 @@ class StepBreakdown:
         if threading.get_ident() == self._thread:
             self._cache_hit = True
 
-    def _on_compile(self, seconds: float, program: Optional[str]) -> None:
-        """One backend compile or persistent-cache load on the loop's
-        thread (other threads' — an eval sidecar's — are theirs)."""
+    def _on_timed(self, kind: str, seconds: float,
+                  program: Optional[str]) -> None:
+        """One jaxpr trace (``trace``), lowering to MLIR (``lower``), or
+        backend compile or persistent-cache load (``compile``) on the
+        loop's thread (other threads' — an eval sidecar's — are theirs).
+        A compile adds its seconds to ``compile_load_sec``; a trace or a
+        lowering adds to ``trace_lower_sec`` what it did not spend in the
+        ones it encloses, so that no second counts twice, and takes the
+        place of their spans (jax.numpy's own jits, traced inside a step:
+        a thousand lines for a small model's start-up). A compile inside
+        a trace keeps its span."""
         if threading.get_ident() != self._thread:
             return
         end_ns = time.monotonic_ns()
-        hit, self._cache_hit = self._cache_hit, False
+        start_ns = end_ns - int(seconds * 1e9)
+        inner_ns = 0
+        while self._outer and self._outer[-1][0] >= start_ns:
+            s, e, inner = self._outer.pop()
+            inner_ns += e - s
+            if inner[0] != "compile":
+                self._unpend(inner)
         top = self._stack[-1] if self._stack else None
         parent = self.compile_parent
         if parent is None:
             parent = top.id if top is not None else self._interval_id
-        self.compile_load_sec += seconds
-        self._pending.append((
-            "compile", end_ns - int(seconds * 1e9), end_ns, next_span_id(),
-            parent, top.step if top is not None else None,
-            top.steps if top is not None else 0,
-            {"seconds": round(seconds, 4), "program": program,
-             "cache_hit": hit,
-             "during": top.name if top is not None else None}))
+        attrs = {"seconds": round(seconds, 4), "program": program,
+                 "during": top.name if top is not None else None}
+        if kind == "compile":
+            attrs["cache_hit"], self._cache_hit = self._cache_hit, False
+            self.compile_load_sec += seconds
+        else:
+            self.trace_lower_sec += max(end_ns - start_ns - inner_ns, 0) / 1e9
+        span = (kind, start_ns, end_ns, next_span_id(), parent,
+                top.step if top is not None else None,
+                top.steps if top is not None else 0, attrs)
+        self._pending.append(span)
+        self._outer.append((start_ns, end_ns, span))
+
+    def _unpend(self, span: Span) -> None:
+        """Take ``span`` back out of the pending list, where a flush (the
+        watchdog's hang dump) has not written it already."""
+        for i in range(len(self._pending) - 1, -1, -1):
+            if self._pending[i] is span:
+                del self._pending[i]
+                return
 
     # ----------------------------------------------------------- writing
     def flush(self, tracer, ring: bool = False) -> None:
@@ -318,6 +437,7 @@ class StepBreakdown:
         }
         if self._device_wait_ns is not None:
             out["device_sync_sec"] = round(wait_ns / 1e9, 6)
+        out["process_age_sec"] = round((end_ns - self._start_ns) / 1e9, 4)
         self._interval_span(end_ns, step)
         self._open_interval(end_ns, step)
         return out
@@ -352,9 +472,14 @@ class StepBreakdown:
         sync causes) and ``loop_host_sec`` (wall time less ``data_wait``
         and ``device_wait``: what the loop thread itself held);
         ``device_sync_sec`` when a boundary sample was taken;
+        ``process_age_sec`` (the process's start to the interval's end);
         the run constants ``compile_seconds`` (first-dispatch wall time),
-        ``startup_sec`` and ``compile_load_sec`` (every backend compile or
-        cache load so far) once known."""
+        ``startup_sec``, ``compile_load_sec`` (every backend compile or
+        cache load so far), ``trace_lower_sec`` (every trace and lowering
+        so far), ``before_train_sec``, ``import_sec`` and
+        ``before_train_compile_sec`` (the spans ``process.before_train``
+        and ``process.import``, the compiles beneath the first) once
+        known."""
         out, self._closed = self._closed, None
         if out is None:
             out = self._close_interval(time.monotonic_ns(), None)
@@ -363,6 +488,12 @@ class StepBreakdown:
         if self.startup_sec is not None:
             out["startup_sec"] = round(self.startup_sec, 4)
         out["compile_load_sec"] = round(self.compile_load_sec, 4)
+        out["trace_lower_sec"] = round(self.trace_lower_sec, 4)
+        out["before_train_sec"] = round(self.before_train_sec, 4)
+        if self.import_sec is not None:
+            out["import_sec"] = round(self.import_sec, 4)
+        out["before_train_compile_sec"] = round(
+            self.before_train_compile_sec, 4)
         return out
 
 

@@ -45,6 +45,44 @@ def next_span_id() -> int:
     return next(_ids)
 
 
+def read_process_start_ns(stat_path: str = "/proc/self/stat"
+                          ) -> Optional[int]:
+    """The process's start on ``time.monotonic_ns()``, from the kernel's
+    ``starttime`` (field 22 of ``stat_path``: ticks of ``SC_CLK_TCK``
+    since boot, so 10 ms at 100 Hz) laid on the monotonic clock through
+    ``CLOCK_BOOTTIME``. None where there is no such file or clock."""
+    try:
+        with open(stat_path) as f:
+            stat = f.read()
+        # the command (field 2) may hold spaces and ")": count from its end
+        ticks = int(stat.rsplit(")", 1)[1].split()[19])
+        hz = os.sysconf("SC_CLK_TCK")
+        boot_ns = time.clock_gettime_ns(time.CLOCK_BOOTTIME)
+        mono_ns = time.monotonic_ns()
+    except (OSError, ValueError, IndexError, AttributeError):
+        return None
+    return mono_ns - (boot_ns - ticks * 10 ** 9 // hz)
+
+
+_process_start: Optional[tuple] = None
+
+
+def process_start() -> tuple:
+    """``(ns, source)``: the process's start on ``time.monotonic_ns()``,
+    read once, with ``source`` ``"proc_stat"``; where it cannot be read
+    (no ``/proc``), the first line of the package's import instead, with
+    ``source`` ``"package_import"``."""
+    global _process_start
+    if _process_start is None:
+        from tpu_resnet import IMPORT_NS
+
+        ns = read_process_start_ns()
+        _process_start = ((ns, "proc_stat") if ns is not None
+                          and ns <= IMPORT_NS
+                          else (IMPORT_NS, "package_import"))
+    return _process_start
+
+
 class SpanTracer:
     def __init__(self, directory: str, enabled: bool = True,
                  filename: str = "events.jsonl",

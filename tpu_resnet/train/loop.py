@@ -151,9 +151,10 @@ def train(cfg: RunConfig, mesh=None, metrics: Optional[MetricsWriter] = None,
           max_steps: Optional[int] = None):
     """Run training to ``cfg.train.train_steps``; returns the final state."""
     # The loop's span recorder (obs/breakdown.py), from the first line:
-    # ``train.startup`` runs from here to the end of the first dispatch,
-    # its phases and every compile beneath it are written to events.jsonl
-    # at the first log boundary (the tracer does not exist yet).
+    # ``process.before_train`` ends here and ``train.startup`` runs from
+    # here to the end of the first dispatch; both, their phases and every
+    # compile beneath them are written to events.jsonl when start-up ends
+    # (the tracer does not exist yet).
     breakdown = obs.StepBreakdown()
     breakdown.begin("train.startup", keep=True)  # first_dispatch_done ends it
     elastic_ctx = None
@@ -890,3 +891,8 @@ def train(cfg: RunConfig, mesh=None, metrics: Optional[MetricsWriter] = None,
             and total is not None and step < total:
         raise resilience.Preempted(step, state=state, signum=shutdown.signum)
     return state
+
+
+# The last line of the package's import as train() sees it: the span
+# ``process.import`` ends here, and the compile listeners start.
+obs.breakdown.package_imported()
