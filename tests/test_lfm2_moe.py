@@ -424,7 +424,7 @@ def test_startup_events_name_every_layers_mixer_and_attentions_path():
     assert row["head_dim"] == 16 and "padded_to" not in row
     big = lfm2_moe.attention_paths(Arch(layers=LAYERS), 4096, "tpu", 1)
     assert big == [dict(layer=1, kind="moe_full", path="kernel",
-                        head_dim=64, key_blocks_visited=10,
+                        inputs="fused", head_dim=64, key_blocks_visited=10,
                         key_blocks_total=16)]
 
 

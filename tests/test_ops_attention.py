@@ -173,9 +173,11 @@ def test_a_tiny_run_says_its_attention_path_once(tmp_path):
     assert len(events) == 1
     assert events[0]["layers"] == [
         {"layer": 0, "kind": "dense_sliding", "path": "scan",
-         "key_blocks_visited": 1, "key_blocks_total": 1},
+         "inputs": "composed", "key_blocks_visited": 1,
+         "key_blocks_total": 1},
         {"layer": 1, "kind": "dense_full", "path": "scan",
-         "key_blocks_visited": 1, "key_blocks_total": 1}]
+         "inputs": "composed", "key_blocks_visited": 1,
+         "key_blocks_total": 1}]
 
 
 @pytest.mark.parametrize("remat", [False, True], ids=["plain", "remat"])
@@ -258,6 +260,42 @@ def test_the_cells_kernels_compile_for_a_v5e(one_chip, batch, mask, heads):
     ).compile().as_text()
     assert "splash_mqa_fwd_segmented_residuals" in text
     assert "splash_mqa_dkv_segmented_no_residuals" in text
+
+
+@pytest.mark.parametrize("batch, seq, heads, rotary", [
+    (1, 8192, (32, 4), "by_ids"), (2, 4096, (32, 4), "from_start"),
+    (2, 4096, (32, 4), None)],
+    ids=["block_diffusion", "sliding", "full"])
+def test_the_attention_inputs_kernels_compile_for_a_v5e(
+        one_chip, monkeypatch, batch, seq, heads, rotary):
+    """Mosaic takes the pass that prepares attention's inputs
+    (``ops/attention_inputs.py``), forward and backward, at the cells'
+    shapes: the projections as the products write them, rotary by position
+    ids, from the sequence's start, or none. A compile, not a run; the
+    chip's backend is named in the test, as a chip would name it."""
+    from tpu_resnet.ops.attention_inputs import attention_inputs
+
+    h, kv = heads
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def shaped(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def loss(q, k, v, q_scale, k_scale, positions):
+        rot = {"by_ids": (1e6, positions), "from_start": (1e4, None),
+               None: None}[rotary]
+        outs = attention_inputs(
+            q.reshape(batch, seq, h, 128), k.reshape(batch, seq, kv, 128),
+            v.reshape(batch, seq, kv, 128), q_scale, k_scale, rot,
+            jnp.bfloat16, 1e-6)
+        return sum(jnp.sum(o.astype(jnp.float32)) for o in outs)
+
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        shaped(batch, seq, h * 128), shaped(batch, seq, kv * 128),
+        shaped(batch, seq, kv * 128), shaped(128, dtype=jnp.float32),
+        shaped(128, dtype=jnp.float32), shaped(1, seq, dtype=jnp.int32)
+    ).compile().as_text()
+    assert "attention_inputs_fwd" in text and "attention_inputs_bwd" in text
 
 
 def _the_parents_path(q, k, v, doc, window, dtype, block):

@@ -296,11 +296,13 @@ def test_preset_states_the_published_widths_and_spells_its_program():
 
 
 @pytest.mark.parametrize("backend, devices, said", [
-    ("tpu", 1, dict(path="kernel", key_blocks_visited=20,
+    ("tpu", 1, dict(path="kernel", inputs="fused", key_blocks_visited=20,
                     key_blocks_total=32, diagonal_rows=4096)),
-    ("tpu", 4, dict(path="scan", key_blocks_visited=32 * 32,
+    ("tpu", 4, dict(path="scan", inputs="composed",
+                    key_blocks_visited=32 * 32,
                     key_blocks_total=32 * 32, diagonal_rows=0)),
-    ("cpu", 8, dict(path="scan", key_blocks_visited=32 * 32,
+    ("cpu", 8, dict(path="scan", inputs="composed",
+                    key_blocks_visited=32 * 32,
                     key_blocks_total=32 * 32, diagonal_rows=0)),
 ], ids=["one_chip", "four_chips", "cpu"])
 def test_attention_paths_of_the_preset(backend, devices, said):
@@ -321,7 +323,7 @@ def test_half_a_tile_of_clean_ids_takes_the_scan():
     arch = build_model(load_config("sdar_30b_a3b_chat")).arch
     assert sdar_moe.attention_paths(arch, 512, "tpu", 1)[0]["path"] == "scan"
     assert sdar_moe.attention_paths(arch, 1024, "tpu", 1)[0] == dict(
-        layer=0, kind="block_diffusion", path="kernel",
+        layer=0, kind="block_diffusion", path="kernel", inputs="fused",
         key_blocks_visited=2, key_blocks_total=2, diagonal_rows=1024)
 
 
@@ -371,5 +373,5 @@ def test_tiny_preset_trains_through_train_and_says_its_path(tmp_path):
                 if e["span"] == "attention_path"]
     assert len(said) == 1 and len(said[0]["layers"]) == 2
     assert said[0]["layers"][0] == dict(
-        layer=0, kind="block_diffusion", path="scan",
+        layer=0, kind="block_diffusion", path="scan", inputs="composed",
         key_blocks_visited=1, key_blocks_total=1, diagonal_rows=0)

@@ -53,9 +53,9 @@ import jax
 import jax.numpy as jnp
 
 from tpu_resnet.models.transformer import (  # noqa: F401  (re-exported)
-    COUNTERS, RMSNorm, SwiGLU, _dot, _f32, _init, _KEEP, _reach, attend,
+    COUNTERS, INPUTS, RMSNorm, SwiGLU, _dot, _f32, _init, _KEEP, _reach,
     balanced_bias, blocked_attention, dispatch_experts, expert_paths,
-    refuses, rotary, sigmoid_router, sow_counters)
+    refuses, rotary, self_attention, sigmoid_router, sow_counters)
 from tpu_resnet.ops.attention import attention_path, key_blocks
 
 # layer kinds: what F is, and which mask attention takes
@@ -74,26 +74,18 @@ class Attention(nn.Module):
 
     @nn.compact
     def __call__(self, x, doc):
-        b, s, d = x.shape
-        h, kv, hd = self.heads, self.kv_heads, self.head_dim
-        with jax.named_scope("qkv"):
-            q = _dot(x, self.param("wq", _init, (d, h * hd), _f32),
-                     self.dtype).reshape(b, s, h, hd)
-            k = _dot(x, self.param("wk", _init, (d, kv * hd), _f32),
-                     self.dtype).reshape(b, s, kv, hd)
-            v = _dot(x, self.param("wv", _init, (d, kv * hd), _f32),
-                     self.dtype).reshape(b, s, kv, hd)
-            gate = _dot(x, self.param("wg", _init, (d, h * hd), _f32),
+        d, width = x.shape[-1], self.heads * self.head_dim
+        out = self_attention(
+            self, x, doc, self.window, heads=self.heads,
+            kv_heads=self.kv_heads, head_dim=self.head_dim, eps=self.eps,
+            rotary_of=(self.rope_theta, None) if self.window else None,
+            block=self.block, dtype=self.dtype)
+        with jax.named_scope("qkv"), jax.named_scope("project"):
+            gate = _dot(x, self.param("wg", _init, (d, width), _f32),
                         self.dtype)
-            q = RMSNorm(self.eps, name="q_norm")(q)
-            k = RMSNorm(self.eps, name="k_norm")(k)
-            if self.window:
-                q, k = rotary(q, self.rope_theta), rotary(k, self.rope_theta)
-        with jax.named_scope("scores"):
-            out = attend(q, k, v, doc, self.window, self.block, self.dtype)
         with jax.named_scope("gate_out"):
             out = out * jax.nn.sigmoid(gate)
-            return _dot(out, self.param("wo", _init, (h * hd, d), _f32),
+            return _dot(out, self.param("wo", _init, (width, d), _f32),
                         self.dtype)
 
 
@@ -253,7 +245,8 @@ class Afmoe(nn.Module):
 def attention_paths(model: Arch, seq_len: int, backend: str,
                     devices: int) -> List[Dict[str, object]]:
     """For each layer, the ``path`` its attention takes at ``seq_len`` on
-    ``devices`` of ``backend`` and, in tiles of queries by keys, ``key_blocks_visited``
+    ``devices`` of ``backend``, how its ``inputs`` are prepared on that
+    path (``transformer.INPUTS``) and, in tiles of queries by keys, ``key_blocks_visited``
     of ``key_blocks_total``: the kernel's from its own mask table, the
     scan's from its uniform span of ``reach + block`` keys a block. What
     ``train()`` says once, as the event ``attention_path``."""
@@ -269,7 +262,7 @@ def attention_paths(model: Arch, seq_len: int, backend: str,
             span = _reach(seq_len, window, block) + block
             visited = (seq_len // block) * -(-span // block)
             total = (seq_len // block) ** 2
-        rows.append(dict(layer=i, kind=kind, path=path,
+        rows.append(dict(layer=i, kind=kind, path=path, inputs=INPUTS[path],
                          key_blocks_visited=visited, key_blocks_total=total))
     return rows
 

@@ -56,10 +56,10 @@ import jax
 import jax.numpy as jnp
 
 from tpu_resnet.models import transformer
-from tpu_resnet.models.transformer import (RMSNorm, SwiGLU, _dot, _f32,
-                                           _init, _KEEP, attend,
+from tpu_resnet.models.transformer import (INPUTS, RMSNorm, SwiGLU, _dot,
+                                           _f32, _init, _KEEP,
                                            balanced_bias, dispatch_experts,
-                                           rotary, sigmoid_router,
+                                           self_attention, sigmoid_router,
                                            sow_counters)
 from tpu_resnet.ops.attention import attention_path, key_blocks
 
@@ -137,22 +137,14 @@ class Attention(nn.Module):
     @nn.compact
     def __call__(self, x, doc):
         m = self.arch
-        b, s, d = x.shape
-        h, kv, hd = m.heads, m.kv_heads, m.head_dim
-        with jax.named_scope("qkv"):
-            q = _dot(x, self.param("wq", _init, (d, h * hd), _f32),
-                     m.dtype).reshape(b, s, h, hd)
-            k = _dot(x, self.param("wk", _init, (d, kv * hd), _f32),
-                     m.dtype).reshape(b, s, kv, hd)
-            v = _dot(x, self.param("wv", _init, (d, kv * hd), _f32),
-                     m.dtype).reshape(b, s, kv, hd)
-            q = rotary(RMSNorm(m.eps, name="q_norm")(q), m.rope_theta)
-            k = rotary(RMSNorm(m.eps, name="k_norm")(k), m.rope_theta)
-        with jax.named_scope("scores"):
-            out = attend(q, k, v, doc, 0, m.attn_block, m.dtype)
+        d = x.shape[-1]
+        out = self_attention(
+            self, x, doc, 0, heads=m.heads, kv_heads=m.kv_heads,
+            head_dim=m.head_dim, eps=m.eps, rotary_of=(m.rope_theta, None),
+            block=m.attn_block, dtype=m.dtype)
         with jax.named_scope("out"):
-            return _dot(out, self.param("wo", _init, (h * hd, d), _f32),
-                        m.dtype)
+            return _dot(out, self.param(
+                "wo", _init, (m.heads * m.head_dim, d), _f32), m.dtype)
 
 
 class ExpertLayer(nn.Module):
@@ -294,7 +286,8 @@ def token_mixers(model: Arch) -> List[Dict[str, object]]:
 def attention_paths(model: Arch, seq_len: int, backend: str,
                     devices: int) -> List[Dict[str, object]]:
     """For each ATTENTION layer, the ``path`` it takes at ``seq_len`` on
-    ``devices`` of ``backend`` with its ``head_dim`` (a head of 64 goes to
+    ``devices`` of ``backend``, how its ``inputs`` are prepared on that
+    path (``transformer.INPUTS``), with its ``head_dim`` (a head of 64 goes to
     the kernel as it is: no ``padded_to``) and, in tiles of queries by
     keys, ``key_blocks_visited`` of ``key_blocks_total``: the kernel's from
     its own mask table, the scan's from its span of every key before a
@@ -306,7 +299,8 @@ def attention_paths(model: Arch, seq_len: int, backend: str,
                                     model.heads // model.kv_heads)
     else:
         visited = total = (seq_len // min(model.attn_block, seq_len)) ** 2
-    return [dict(layer=i, kind=kind, path=path, head_dim=model.head_dim,
+    return [dict(layer=i, kind=kind, path=path, inputs=INPUTS[path],
+                 head_dim=model.head_dim,
                  key_blocks_visited=visited, key_blocks_total=total)
             for i, kind in enumerate(model.layers)
             if kind.endswith("_full")]

@@ -58,10 +58,9 @@ import jax.numpy as jnp
 
 from tpu_resnet.models import transformer
 from tpu_resnet.models.transformer import (COUNTERS,  # noqa: F401
-                                           RMSNorm, _dot, _f32,
-                                           _init, _KEEP, attend,
-                                           dispatch_experts, rotary,
-                                           sow_counters)
+                                           INPUTS, RMSNorm, _dot, _f32,
+                                           _init, _KEEP, dispatch_experts,
+                                           self_attention, sow_counters)
 from tpu_resnet.ops.attention import (BlockDiffusion, attention_path,
                                       diagonal_rows, key_blocks)
 
@@ -129,25 +128,15 @@ class Attention(nn.Module):
     @nn.compact
     def __call__(self, x, doc, positions):
         m = self.arch
-        b, s, d = x.shape
-        h, kv, hd = m.heads, m.kv_heads, m.head_dim
-        with jax.named_scope("qkv"):
-            q = _dot(x, self.param("wq", _init, (d, h * hd), _f32),
-                     m.dtype).reshape(b, s, h, hd)
-            k = _dot(x, self.param("wk", _init, (d, kv * hd), _f32),
-                     m.dtype).reshape(b, s, kv, hd)
-            v = _dot(x, self.param("wv", _init, (d, kv * hd), _f32),
-                     m.dtype).reshape(b, s, kv, hd)
-            q = rotary(RMSNorm(m.eps, name="q_norm")(q), m.rope_theta,
-                       positions)
-            k = rotary(RMSNorm(m.eps, name="k_norm")(k), m.rope_theta,
-                       positions)
-        with jax.named_scope("scores"):
-            out = attend(q, k, v, doc, BlockDiffusion(s // 2, m.block_length),
-                         m.attn_block, m.dtype)
+        d = x.shape[-1]
+        out = self_attention(
+            self, x, doc, BlockDiffusion(x.shape[1] // 2, m.block_length),
+            heads=m.heads, kv_heads=m.kv_heads, head_dim=m.head_dim,
+            eps=m.eps, rotary_of=(m.rope_theta, positions),
+            block=m.attn_block, dtype=m.dtype)
         with jax.named_scope("out"):
-            return _dot(out, self.param("wo", _init, (h * hd, d), _f32),
-                        m.dtype)
+            return _dot(out, self.param(
+                "wo", _init, (m.heads * m.head_dim, d), _f32), m.dtype)
 
 
 class ExpertLayer(nn.Module):
@@ -296,7 +285,8 @@ def train_flops_per_sequence(model: Arch, seq_len: int) -> float:
 def attention_paths(model: Arch, seq_len: int, backend: str,
                     devices: int) -> List[Dict[str, object]]:
     """For each layer, the ``path`` its attention takes over the ``2 x
-    seq_len`` positions on ``devices`` of ``backend`` and, in tiles of
+    seq_len`` positions on ``devices`` of ``backend``, how its ``inputs``
+    are prepared on that path (``transformer.INPUTS``) and, in tiles of
     queries by keys, ``key_blocks_visited`` of ``key_blocks_total``: the
     kernel's from its own mask table, which is over the clean keys alone,
     with the ``diagonal_rows`` it leaves to the product of a block by a
@@ -312,6 +302,7 @@ def attention_paths(model: Arch, seq_len: int, backend: str,
         visited = total = (fed // min(model.attn_block, fed)) ** 2
         beside = 0
     return [dict(layer=i, kind="block_diffusion", path=path,
+                 inputs=INPUTS[path],
                  key_blocks_visited=visited, key_blocks_total=total,
                  diagonal_rows=beside)
             for i in range(model.layers)]

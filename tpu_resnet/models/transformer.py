@@ -45,13 +45,17 @@ groups' sizes all the same; ``moe_overflow_frac`` says whether the tiers
 beyond ran, ``moe_rows_filled_frac`` how much of the buffer carried an
 assignment).
 
-**Attention** (``attend``) never builds a ``(B, H, S, S)`` score tensor.
-On one TPU chip, at heads of a multiple of 128, or of 64, and sequences
-its blocks divide, it is one fused kernel that keeps each tile of scores on the chip
+**Attention** (``self_attention``, which all three families call: the
+q/k/v projections, the q/k norms, rotary where a family asks for it, and
+attention) never builds a ``(B, H, S, S)`` score tensor. On one TPU chip,
+at heads of a multiple of 128, or of 64, and sequences its blocks divide,
+it is one fused kernel that keeps each tile of scores on the chip
 (``ops/attention.py``, which also says how the path is chosen and what a
-mask is); everywhere else a block of queries at a time against the keys
-its mask can reach, as a scan whose body is under ``jax.checkpoint``
-(``blocked_attention``).
+mask is), fed by one pass that norms, rotates, scales, casts and lays out
+its inputs, with a backward pass of its own (``ops/attention_inputs.py``);
+everywhere else the norms and rotary as composed here and a block of
+queries at a time against the keys its mask can reach, as a scan whose
+body is under ``jax.checkpoint`` (``blocked_attention``).
 """
 
 from __future__ import annotations
@@ -66,7 +70,8 @@ from jax.ad_checkpoint import checkpoint_name
 
 from tpu_resnet.ops import rows_to_tokens
 from tpu_resnet.ops.attention import (BlockDiffusion, attention_path,
-                                      fused_attention)
+                                      heads_first_attention)
+from tpu_resnet.ops.attention_inputs import attention_inputs
 from tpu_resnet.ops.grouped import grouped_dot, grouped_path, row_tile
 
 COUNTERS = ("moe_dropped_frac", "moe_load_max_over_mean", "moe_here_frac",
@@ -92,6 +97,23 @@ def _dot(x, w, dtype, out=None):
     ).astype(out or dtype)
 
 
+def rms_norm(x, scale, eps: float):
+    """``x * rsqrt(mean(x * x) + eps) * scale`` over the last axis, in
+    float32."""
+    x = x.astype(_f32)
+    return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True)
+                             + eps) * scale
+
+
+class NormScale(nn.Module):
+    """The ``(width,)`` weight of an RMSNorm, ``<name>/scale``, for a norm
+    that is computed elsewhere (``self_attention``'s q/k norms)."""
+
+    @nn.compact
+    def __call__(self, width: int):
+        return self.param("scale", nn.initializers.ones, (width,), _f32)
+
+
 class RMSNorm(nn.Module):
     eps: float = 1e-5
 
@@ -99,9 +121,7 @@ class RMSNorm(nn.Module):
     def __call__(self, x):
         scale = self.param("scale", nn.initializers.ones, (x.shape[-1],),
                            _f32)
-        x = x.astype(_f32)
-        return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True)
-                                 + self.eps) * scale
+        return rms_norm(x, scale, self.eps)
 
 
 class SwiGLU(nn.Module):
@@ -204,22 +224,61 @@ def blocked_attention(q, k, v, doc, mask, block: int, dtype):
     return jnp.moveaxis(out, 0, 1).reshape(b, s, kv, g, d)
 
 
-def attend(q, k, v, doc, mask, block: int, dtype):
-    """``blocked_attention``'s contract by whichever path
-    ``attention_path`` gives here; the result is named ``attention`` for a
-    ``remat`` policy. ``q`` is ``(B, S, H, D)``; returns ``(B, S, H * D)``."""
-    b, s, h, hd = q.shape
-    kv = k.shape[2]
-    q = q.reshape(b, s, kv, h // kv, hd)
+# What the event ``attention_path`` says of a layer's inputs on each path
+INPUTS = {"kernel": "fused", "scan": "composed"}
+
+
+def self_attention(module: nn.Module, x, doc, mask, *, heads: int,
+                   kv_heads: int, head_dim: int, eps: float, rotary_of,
+                   block: int, dtype):
+    """Attention of ``x`` ``(B, S, d)`` over itself within documents
+    ``doc`` under ``mask`` (``blocked_attention``) by whichever path
+    ``attention_path`` gives here, before the output's product: ``q, k, v
+    = x Wq, x Wk, x Wv`` (``module``'s ``wq``, ``wk``, ``wv``), per head
+    ``q = RMSNorm(q)``, ``k = RMSNorm(k)`` (``q_norm/scale``,
+    ``k_norm/scale``), the rotary embedding where ``rotary_of`` is
+    ``(theta, positions)`` (positions ``(P, S)``, or None to count from
+    the sequence's start; None for no rotary). Returns ``(B, S, H * D)``
+    in ``dtype``, named ``attention`` for a ``remat`` policy.
+
+    On the kernel's path the norms, rotary, the scale and the kernel's
+    layout are one pass each way (``ops/attention_inputs.py``); on the
+    scan's they are the composed chain (``rms_norm``, ``rotary``), the
+    reference of that pass. Scopes: ``qkv/project`` the products,
+    ``qkv/prepare`` what lies between them and ``scores``."""
+    b, s, d = x.shape
+    h, kv, hd = heads, kv_heads, head_dim
     # the kernel's blocks have to divide the keys it is given, which under
     # the three-part mask are the clean copy's alone
     keys = mask.clean_len if isinstance(mask, BlockDiffusion) else s
-    if attention_path(jax.default_backend(), jax.device_count(), hd,
-                      keys) == "kernel":
-        out = fused_attention(q, k, v, doc, mask, dtype)
-    else:
-        out = blocked_attention(q, k, v, doc, mask, block, dtype)
-    return checkpoint_name(out.reshape(b, s, h * hd), "attention")
+    kernel = attention_path(jax.default_backend(), jax.device_count(), hd,
+                            keys) == "kernel"
+    with jax.named_scope("qkv"):
+        with jax.named_scope("project"):
+            q = _dot(x, module.param("wq", _init, (d, h * hd), _f32),
+                     dtype).reshape(b, s, h, hd)
+            k = _dot(x, module.param("wk", _init, (d, kv * hd), _f32),
+                     dtype).reshape(b, s, kv, hd)
+            v = _dot(x, module.param("wv", _init, (d, kv * hd), _f32),
+                     dtype).reshape(b, s, kv, hd)
+        q_scale = NormScale(name="q_norm")(hd)
+        k_scale = NormScale(name="k_norm")(hd)
+        with jax.named_scope("prepare"):
+            if kernel:
+                q, k, v = attention_inputs(q, k, v, q_scale, k_scale,
+                                           rotary_of, dtype, eps)
+            else:
+                q, k = rms_norm(q, q_scale, eps), rms_norm(k, k_scale, eps)
+                if rotary_of is not None:
+                    q, k = rotary(q, *rotary_of), rotary(k, *rotary_of)
+    with jax.named_scope("scores"):
+        if kernel:
+            out = jnp.transpose(heads_first_attention(q, k, v, doc, mask),
+                                (0, 3, 1, 2, 4))
+        else:
+            out = blocked_attention(q.reshape(b, s, kv, h // kv, hd), k, v,
+                                    doc, mask, block, dtype)
+        return checkpoint_name(out.reshape(b, s, h * hd), "attention")
 
 
 # ----------------------------------------------------------------- experts

@@ -237,17 +237,28 @@ def fused_attention(q, k, v, doc, mask: Mask, dtype, *,
     maximum, exponentials and sum are float32. ``blocks`` and ``interpret``
     are for the tests: the first defaults to ``BLOCKS``, the second to any
     backend but a TPU."""
+    q, k, v = _heads_first(q, k, v, dtype)
+    out = heads_first_attention(q, k, v, doc, mask, blocks=blocks,
+                                interpret=interpret)
+    return jnp.transpose(out, (0, 3, 1, 2, 4))
+
+
+def heads_first_attention(q, k, v, doc, mask: Mask, *,
+                          blocks: Optional[splash.BlockSizes] = None,
+                          interpret: Optional[bool] = None):
+    """``fused_attention`` on inputs already in the kernel's layout and
+    dtype: ``q`` ``(B, KV, G, S, D)`` scaled by ``1 / sqrt(D)``, ``k`` and
+    ``v`` ``(B, KV, S, D)``; returns ``(B, KV, G, S, D)``
+    (``ops/attention_inputs.py`` writes that layout in the pass that
+    prepares them)."""
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     blocks = blocks or BLOCKS
-    q, k, v = _heads_first(q, k, v, dtype)
     if isinstance(mask, BlockDiffusion):
-        out = _two_parts(q, k, v, doc, mask, blocks, interpret)
-    else:
-        kernel = _kernel(q.shape[3], mask, q.shape[2], blocks, interpret)
-        per_head = jax.vmap(kernel, in_axes=(0, 0, 0, None))   # over KV
-        out = jax.vmap(per_head)(q, k, v, splash.SegmentIds(doc, doc))
-    return jnp.transpose(out, (0, 3, 1, 2, 4))
+        return _two_parts(q, k, v, doc, mask, blocks, interpret)
+    kernel = _kernel(q.shape[3], mask, q.shape[2], blocks, interpret)
+    per_head = jax.vmap(kernel, in_axes=(0, 0, 0, None))   # over KV
+    return jax.vmap(per_head)(q, k, v, splash.SegmentIds(doc, doc))
 
 
 # ------------------------------------------ block diffusion, in two parts
