@@ -119,7 +119,8 @@ def test_a_family_is_fed_by_its_kind_of_data_only(toy, preset, overrides,
         check_step_config(cfg, 1)
     said = str(err.value)
     assert repr(model) in said and repr(dataset) in said
-    assert "feeds model 'afmoe', 'lfm2_moe', 'sdar_moe', 'toy_tokens'" in said
+    assert ("feeds model 'afmoe', 'lfm2_moe', 'qwen3_next', 'sdar_moe', "
+            "'toy_tokens'") in said
     assert "feeds model 'mlp', 'resnet'" in said
 
 
@@ -316,7 +317,8 @@ def test_register_refuses_an_unknown_kind_of_input():
 def test_unknown_model_and_unknown_module_are_said():
     cfg = load_config("smoke", overrides=["model.name=resnext"])
     with pytest.raises(ValueError, match="unknown model 'resnext'.*'afmoe', "
-                                         "'lfm2_moe', 'mlp', 'resnet'"):
+                                         "'lfm2_moe', 'mlp', 'qwen3_next', "
+                                         "'resnet'"):
         build_model(cfg)
     with pytest.raises(ValueError, match="no registered model family "
                                          "builds a ToyTokens"):

@@ -164,7 +164,8 @@ class ModelConfig:
     """
 
     # resnet | mlp | afmoe (its fields: AfmoeConfig) | sdar_moe
-    # (SdarMoeConfig) | lfm2_moe (Lfm2MoeConfig)
+    # (SdarMoeConfig) | lfm2_moe (Lfm2MoeConfig) | qwen3_next
+    # (Qwen3NextConfig)
     name: str = "resnet"
     resnet_size: int = 50
     width_multiplier: int = 1
@@ -297,6 +298,37 @@ class Lfm2MoeConfig:
     rms_eps: float = 1e-5
     route_scale: float = 1.0
     balance_coeff: float = 0.001
+
+
+@dataclasses.dataclass
+class Qwen3NextConfig:
+    """The fields of ``model.name=qwen3_next`` (the family's module has the
+    equations, at ``Arch``): a decoder whose layers mix tokens by Gated
+    DeltaNet or by gated attention, every one with routed experts beside a
+    gated shared one, as one chip of an expert-parallel group holds it. The
+    defaults are the published widths of the preset's source; the rows of
+    the vocabulary held here are ``data.vocab_size``."""
+
+    # each layer's mixer, in order: linear (Gated DeltaNet) | full
+    layers: tuple = ("linear", "linear", "linear", "full")
+    hidden: int = 2048
+    heads: int = 16
+    kv_heads: int = 2
+    head_dim: int = 256
+    rotary_dim: int = 64       # partial_rotary_factor 0.25 x head_dim
+    key_heads: int = 16        # linear_num_key_heads
+    value_heads: int = 32      # linear_num_value_heads
+    key_dim: int = 128
+    value_dim: int = 128
+    conv_taps: int = 4         # linear_conv_kernel_dim
+    expert_width: int = 512
+    shared_width: int = 512
+    experts_total: int = 512   # the router's width
+    experts_first: int = 0     # the routed experts this chip holds:
+    experts_held: int = 32     # experts_first .. experts_first + held
+    top_k: int = 10
+    rope_theta: float = 1e7
+    rms_eps: float = 1e-6
 
 
 @dataclasses.dataclass
@@ -813,6 +845,8 @@ class RunConfig:
         default_factory=SdarMoeConfig)
     lfm2_moe: Lfm2MoeConfig = dataclasses.field(
         default_factory=Lfm2MoeConfig)
+    qwen3_next: Qwen3NextConfig = dataclasses.field(
+        default_factory=Qwen3NextConfig)
     optim: OptimConfig = dataclasses.field(default_factory=OptimConfig)
     mesh: MeshConfig = dataclasses.field(default_factory=MeshConfig)
     train: TrainConfig = dataclasses.field(default_factory=TrainConfig)
@@ -993,6 +1027,21 @@ def _lfm2_24b_a2b_ep8() -> RunConfig:
     return cfg
 
 
+def _qwen3_next_80b_a3b_ep16() -> RunConfig:
+    """Qwen3-Next-80B-A3B (Qwen, ``qwen3_next``) as one of sixteen chips
+    that share each layer: 32 of the 512 routed experts and an eighth of
+    the vocabulary held here, published layers 1-4 (Gated DeltaNet three
+    times, then gated attention: one period); next-token cross-entropy,
+    AdamW as the other token presets."""
+    cfg = _trinity_mini_ep16()
+    cfg.data.vocab_size = 18_992
+    cfg.model.name = "qwen3_next"
+    # the DeltaNet mixers' elementwise work computed again backward: the
+    # step does not fit the chip's 16 GB without it (PERF.md section 4)
+    cfg.model.remat = True
+    return cfg
+
+
 # The supported config space (these presets × mesh/dtype/fused/remat/
 # engine variations) is certified statically: tpu_resnet/analysis/
 # configmatrix.py traces the compiled train/eval program of every
@@ -1009,6 +1058,7 @@ PRESETS = {
     "trinity_mini_ep16": _trinity_mini_ep16,
     "sdar_30b_a3b_chat": _sdar_30b_a3b_chat,
     "lfm2_24b_a2b_ep8": _lfm2_24b_a2b_ep8,
+    "qwen3_next_80b_a3b_ep16": _qwen3_next_80b_a3b_ep16,
 }
 
 

@@ -21,11 +21,12 @@ from typing import Callable, Dict, Optional, Tuple
 
 import jax.numpy as jnp
 
-from tpu_resnet.models import (afmoe, lfm2_moe, mlp, resnet, sdar_moe,
-                               transformer)
+from tpu_resnet.models import (afmoe, lfm2_moe, mlp, qwen3_next, resnet,
+                               sdar_moe, transformer)
 from tpu_resnet.models.afmoe import Afmoe
 from tpu_resnet.models.lfm2_moe import Lfm2Moe
 from tpu_resnet.models.mlp import MLP
+from tpu_resnet.models.qwen3_next import Qwen3Next
 from tpu_resnet.models.sdar_moe import SdarMoe
 from tpu_resnet.models.resnet import (
     ResNetV2,
@@ -37,6 +38,7 @@ __all__ = [
     "Afmoe",
     "Lfm2Moe",
     "MLP",
+    "Qwen3Next",
     "ResNetV2",
     "SdarMoe",
     "cifar_resnet_v2",
@@ -140,7 +142,8 @@ def sample_input(cfg):
     set's size or, for a token model, one short sequence of ids (no
     leaf's shape depends on the length, in any token family: a conv
     layer's filter of ``lfm2_moe`` is ``(hidden, taps)``; of ``sdar_moe``
-    the eight ids are a noised and a clean copy of one block of four)."""
+    the eight ids are a noised and a clean copy of one block of four; of
+    ``qwen3_next`` the recurrence takes them as one chunk)."""
     if family(cfg).inputs == "tokens":
         return jnp.zeros((1, 8), jnp.int32)
     size = cfg.data.resolved_image_size
@@ -181,3 +184,8 @@ register(Family("lfm2_moe", "tokens", Lfm2Moe, lfm2_moe.build,
                 train_flops_per_example=lfm2_moe.train_flops_per_example,
                 counters=lfm2_moe.COUNTERS, refuses=transformer.refuses,
                 startup_events=lfm2_moe.startup_events))
+register(Family("qwen3_next", "tokens", Qwen3Next, qwen3_next.build,
+                qwen3_next.spell,
+                train_flops_per_example=qwen3_next.train_flops_per_example,
+                counters=qwen3_next.COUNTERS, refuses=qwen3_next.refuses,
+                startup_events=qwen3_next.startup_events))

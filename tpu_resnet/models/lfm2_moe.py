@@ -37,8 +37,9 @@ The equations (``d`` hidden size; RMSNorm with a weight; no bias):
 Attention by path, the dispatch with its five ``moe_*`` counters, the
 router and its bias update, RMSNorm, SwiGLU, the rotary embedding and the
 products' numerics are ``models/transformer.py``'s, shared with the other
-token families; this module keeps what is LFM2's own: the short
-convolution with its document cut and ``conv_cut_taps_frac``, the layer
+token families, as is the short convolution with its document cut
+(``short_conv``, which ``qwen3_next`` calls too); this module keeps what is
+LFM2's own: the gated operator around it and ``conv_cut_taps_frac``, the layer
 kinds that pair either mixer with either ``F``, two norms a layer, the tied
 head. Parameters, the residual stream, norms, router, softmax, logits and
 loss are float32; matrix products take ``dtype`` operands (bf16),
@@ -48,7 +49,6 @@ accumulate in float32 and hand on ``dtype``.
 from __future__ import annotations
 
 import dataclasses
-import math
 from typing import Any, Dict, List, Tuple
 
 import flax.linen as nn
@@ -56,33 +56,15 @@ import jax
 import jax.numpy as jnp
 
 from tpu_resnet.models import transformer
-from tpu_resnet.models.transformer import (INPUTS, RMSNorm, SwiGLU, _dot,
-                                           _f32, _init, _KEEP,
-                                           balanced_bias, dispatch_experts,
-                                           self_attention, sigmoid_router,
-                                           sow_counters)
+from tpu_resnet.models.transformer import (  # noqa: F401  (re-exported)
+    INPUTS, RMSNorm, SwiGLU, _dot, _f32, _init, _KEEP, balanced_bias,
+    dispatch_experts, same_document, self_attention, short_conv,
+    sigmoid_router, sow_counters, taps_init)
 from tpu_resnet.ops.attention import attention_path, key_blocks
 
 # layer kinds: what F is, and which token mixer the layer takes
 LAYER_KINDS = ("dense_conv", "dense_full", "moe_conv", "moe_full")
 COUNTERS = transformer.COUNTERS + ("conv_cut_taps_frac",)
-
-
-def _taps_init(key, shape, dtype=_f32):
-    """A depthwise filter as PyTorch's ``Conv1d`` draws it: uniform within
-    ``1 / sqrt(taps)`` (its fan-in is the taps of one channel). At the
-    matrices' 0.02 a fresh operator would hand on a fiftieth of its
-    gate."""
-    bound = 1.0 / math.sqrt(shape[-1])
-    return jax.random.uniform(key, shape, dtype, -bound, bound)
-
-
-def same_document(doc, back: int):
-    """``(B, S)`` bool: whether position ``t - back`` exists and lies in
-    ``t``'s document."""
-    before = jnp.pad(doc, ((0, 0), (back, 0)),
-                     constant_values=-1)[:, :doc.shape[1]]
-    return before == doc
 
 
 def cut_taps_frac(doc, taps: int):
@@ -94,20 +76,6 @@ def cut_taps_frac(doc, taps: int):
     b, s = doc.shape
     cut = sum(jnp.sum(~same_document(doc, j)[:, j:]) for j in range(1, taps))
     return cut.astype(_f32) / (taps * b * s)
-
-
-def short_conv(u, taps, doc):
-    """The causal depthwise convolution of ``u`` ``(B, S, d)`` by ``taps``
-    ``(d, K)`` within documents: ``c_t = sum_j taps[:, K-1-j] * u_{t-j}``
-    with ``u_{t-j}`` 0 before the start of ``t``'s document. ``K``
-    shifted multiply-adds."""
-    s, last = u.shape[1], taps.shape[1] - 1
-    out = u * taps[:, last]
-    for j in range(1, last + 1):
-        back = jnp.pad(u, ((0, 0), (j, 0), (0, 0)))[:, :s]
-        out = out + jnp.where(same_document(doc, j)[..., None], back,
-                              0.0) * taps[:, last - j]
-    return out
 
 
 class ShortConv(nn.Module):
@@ -124,7 +92,7 @@ class ShortConv(nn.Module):
         with jax.named_scope("gate_conv"):
             b, c, x = (a.astype(_f32) for a in jnp.split(bcx, 3, axis=-1))
             y = c * short_conv(
-                b * x, self.param("conv", _taps_init, (d, self.taps), _f32),
+                b * x, self.param("conv", taps_init, (d, self.taps), _f32),
                 doc)
         with jax.named_scope("out_proj"):
             return _dot(y, self.param("out_proj", _init, (d, d), _f32),

@@ -30,9 +30,9 @@ The equations (``d`` hidden size; RMSNorm with a weight; no bias):
   the clean text strictly before it; the clean copy sees clean text up to
   and including its own block; nothing sees a noisy key of another block.
   ``Attn = (softmax V) Wo``.
-- expert layer: ``s = softmax(x Wr)`` in float32 over ALL
-  ``experts_total``; chosen = top-k of ``s``; ``w = s[chosen] / sum
-  s[chosen]``; ``MoE(x) = sum w_e Expert_e(x)`` over the chosen experts
+- expert layer: ``models/transformer.py``'s softmax router (``s =
+  softmax(x Wr)`` in float32 over ALL ``experts_total``; chosen = top-k of
+  ``s``; ``w = s[chosen] / sum s[chosen]``); ``MoE(x) = sum w_e Expert_e(x)`` over the chosen experts
   THIS CHIP HOLDS (``experts_held = (first, count)``), ``Expert(x) =
   (silu(x Wg) * (x Wu)) Wd``. No shared expert, no bias, no auxiliary
   loss. The dispatch is ``models/transformer.py``'s, as are attention's
@@ -60,7 +60,8 @@ from tpu_resnet.models import transformer
 from tpu_resnet.models.transformer import (COUNTERS,  # noqa: F401
                                            INPUTS, RMSNorm, _dot, _f32,
                                            _init, _KEEP, dispatch_experts,
-                                           self_attention, sow_counters)
+                                           self_attention, softmax_router,
+                                           sow_counters)
 from tpu_resnet.ops.attention import (BlockDiffusion, attention_path,
                                       diagonal_rows, key_blocks)
 
@@ -153,11 +154,9 @@ class ExpertLayer(nn.Module):
         w_up = self.param("up", _init, (count, d, m.expert_width), _f32)
         w_down = self.param("down", _init, (count, m.expert_width, d), _f32)
         with jax.named_scope("router"):
-            scores = jax.nn.softmax(jnp.dot(
+            chosen, weight = softmax_router(           # (N, k) float32
                 x, self.param("router", _init, (d, m.experts_total), _f32),
-                precision=jax.lax.Precision.HIGHEST), axis=-1)
-            s, chosen = jax.lax.top_k(scores, m.top_k)
-            weight = s / jnp.sum(s, -1, keepdims=True)  # (N, k) float32
+                m.top_k)
         out, counters = dispatch_experts(
             x, chosen, weight, w_gate, w_up, w_down,
             experts_total=m.experts_total, experts_held=m.experts_held,

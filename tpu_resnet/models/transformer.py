@@ -1,13 +1,18 @@
-"""What the token families share (``afmoe``, ``sdar_moe``, ``lfm2_moe``):
-the numerics of a matrix product, RMSNorm, the SwiGLU MLP, the rotary
-embedding by position ids, attention by whichever path the backend and the
-shapes give, the sigmoid router with its bias, and the dispatch of an
-expert layer's assignments to the experts this chip holds. A family keeps
-what is its own: its token mixers, its norms' places, its masks, its
-objective, a router of another kind.
+"""What the token families share (``afmoe``, ``sdar_moe``, ``lfm2_moe``,
+``qwen3_next``): the numerics of a matrix product, RMSNorm, the SwiGLU MLP,
+the rotary embedding by position ids (over a whole head or its first
+columns), attention by whichever path the backend and the shapes give (with
+an output gate taken from the query's product where a family asks), the
+causal short convolution within documents, the sigmoid router with its bias
+and the softmax router, and the dispatch of an expert layer's assignments
+to the experts this chip holds. A family keeps what is its own: its token
+mixers, its norms' places, its masks, its objective.
 
-**The sigmoid router** (``sigmoid_router``, ``balanced_bias``; ``afmoe``
-and ``lfm2_moe`` call it, each with its two numbers): ``s = sigmoid(x
+**The softmax router** (``softmax_router``; ``sdar_moe`` and
+``qwen3_next``): ``s = softmax(x Wr)`` over all experts, top-k, the chosen
+renormalised to 1. **The sigmoid router** (``sigmoid_router``,
+``balanced_bias``; ``afmoe`` and ``lfm2_moe`` call it, each with its two
+numbers): ``s = sigmoid(x
 Wr)`` in float32 over ALL ``experts_total``; chosen = top-k of ``s + b``,
 ``b`` the layer's ``expert_bias``, state without a gradient that chooses
 and does not weigh; ``w = s[chosen] / (sum s[chosen] + eps) * scale``. In
@@ -45,7 +50,7 @@ groups' sizes all the same; ``moe_overflow_frac`` says whether the tiers
 beyond ran, ``moe_rows_filled_frac`` how much of the buffer carried an
 assignment).
 
-**Attention** (``self_attention``, which all three families call: the
+**Attention** (``self_attention``, which all four families call: the
 q/k/v projections, the q/k norms, rotary where a family asks for it, and
 attention) never builds a ``(B, H, S, S)`` score tensor. On one TPU chip,
 at heads of a multiple of 128, or of 64, and sequences its blocks divide,
@@ -107,10 +112,16 @@ def rms_norm(x, scale, eps: float):
 
 class NormScale(nn.Module):
     """The ``(width,)`` weight of an RMSNorm, ``<name>/scale``, for a norm
-    that is computed elsewhere (``self_attention``'s q/k norms)."""
+    that is computed elsewhere (``self_attention``'s q/k norms). A
+    zero-centred norm's weight ``w`` starts at 0 and scales by ``1 + w``:
+    what is returned is that scale."""
+    zero_centred: bool = False
 
     @nn.compact
     def __call__(self, width: int):
+        if self.zero_centred:
+            return 1.0 + self.param("scale", nn.initializers.zeros, (width,),
+                                    _f32)
         return self.param("scale", nn.initializers.ones, (width,), _f32)
 
 
@@ -141,11 +152,16 @@ class SwiGLU(nn.Module):
                     self.dtype)
 
 
-def rotary(x, theta: float, positions=None):
-    """Rotate-half rotary embedding over the whole head; ``x`` is
-    ``(B, S, H, D)`` float32. ``positions`` are ``(B, S)`` position ids;
-    without them positions count from the sequence's start."""
+def rotary(x, theta: float, positions=None, dims: int = 0):
+    """Rotate-half rotary embedding; ``x`` is ``(B, S, H, D)`` float32.
+    ``positions`` are ``(B, S)`` position ids; without them positions count
+    from the sequence's start. ``dims`` (0: the whole head) rotates the
+    first ``dims`` of each head and passes the rest through (a partial
+    rotary factor), the frequencies over those ``dims``."""
     d = x.shape[-1]
+    if dims and dims < d:
+        return jnp.concatenate([rotary(x[..., :dims], theta, positions),
+                                x[..., dims:]], -1)
     inv = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=_f32) / d))
     if positions is None:
         positions = jnp.arange(x.shape[1])[None]
@@ -154,6 +170,38 @@ def rotary(x, theta: float, positions=None):
     sin = jnp.concatenate([jnp.sin(ang)] * 2, -1)[:, :, None, :]
     x1, x2 = x[..., : d // 2], x[..., d // 2:]
     return x * cos + jnp.concatenate([-x2, x1], -1) * sin
+
+
+# ------------------------------------------------------- short convolution
+def same_document(doc, back: int):
+    """``(B, S)`` bool: whether position ``t - back`` exists and lies in
+    ``t``'s document."""
+    before = jnp.pad(doc, ((0, 0), (back, 0)),
+                     constant_values=-1)[:, :doc.shape[1]]
+    return before == doc
+
+
+def taps_init(key, shape, dtype=_f32):
+    """A depthwise filter ``(channels, K)`` as PyTorch's ``Conv1d`` draws
+    it: uniform within ``1 / sqrt(K)`` (its fan-in is the taps of one
+    channel). At the matrices' 0.02 a fresh filter would hand on a
+    fiftieth of its input."""
+    bound = 1.0 / math.sqrt(shape[-1])
+    return jax.random.uniform(key, shape, dtype, -bound, bound)
+
+
+def short_conv(u, taps, doc):
+    """The causal depthwise convolution of ``u`` ``(B, S, d)`` by ``taps``
+    ``(d, K)``, any ``K``, within documents: ``c_t = sum_j taps[:, K-1-j] *
+    u_{t-j}`` with ``u_{t-j}`` 0 before the start of ``t``'s document.
+    ``K`` shifted multiply-adds."""
+    s, last = u.shape[1], taps.shape[1] - 1
+    out = u * taps[:, last]
+    for j in range(1, last + 1):
+        back = jnp.pad(u, ((0, 0), (j, 0), (0, 0)))[:, :s]
+        out = out + jnp.where(same_document(doc, j)[..., None], back,
+                              0.0) * taps[:, last - j]
+    return out
 
 
 # --------------------------------------------------------------- attention
@@ -230,7 +278,8 @@ INPUTS = {"kernel": "fused", "scan": "composed"}
 
 def self_attention(module: nn.Module, x, doc, mask, *, heads: int,
                    kv_heads: int, head_dim: int, eps: float, rotary_of,
-                   block: int, dtype):
+                   block: int, dtype, rotary_dim: int = 0,
+                   zero_centred: bool = False, gated: bool = False):
     """Attention of ``x`` ``(B, S, d)`` over itself within documents
     ``doc`` under ``mask`` (``blocked_attention``) by whichever path
     ``attention_path`` gives here, before the output's product: ``q, k, v
@@ -238,8 +287,14 @@ def self_attention(module: nn.Module, x, doc, mask, *, heads: int,
     ``q = RMSNorm(q)``, ``k = RMSNorm(k)`` (``q_norm/scale``,
     ``k_norm/scale``), the rotary embedding where ``rotary_of`` is
     ``(theta, positions)`` (positions ``(P, S)``, or None to count from
-    the sequence's start; None for no rotary). Returns ``(B, S, H * D)``
+    the sequence's start; None for no rotary), over the first
+    ``rotary_dim`` columns of a head (0: all). Returns ``(B, S, H * D)``
     in ``dtype``, named ``attention`` for a ``remat`` policy.
+
+    ``zero_centred`` norms scale by ``1 + w`` (``NormScale``). ``gated``:
+    ``Wq`` gives each head its query and then a gate of as many columns,
+    ``(d, H * 2D)``, and the result is ``attention * sigmoid(gate)``
+    (scope ``gate_out``).
 
     On the kernel's path the norms, rotary, the scale and the kernel's
     layout are one pass each way (``ops/attention_inputs.py``); on the
@@ -255,22 +310,25 @@ def self_attention(module: nn.Module, x, doc, mask, *, heads: int,
                             keys) == "kernel"
     with jax.named_scope("qkv"):
         with jax.named_scope("project"):
-            q = _dot(x, module.param("wq", _init, (d, h * hd), _f32),
-                     dtype).reshape(b, s, h, hd)
+            q = _dot(x, module.param("wq", _init, (d, h * hd * (1 + gated)),
+                                     _f32), dtype).reshape(b, s, h, -1)
+            if gated:
+                q, gate = q[..., :hd], q[..., hd:].reshape(b, s, h * hd)
             k = _dot(x, module.param("wk", _init, (d, kv * hd), _f32),
                      dtype).reshape(b, s, kv, hd)
             v = _dot(x, module.param("wv", _init, (d, kv * hd), _f32),
                      dtype).reshape(b, s, kv, hd)
-        q_scale = NormScale(name="q_norm")(hd)
-        k_scale = NormScale(name="k_norm")(hd)
+        q_scale = NormScale(zero_centred, name="q_norm")(hd)
+        k_scale = NormScale(zero_centred, name="k_norm")(hd)
         with jax.named_scope("prepare"):
             if kernel:
                 q, k, v = attention_inputs(q, k, v, q_scale, k_scale,
-                                           rotary_of, dtype, eps)
+                                           rotary_of, dtype, eps, rotary_dim)
             else:
                 q, k = rms_norm(q, q_scale, eps), rms_norm(k, k_scale, eps)
                 if rotary_of is not None:
-                    q, k = rotary(q, *rotary_of), rotary(k, *rotary_of)
+                    q, k = (rotary(x, *rotary_of, dims=rotary_dim)
+                            for x in (q, k))
     with jax.named_scope("scores"):
         if kernel:
             out = jnp.transpose(heads_first_attention(q, k, v, doc, mask),
@@ -278,7 +336,11 @@ def self_attention(module: nn.Module, x, doc, mask, *, heads: int,
         else:
             out = blocked_attention(q.reshape(b, s, kv, h // kv, hd), k, v,
                                     doc, mask, block, dtype)
-        return checkpoint_name(out.reshape(b, s, h * hd), "attention")
+        out = checkpoint_name(out.reshape(b, s, h * hd), "attention")
+    if gated:
+        with jax.named_scope("gate_out"):
+            out = out * jax.nn.sigmoid(gate)
+    return out
 
 
 # ----------------------------------------------------------------- experts
@@ -293,6 +355,18 @@ def sigmoid_router(x, w_router, bias, top_k: int, *, eps: float,
     _, chosen = jax.lax.top_k(scores + jax.lax.stop_gradient(bias), top_k)
     s = jnp.take_along_axis(scores, chosen, axis=-1)
     return chosen, s / (jnp.sum(s, -1, keepdims=True) + eps) * scale
+
+
+def softmax_router(x, w_router, top_k: int):
+    """``(chosen, weight)``, both ``(N, top_k)``, of ``x`` ``(N, d)``
+    float32 under the router ``w_router`` ``(d, experts_total)``: ``s =
+    softmax(x Wr)`` in float32 over ALL experts, chosen = top-k of ``s``,
+    ``w = s[chosen] / sum s[chosen]``. The product is at ``HIGHEST``, as
+    the sigmoid router's."""
+    scores = jax.nn.softmax(jnp.dot(
+        x, w_router, precision=jax.lax.Precision.HIGHEST), axis=-1)
+    s, chosen = jax.lax.top_k(scores, top_k)
+    return chosen, s / jnp.sum(s, -1, keepdims=True)
 
 
 def balanced_bias(bias, chosen, coeff: float):

@@ -81,6 +81,7 @@ and the clean keys' ``dk`` and ``dv`` laid end to end).
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 import math
 from typing import NamedTuple, Optional, Tuple, Union
@@ -168,6 +169,15 @@ def block_sizes(block_q: int, block_kv: int,
 # Fixed after a sweep on the chip at the cell's shapes (PERF.md section 5).
 # A sequence that these do not divide takes the scan.
 BLOCKS = block_sizes(1024, 1024, 512)
+# Heads wider than the 128 lanes: the fused backward's tile of 1,024
+# queries needs 17.5 MB of VMEM at heads of 256, past the 16 MB Mosaic
+# allows a kernel; half the queries a backward tile, the forward's as above.
+WIDE_BLOCKS = dataclasses.replace(BLOCKS, block_q_dkv=512)
+
+
+def blocks_for(head_dim: int) -> splash.BlockSizes:
+    """The kernel's tiles at heads of ``head_dim``."""
+    return BLOCKS if head_dim <= 128 else WIDE_BLOCKS
 
 
 def attention_path(backend: str, devices: int, head_dim: int,
@@ -235,8 +245,8 @@ def fused_attention(q, k, v, doc, mask: Mask, dtype, *,
     ``1 / sqrt(D)`` goes into ``q`` before it is cast, so ``q`` is rounded
     once; both products take ``dtype`` operands and accumulate in float32;
     maximum, exponentials and sum are float32. ``blocks`` and ``interpret``
-    are for the tests: the first defaults to ``BLOCKS``, the second to any
-    backend but a TPU."""
+    are for the tests: the first defaults to ``blocks_for`` the heads, the
+    second to any backend but a TPU."""
     q, k, v = _heads_first(q, k, v, dtype)
     out = heads_first_attention(q, k, v, doc, mask, blocks=blocks,
                                 interpret=interpret)
@@ -253,7 +263,7 @@ def heads_first_attention(q, k, v, doc, mask: Mask, *,
     prepares them)."""
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    blocks = blocks or BLOCKS
+    blocks = blocks or blocks_for(q.shape[-1])
     if isinstance(mask, BlockDiffusion):
         return _two_parts(q, k, v, doc, mask, blocks, interpret)
     kernel = _kernel(q.shape[3], mask, q.shape[2], blocks, interpret)

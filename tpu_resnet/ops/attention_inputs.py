@@ -15,7 +15,11 @@ then ``rotary``, then ``ops/attention.py::_heads_first``) is, a row of
 sine with the first half's sign turned: ``concatenate([-y2, y1]) * sin``
 of the rotate-half form, to the bit, as one multiply-add.
 ``rotary_table`` computes cosine and sine from the positions once a
-layer, outside both passes.
+layer, outside both passes. A partial rotary (``rotary_dim`` under ``D``)
+rotates the row's first ``rotary_dim`` columns and passes the rest: the
+tables hold 1 and 0 past them, and the turn (``part_turn``) swaps the two
+halves of those columns and gives 0 past them, a permutation that is its
+own transpose.
 
 Forward, each projection is read once and written once, ``q`` as ``(B,
 KV, G, S, D)`` and ``k`` as ``(B, KV, S, D)``: the two rounding points are
@@ -67,26 +71,49 @@ TILE = 1024        # positions a step of the kernels
 
 
 def rotary_table(theta: float, positions: Optional[jax.Array], seq_len: int,
-                 head_dim: int) -> Tuple[jax.Array, jax.Array]:
+                 head_dim: int, rot: int = 0) -> Tuple[jax.Array, jax.Array]:
     """``(cos, sin_signed)``, both ``(P, S, D)`` float32, of ``positions``
     ``(P, S)`` (``P`` 1 or the batch; None counts from the sequence's
     start), computed as ``models/transformer.py::rotary`` computes its
     tables: the cosine over both halves, the sine with the first half's
-    sign turned."""
-    inv = 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=_f32)
-                           / head_dim))
+    sign turned. With ``rot`` (0: the whole head) the tables are of the
+    first ``rot`` columns, and 1 and 0 past them."""
+    width = rot or head_dim
+    inv = 1.0 / (theta ** (jnp.arange(0, width, 2, dtype=_f32) / width))
     if positions is None:
         positions = jnp.arange(seq_len)[None]
     ang = positions.astype(_f32)[:, :, None] * inv
     cos, sin = jnp.cos(ang), jnp.sin(ang)
-    return (jnp.concatenate([cos, cos], -1),
-            jnp.concatenate([-sin, sin], -1))
+    cos, sin = (jnp.concatenate([cos, cos], -1),
+                jnp.concatenate([-sin, sin], -1))
+    if width == head_dim:
+        return cos, sin
+    rest = ang.shape[:2] + (head_dim - width,)
+    return (jnp.concatenate([cos, jnp.ones(rest, _f32)], -1),
+            jnp.concatenate([sin, jnp.zeros(rest, _f32)], -1))
 
 
 def half_turn(x):
     """The two halves of the last axis swapped."""
     d = x.shape[-1] // 2
     return jnp.concatenate([x[..., d:], x[..., :d]], -1)
+
+
+def _jnp_roll(x, shift):
+    return jnp.roll(x, shift, axis=x.ndim - 1)
+
+
+def part_turn(x, roll=_jnp_roll, *, rot: int):
+    """The two halves of the first ``rot`` columns of the last axis
+    swapped, 0 past them: two rolls of the row, each column taking the
+    one whose source is its partner (a rolled column index says which,
+    whichever way ``roll`` turns)."""
+    d, half = x.shape[-1], rot // 2
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, x.ndim - 1)
+    partner = jnp.where(lane < half, lane + half, lane - half)
+    ahead = roll(lane, half) == partner
+    return jnp.where(lane < rot, jnp.where(ahead, roll(x, half),
+                                           roll(x, d - half)), 0.0)
 
 
 def _norm(x, eps: float):
@@ -126,18 +153,30 @@ def _roll_half(y):
     return pltpu.roll(y, y.shape[-1] // 2, axis=y.ndim - 1)
 
 
-def _fwd_kernel(*refs, eps, factor, rotary):
+def _lane_roll(y, shift):
+    return pltpu.roll(y, shift, axis=y.ndim - 1)
+
+
+def _turn(rot: int, whole, roll):
+    """The turn of a row: ``whole`` for a rotary over the whole head
+    (``rot`` 0), ``part_turn`` by ``roll`` for a partial one."""
+    return functools.partial(part_turn, roll=roll, rot=rot) if rot else whole
+
+
+def _fwd_kernel(*refs, eps, factor, rotary, rot):
     x_ref, scale_ref, *table, out_ref = refs
     cos, sin = (t[0] for t in table) if rotary else (None, None)
-    out_ref[0, 0] = _prepare(x_ref[0], scale_ref[...], cos, sin, _roll_half,
-                             eps, factor).astype(out_ref.dtype)
+    out_ref[0, 0] = _prepare(x_ref[0], scale_ref[...], cos, sin,
+                             _turn(rot, _roll_half, _lane_roll), eps,
+                             factor).astype(out_ref.dtype)
 
 
-def _bwd_kernel(*refs, eps, factor, rotary):
+def _bwd_kernel(*refs, eps, factor, rotary, rot):
     x_ref, g_ref, scale_ref, *table, dx_ref, d_scale_ref = refs
     cos, sin = (t[0] for t in table) if rotary else (None, None)
     dx, d_scale = _transposed(x_ref[0], g_ref[0, 0], scale_ref[...], cos,
-                              sin, _roll_half, eps, factor)
+                              sin, _turn(rot, _roll_half, _lane_roll), eps,
+                              factor)
     dx_ref[0] = dx.astype(dx_ref.dtype)
     d_scale_ref[...] = d_scale.reshape(d_scale_ref.shape)
 
@@ -160,12 +199,12 @@ def _specs(x, table):
     return (b, s // t, h), rows, heads, scale, tables
 
 
-def _kernel_forward(x, scale, table, *, eps, factor, dtype, interpret):
+def _kernel_forward(x, scale, table, *, eps, factor, rot, dtype, interpret):
     b, s, h, d = x.shape
     grid, rows, heads, scale_spec, tables = _specs(x, table)
     return pl.pallas_call(
         functools.partial(_fwd_kernel, eps=eps, factor=factor,
-                          rotary=table is not None),
+                          rotary=table is not None, rot=rot),
         grid=grid, in_specs=[rows, scale_spec, *tables], out_specs=heads,
         out_shape=jax.ShapeDtypeStruct((b, h, s, d), dtype),
         compiler_params=pltpu.CompilerParams(
@@ -175,12 +214,12 @@ def _kernel_forward(x, scale, table, *, eps, factor, dtype, interpret):
       *(table if table is not None else ()))
 
 
-def _kernel_backward(x, scale, table, g, *, eps, factor, interpret):
+def _kernel_backward(x, scale, table, g, *, eps, factor, rot, interpret):
     b, s, h, d = x.shape
     grid, rows, heads, scale_spec, tables = _specs(x, table)
     dx, d_scale = pl.pallas_call(
         functools.partial(_bwd_kernel, eps=eps, factor=factor,
-                          rotary=table is not None),
+                          rotary=table is not None, rot=rot),
         grid=grid, in_specs=[rows, heads, scale_spec, *tables],
         out_specs=[rows, pl.BlockSpec((1, 1, 1, 1, d),
                                       lambda i, j, k: (i, j, k, 0, 0))],
@@ -199,31 +238,33 @@ def _rows(table):
     return (None, None) if table is None else (t[:, :, None] for t in table)
 
 
-@functools.partial(jax.jit, static_argnames=("eps", "factor", "kv", "dtype",
-                                             "kernel", "interpret"))
-def _forward(x, scale, table, *, eps, factor, kv, dtype, kernel, interpret):
+@functools.partial(jax.jit, static_argnames=("eps", "factor", "rot", "kv",
+                                             "dtype", "kernel", "interpret"))
+def _forward(x, scale, table, *, eps, factor, rot, kv, dtype, kernel,
+             interpret):
     """The prepared projection as ``(B, KV, H / KV, S, D)``."""
     b, s, h, d = x.shape
     if kernel:
         out = _kernel_forward(x, scale, table, eps=eps, factor=factor,
-                              dtype=dtype, interpret=interpret)
+                              rot=rot, dtype=dtype, interpret=interpret)
         return out.reshape(b, kv, h // kv, s, d)
-    y = _prepare(x, scale, *_rows(table), half_turn, eps, factor)
+    y = _prepare(x, scale, *_rows(table), _turn(rot, half_turn, _jnp_roll),
+                 eps, factor)
     return jnp.transpose(y.astype(dtype).reshape(b, s, kv, h // kv, d),
                          (0, 2, 3, 1, 4))
 
 
-@functools.partial(jax.jit, static_argnames=("eps", "factor", "kernel",
+@functools.partial(jax.jit, static_argnames=("eps", "factor", "rot", "kernel",
                                              "interpret"))
-def _backward(x, scale, table, g, *, eps, factor, kernel, interpret):
+def _backward(x, scale, table, g, *, eps, factor, rot, kernel, interpret):
     """The projection's cotangent in its dtype and the scale's float32
     gradient, from the cotangent ``g`` of the prepared projection."""
     if kernel:
         return _kernel_backward(x, scale, table, g, eps=eps, factor=factor,
-                                interpret=interpret)
+                                rot=rot, interpret=interpret)
     g = jnp.transpose(g, (0, 3, 1, 2, 4)).reshape(x.shape)
-    dx, d_scale = _transposed(x, g, scale, *_rows(table), half_turn, eps,
-                              factor)
+    dx, d_scale = _transposed(x, g, scale, *_rows(table),
+                              _turn(rot, half_turn, _jnp_roll), eps, factor)
     return dx.astype(x.dtype), d_scale
 
 
@@ -235,40 +276,44 @@ def _path(x):
                 interpret=jax.default_backend() != "tpu")
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _prepared(x, scale, table, eps, factor, kv, dtype):
-    return _forward(x, scale, table, eps=eps, factor=factor, kv=kv,
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _prepared(x, scale, table, eps, factor, rot, kv, dtype):
+    return _forward(x, scale, table, eps=eps, factor=factor, rot=rot, kv=kv,
                     dtype=dtype, **_path(x))
 
 
-def _prepared_fwd(x, scale, table, eps, factor, kv, dtype):
-    out = _forward(x, scale, table, eps=eps, factor=factor, kv=kv,
+def _prepared_fwd(x, scale, table, eps, factor, rot, kv, dtype):
+    out = _forward(x, scale, table, eps=eps, factor=factor, rot=rot, kv=kv,
                    dtype=dtype, **_path(x))
     return out, (x, scale, table)
 
 
-def _prepared_bwd(eps, factor, kv, dtype, residuals, g):
+def _prepared_bwd(eps, factor, rot, kv, dtype, residuals, g):
     x, scale, table = residuals
     dx, d_scale = _backward(x, scale, table, g, eps=eps, factor=factor,
-                            **_path(x))
+                            rot=rot, **_path(x))
     return dx, d_scale, None
 
 
 _prepared.defvjp(_prepared_fwd, _prepared_bwd)
 
 
-def attention_inputs(q, k, v, q_scale, k_scale, rotary, dtype, eps: float):
+def attention_inputs(q, k, v, q_scale, k_scale, rotary, dtype, eps: float,
+                     rotary_dim: int = 0):
     """``q`` ``(B, S, H, D)`` and ``k``, ``v`` ``(B, S, KV, D)``, the
     projections, as the fused kernel takes them: ``q`` normed, rotated,
     scaled by ``1 / sqrt(D)`` and cast to ``dtype`` as ``(B, KV, H / KV,
     S, D)``; ``k`` normed, rotated and cast as ``(B, KV, S, D)``; ``v``
     cast as ``(B, KV, S, D)`` (the module docstring). ``q_scale`` and
     ``k_scale`` are the norms' ``(D,)`` float32 weights, ``rotary`` None or
-    ``(theta, positions)`` with ``positions`` ``(P, S)`` or None."""
+    ``(theta, positions)`` with ``positions`` ``(P, S)`` or None, over the
+    first ``rotary_dim`` columns of a head (0: all)."""
     b, s, h, d = q.shape
     kv = k.shape[2]
-    table = None if rotary is None else rotary_table(*rotary, s, d)
-    return (_prepared(q, q_scale, table, eps, 1.0 / math.sqrt(d), kv, dtype),
-            _prepared(k, k_scale, table, eps, 1.0, kv, dtype).reshape(
+    rot = rotary_dim if 0 < rotary_dim < d else 0
+    table = None if rotary is None else rotary_table(*rotary, s, d, rot)
+    return (_prepared(q, q_scale, table, eps, 1.0 / math.sqrt(d), rot, kv,
+                      dtype),
+            _prepared(k, k_scale, table, eps, 1.0, rot, kv, dtype).reshape(
                 b, kv, s, d),
             jnp.transpose(v.astype(dtype), (0, 2, 1, 3)))
