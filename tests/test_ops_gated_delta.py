@@ -1,14 +1,20 @@
 """Gated DeltaNet's recurrence in the chunked form (ops/gated_delta.py):
-the kernels under Pallas' interpreter and the scan, each against the
-recurrence written out token by token, forward and every input's
-gradient, on the CPU at a tiny size (2 sequences of 32 or 40, 2 key heads
-and 4 value heads of 16, chunks of 8); and the kernels compiled for a
-described v5e at the cell's shapes."""
+the kernels under Pallas' interpreter (under ``jax.checkpoint`` too, the
+inverses kept) and the scan, each against the recurrence written out token
+by token, forward and every input's gradient, on the CPU at a tiny size (2
+sequences of 32 or 40, 2 key heads and 4 value heads of 16, chunks of 8);
+the inverse kernel against the function it runs, to the bit; which kernel
+holds the float32 products; and the kernels compiled for a described v5e at
+the cell's shapes."""
+
+import math
+import re
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.extend import core
 
 from tpu_resnet.ops import gated_delta
 
@@ -67,14 +73,22 @@ CASES = {
 }
 
 
-@pytest.mark.parametrize("path", ["scan", "kernel"])
+@pytest.mark.parametrize("path", ["scan", "kernel", "kernel_remat"])
 @pytest.mark.parametrize("case", sorted(CASES))
 def test_chunked_form_is_the_recurrence_token_by_token(path, case):
+    """``kernel_remat``: the kernels under ``jax.checkpoint`` keeping only
+    the inverses, as the model's remat does; the backward pass runs the
+    forward kernel again on the kept inverses."""
     q, k, v, beta, g, reset = inputs(**CASES[case])
 
     def run(q, k, v, beta, g):
         return gated_delta.gated_delta(q, k, v, beta, g, reset,
-                                       dtype=jnp.float32, chunk=C, path=path)
+                                       dtype=jnp.float32, chunk=C,
+                                       path=path.split("_")[0])
+
+    if path == "kernel_remat":
+        run = jax.checkpoint(run, policy=jax.checkpoint_policies
+                             .save_only_these_names(gated_delta.INVERSE))
 
     def want(q, k, v, beta, g):
         return token_by_token(q, k, v, beta, g, reset)
@@ -92,6 +106,96 @@ def test_chunked_form_is_the_recurrence_token_by_token(path, case):
         assert bool(jnp.all(jnp.isfinite(a))), name
         np.testing.assert_allclose(a, b, atol=5e-5 * float(jnp.max(
             jnp.abs(b))), err_msg=name)
+
+
+@pytest.mark.parametrize("units", [1, 2, 4])
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_the_inverse_kernel_is_the_unit_lower_inverse_to_the_bit(case,
+                                                                 units):
+    """``gated_delta_fwd_inverse``, ``units`` chunks a grid step, writes for
+    every sequence, value head and chunk the bf16 cast of
+    ``_unit_lower_inverse`` of ``N`` from ``_masks``, bit for bit."""
+    q, k, v, beta, g, reset = inputs(**CASES[case])
+    got = np.asarray(gated_delta.inverses(k, beta, g, reset,
+                                          dtype=jnp.bfloat16, chunk=C,
+                                          units=units))
+    b, length, hv = beta.shape
+    assert got.shape == (b, hv, length // C, C, C)
+
+    def chunked(x):
+        return jnp.cumsum(x.reshape(b, length // C, C, *x.shape[2:]),
+                          axis=2).reshape(x.shape)
+
+    g_cum, r_cum = chunked(g), chunked(reset.astype(jnp.float32))
+    kb = k.astype(jnp.bfloat16)
+
+    @jax.jit
+    def want(k, beta, gc, rc):
+        _, strict, gam, *_ = gated_delta._masks(gc[:, None], gc[None, :],
+                                                rc[:, None], rc[None, :], D)
+        kk = gated_delta._mm(k, k, gated_delta._NT, jnp.bfloat16)
+        return gated_delta._unit_lower_inverse(beta[:, None] * jnp.where(
+            strict, kk * gam, 0.0)).astype(jnp.bfloat16)
+
+    for i in range(b):
+        for h in range(hv):
+            for c in range(length // C):
+                at = slice(c * C, (c + 1) * C)
+                np.testing.assert_array_equal(
+                    got[i, h, c], want(kb[i, at, h // (HV // HK)],
+                                       beta[i, at, h], g_cum[i, at, h],
+                                       r_cum[i, at]),
+                    err_msg=f"sequence {i}, head {h}, chunk {c}")
+
+
+def _eqns(jaxpr):
+    """Every equation under ``jaxpr``, nested jaxprs included."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for v in eqn.params.values():
+            for x in v if isinstance(v, (list, tuple)) else [v]:
+                if isinstance(x, (core.Jaxpr, core.ClosedJaxpr)):
+                    yield from _eqns(getattr(x, "jaxpr", x))
+
+
+def _kernel_bodies(jaxpr):
+    """``{name: [the precision of each product in its body]}`` of every
+    ``pallas_call`` under ``jaxpr``."""
+    found = {}
+    for eqn in _eqns(jaxpr):
+        if eqn.primitive.name == "pallas_call":
+            found.setdefault(eqn.params["name"], []).extend(
+                e.params["precision"] for e in _eqns(eqn.params["jaxpr"])
+                if e.primitive.name == "dot_general")
+    return found
+
+
+def test_only_the_inverse_kernel_holds_float32_products():
+    """Forward and backward, the step holds one call of each kernel; the
+    products at ``HIGHEST`` (the inverse's) are the inverse kernel's alone:
+    the forward and the backward kernel read the inverse it wrote."""
+    q, k, v, beta, g, reset = inputs(starts=[(0, 5)])
+
+    def loss(q, k, v, beta, g):
+        return jnp.sum(gated_delta.gated_delta(q, k, v, beta, g, reset,
+                                               dtype=jnp.bfloat16, chunk=C,
+                                               path="kernel"))
+
+    found = _kernel_bodies(jax.make_jaxpr(jax.grad(
+        loss, argnums=(0, 1, 2, 3, 4)))(q, k, v, beta, g).jaxpr)
+    assert set(found) == {"gated_delta_fwd_inverse", "gated_delta_fwd",
+                          "gated_delta_bwd"}
+    top = (jax.lax.Precision.HIGHEST,) * 2
+
+    def highest(name):
+        return sum(p == top for p in found[name])
+
+    # log2(8) - 1 = 2 squarings, two products each, for each chunk of a
+    # grid step (the module's units, of the 4 chunks)
+    units = math.gcd(gated_delta.UNITS, 32 // C)
+    assert highest("gated_delta_fwd_inverse") == 4 * units
+    assert highest("gated_delta_fwd") == highest("gated_delta_bwd") == 0
+    assert len(found["gated_delta_fwd"]) == 6       # K K^T no longer among them
 
 
 def test_a_document_begun_mid_chunk_reads_nothing_of_the_one_before():
@@ -151,11 +255,11 @@ def one_chip():
 
 def test_the_kernels_compile_for_a_v5e_at_the_cells_shapes(one_chip,
                                                            monkeypatch):
-    """Mosaic takes the forward and the backward kernel at 2 x 4,096
-    positions, 16 key and 32 value heads of 128, the module's chunk (what
-    interpret mode cannot show: layouts, broadcasts, VMEM). A compile, not
-    a run; the chip's backend is named in the test, as a chip would name
-    it."""
+    """Mosaic takes the inverse, the forward and the backward kernel at 2 x
+    4,096 positions, 16 key and 32 value heads of 128, the module's chunk
+    (what interpret mode cannot show: layouts, broadcasts, VMEM). A
+    compile, not a run; the chip's backend is named in the test, as a chip
+    would name it."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     def shaped(*shape, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
@@ -170,4 +274,6 @@ def test_the_kernels_compile_for_a_v5e_at_the_cells_shapes(one_chip,
         shaped(2, 4096, 32, 128), shaped(2, 4096, 32, dtype=jnp.float32),
         shaped(2, 4096, 32, dtype=jnp.float32),
         shaped(2, 4096, dtype=jnp.bool_)).compile().as_text()
-    assert "gated_delta_fwd" in text and "gated_delta_bwd" in text
+    for name in ("gated_delta_fwd_inverse", "gated_delta_fwd",
+                 "gated_delta_bwd"):
+        assert re.search(rf"\b{name}\b", text), name

@@ -12,10 +12,13 @@ import json
 import math
 import os
 
+import collections
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.extend import core
 
 from benchmarks.families import qwen3_next as family
 from benchmarks.lib.harness import flat
@@ -152,6 +155,66 @@ def test_remat_keeps_the_gradients():
 
     plain, remat = grads(ARCH), grads(dataclasses.replace(ARCH, remat=True))
     assert worst(flat(remat), flat(plain)) < 1e-6
+
+
+def kernel_calls(jaxpr):
+    """How often each ``pallas_call`` stands in ``jaxpr``, nested jaxprs
+    included (a remat's recomputation among them)."""
+    calls = collections.Counter()
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            calls[eqn.params["name"]] += 1
+        for v in eqn.params.values():
+            for x in v if isinstance(v, (list, tuple)) else [v]:
+                if isinstance(x, (core.Jaxpr, core.ClosedJaxpr)):
+                    calls += kernel_calls(getattr(x, "jaxpr", x))
+    return calls
+
+
+def test_remat_on_the_kernel_path_computes_each_inverse_once(monkeypatch):
+    """The tiny preset's gradient step (``model.remat`` on) holds one
+    inverse kernel a DeltaNet layer: remat keeps the inverses and runs the
+    forward kernel again, not the inverse's. Its gradients are those of
+    the step without remat."""
+    monkeypatch.setattr(gated_delta, "recurrence_path",
+                        lambda *_: "kernel")
+    cfg = load_config("qwen3_next_80b_a3b_ep16", overrides=TINY)
+    assert cfg.model.remat
+    model = build_model(cfg)
+    schedule = sched_lib.build_schedule(cfg.optim, cfg.train)
+    state = init_state(model, cfg.optim, schedule, jax.random.PRNGKey(3),
+                       sample_input(cfg))
+    step = make_train_step(model, cfg.optim, schedule, cfg.data.num_classes)
+    calls = kernel_calls(jax.make_jaxpr(step)(state, *tokens(batch=8)).jaxpr)
+    layers = LAYERS.count("linear")
+    assert calls["gated_delta_fwd_inverse"] == layers
+    assert calls["gated_delta_fwd"] == 2 * layers
+    assert calls["gated_delta_bwd"] == layers
+    params = weights()
+    x, y = tokens()
+
+    def grads(arch):
+        return jax.grad(lambda p: token_xent(Qwen3Next(arch).apply(
+            {"params": p}, x, mutable=["counters"])[0], y))(params)
+
+    plain, remat = grads(ARCH), grads(dataclasses.replace(ARCH, remat=True))
+    assert worst(flat(remat), flat(plain)) < 1e-6
+
+
+def test_only_the_deltanet_mixer_keeps_the_inverses():
+    """The mixer's remat policy is ``_KEEP`` and the inverses by name; the
+    other three token families remat under ``_KEEP`` itself, which saves no
+    such name, so their steps lower as they did."""
+    from tpu_resnet.models import afmoe, lfm2_moe, sdar_moe
+
+    assert afmoe._KEEP is lfm2_moe._KEEP is sdar_moe._KEEP \
+        is transformer._KEEP
+    eqn = jax.make_jaxpr(lambda x: jax.ad_checkpoint.checkpoint_name(
+        x, gated_delta.INVERSE))(jnp.ones(3)).eqns[0]
+    kept = [policy(eqn.primitive, *[v.aval for v in eqn.invars],
+                   **eqn.params)
+            for policy in (transformer._KEEP, qwen3_next._MIXER_KEEP)]
+    assert kept == [False, True]
 
 
 def test_the_shares_add_up_to_the_uncut_layer():
@@ -483,13 +546,15 @@ def test_startup_events_name_every_layers_mixer_and_paths():
         "qwen3_next_80b_a3b_ep16", overrides=TINY))
     assert [r["mixer"] for r in events["token_mixers"]["layers"]] == [
         "gated_delta"] * 3 + ["attention"]
-    assert [r["path"] for r in events["recurrence_path"]["layers"]] == [
-        "scan"] * 3
+    assert [(r["path"], r["inverse"])
+            for r in events["recurrence_path"]["layers"]] == [
+        ("scan", "in_chunk")] * 3
     (row,) = events["attention_path"]["layers"]
     assert row["layer"] == 3 and row["path"] == "scan"
     big = Arch(layers=LAYERS)
     assert qwen3_next.recurrence_paths(big, 4096, "tpu", 1)[0] == dict(
-        layer=0, kind="linear", path="kernel", chunk=128, chunks=32)
+        layer=0, kind="linear", path="kernel", chunk=128, chunks=32,
+        inverse="once")
     assert qwen3_next.attention_paths(big, 4096, "tpu", 1)[0]["path"] == \
         "kernel"
 

@@ -1,15 +1,19 @@
 """Time Gated DeltaNet's recurrence kernels (tpu_resnet/ops/gated_delta.py)
 on one TPU chip at the benchmark cell's shapes (2 sequences of 4,096, 16
-key and 32 value heads of 128, bf16) for each chunk length given, forward
-alone and forward with backward, and print one JSON line per chunk:
+key and 32 value heads of 128, bf16) for each chunk length given: the
+inverse kernel alone at each number of units a grid step given
+(``inverse_ms``, by units), forward alone and forward with backward, and
+print one JSON line per chunk:
 
-    python tools/gated_delta_sweep.py --chunk 64 --chunk 128 --chunk 256
+    python tools/gated_delta_sweep.py --chunk 64 --chunk 128 --chunk 256 \
+        --units 1 --units 2 --units 4
 
 Each time is the median of ``--reps`` calls that end in
 ``block_until_ready``, after one call that compiles. A run off the TPU
 exits non-zero: the kernels' times exist only on the chip."""
 
 import argparse
+import functools
 import json
 import os
 import statistics
@@ -23,6 +27,9 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
 def main(argv=None) -> int:
     p = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     p.add_argument("--chunk", type=int, action="append")
+    p.add_argument("--units", type=int, action="append",
+                   help="(chunk, value head) units a grid step of the "
+                        "inverse kernel (default: the module's UNITS)")
     p.add_argument("--reps", type=int, default=20)
     p.add_argument("--out", default="")
     args = p.parse_args(argv)
@@ -46,6 +53,16 @@ def main(argv=None) -> int:
     g = -jax.random.uniform(keys[4], (b, s, hv), maxval=3.0)
     reset = jax.random.uniform(keys[5], (b, s)) < 1 / 600
     q, k = q.astype(jnp.bfloat16), k.astype(jnp.bfloat16)
+
+    def median_ms(f, *a):
+        jax.block_until_ready(f(*a))
+        times = []
+        for _ in range(args.reps):
+            t0 = time.perf_counter()
+            jax.block_until_ready(f(*a))
+            times.append(1e3 * (time.perf_counter() - t0))
+        return statistics.median(times)
+
     rows = []
     for chunk in args.chunk or [gated_delta.CHUNK]:
         def fwd(q, k, v, beta, g):
@@ -55,15 +72,14 @@ def main(argv=None) -> int:
         both = jax.jit(jax.value_and_grad(
             lambda *a: jnp.sum(fwd(*a).astype(jnp.float32)),
             argnums=(0, 1, 2, 3, 4)))
-        row = {"chunk": chunk}
+        row = {"chunk": chunk, "inverse_ms": {}}
+        for units in args.units or [gated_delta.UNITS]:
+            inverse = jax.jit(functools.partial(
+                gated_delta.inverses, reset=reset, dtype=jnp.bfloat16,
+                chunk=chunk, units=units))
+            row["inverse_ms"][units] = median_ms(inverse, k, beta, g)
         for name, f in (("fwd_ms", jax.jit(fwd)), ("fwd_bwd_ms", both)):
-            jax.block_until_ready(f(q, k, v, beta, g))
-            times = []
-            for _ in range(args.reps):
-                t0 = time.perf_counter()
-                jax.block_until_ready(f(q, k, v, beta, g))
-                times.append(1e3 * (time.perf_counter() - t0))
-            row[name] = statistics.median(times)
+            row[name] = median_ms(f, q, k, v, beta, g)
         row["device"] = jax.devices()[0].device_kind
         rows.append(row)
         print(json.dumps(row), flush=True)

@@ -71,6 +71,11 @@ from tpu_resnet.ops.attention import attention_path, key_blocks
 
 LAYER_KINDS = ("linear", "full")
 COUNTERS = transformer.COUNTERS + ("gdn_doc_chunks_frac",)
+# What remat keeps of a DeltaNet mixer: ``_KEEP``'s, and the recurrence's
+# inverses, so that its backward runs the forward kernel again and not the
+# inverse's.
+_MIXER_KEEP = jax.checkpoint_policies.save_from_both_policies(
+    _KEEP, jax.checkpoint_policies.save_only_these_names(gated_delta.INVERSE))
 
 
 class Norm(nn.Module):
@@ -194,7 +199,7 @@ class Layer(nn.Module):
         m = self.arch
         x = Norm(m.eps, name="input_norm")(h)
         if self.kind == "linear":
-            mixer = nn.remat(GatedDeltaNet, policy=_KEEP) if m.remat \
+            mixer = nn.remat(GatedDeltaNet, policy=_MIXER_KEEP) if m.remat \
                 else GatedDeltaNet
             with jax.named_scope("gdn"):
                 h = h + mixer(m, name="linear_attn")(x, doc, reset)
@@ -235,7 +240,7 @@ class Arch:
     rows_slack: float = 2.0            # transformer.py::buffer_rows
     attn_block: int = 256              # queries a block of the scan path
     chunk: int = gated_delta.CHUNK     # positions a chunk of the recurrence
-    remat: bool = False                # a DeltaNet mixer's backward keeps _KEEP
+    remat: bool = False                # a DeltaNet mixer's keeps _MIXER_KEEP
     dtype: Any = jnp.bfloat16
 
     def __post_init__(self):
@@ -299,12 +304,14 @@ def token_mixers(model: Arch) -> List[Dict[str, object]]:
 def recurrence_paths(model: Arch, seq_len: int, backend: str,
                      devices: int) -> List[Dict[str, object]]:
     """For each DeltaNet layer, the ``path`` its recurrence takes here
-    (``ops/gated_delta.py::recurrence_path``), its ``chunk`` and the chunks
-    of a sequence. What ``train()`` says once, as the event
-    ``recurrence_path``."""
+    (``ops/gated_delta.py::recurrence_path``), its ``chunk``, the chunks of
+    a sequence, and where its UT inverse is computed: ``once`` a layer and
+    step by its own kernel on the kernel path, ``in_chunk`` on the scan's.
+    What ``train()`` says once, as the event ``recurrence_path``."""
     path = gated_delta.recurrence_path(backend, devices)
     return [dict(layer=i, kind=kind, path=path, chunk=model.chunk,
-                 chunks=seq_len // model.chunk)
+                 chunks=seq_len // model.chunk,
+                 inverse="once" if path == "kernel" else "in_chunk")
             for i, kind in enumerate(model.layers) if kind == "linear"]
 
 
