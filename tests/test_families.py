@@ -120,7 +120,7 @@ def test_a_family_is_fed_by_its_kind_of_data_only(toy, preset, overrides,
     said = str(err.value)
     assert repr(model) in said and repr(dataset) in said
     assert ("feeds model 'afmoe', 'lfm2_moe', 'qwen3_next', 'sdar_moe', "
-            "'toy_tokens'") in said
+            "'smallthinker', 'toy_tokens'") in said
     assert "feeds model 'mlp', 'resnet'" in said
 
 

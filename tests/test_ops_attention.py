@@ -232,19 +232,22 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-@pytest.mark.parametrize("batch, mask, heads", [
-    (2, 2048, (4, 8, 128)), (2, 0, (4, 8, 128)),
-    (1, attention.BlockDiffusion(4096, 4), (4, 8, 128)),
-    (2, 0, (8, 4, 64))],
-    ids=["sliding", "full", "block_diffusion", "full_heads_of_64"])
-def test_the_cells_kernels_compile_for_a_v5e(one_chip, batch, mask, heads):
+@pytest.mark.parametrize("batch, mask, heads, seq", [
+    (2, 2048, (4, 8, 128), 4096), (2, 0, (4, 8, 128), 4096),
+    (1, attention.BlockDiffusion(4096, 4), (4, 8, 128), 8192),
+    (2, 0, (8, 4, 64), 4096), (1, 4096, (4, 7, 128), 16384)],
+    ids=["sliding", "full", "block_diffusion", "full_heads_of_64",
+         "window_16k_groups_of_7"])
+def test_the_cells_kernels_compile_for_a_v5e(one_chip, batch, mask, heads,
+                                             seq):
     """Mosaic takes the forward and the backward kernel at the cells'
     shapes and the blocks fixed in the module (what interpret mode cannot
     show: tiling, VMEM); under the three-part mask the kernel over the
     clean keys with its bound a row, beside the diagonal; at heads of 64
-    the kernel as it is, nothing padded. A compile, not a run."""
+    the kernel as it is, nothing padded; at 16,384 positions a window of
+    4,096 over groups of 7 query heads. A compile, not a run."""
     kv, g, d = heads
-    s = 4096 if isinstance(mask, int) else 2 * mask.clean_len
+    s = seq
 
     def shaped(*shape, dtype=jnp.float32):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
@@ -262,16 +265,20 @@ def test_the_cells_kernels_compile_for_a_v5e(one_chip, batch, mask, heads):
     assert "splash_mqa_dkv_segmented_no_residuals" in text
 
 
-@pytest.mark.parametrize("batch, seq, heads, rotary", [
-    (1, 8192, (32, 4), "by_ids"), (2, 4096, (32, 4), "from_start"),
-    (2, 4096, (32, 4), None)],
-    ids=["block_diffusion", "sliding", "full"])
+@pytest.mark.parametrize("batch, seq, heads, rotary, normed", [
+    (1, 8192, (32, 4), "by_ids", True),
+    (2, 4096, (32, 4), "from_start", True), (2, 4096, (32, 4), None, True),
+    (1, 16384, (28, 4), "from_start", False),
+    (1, 16384, (28, 4), None, False)],
+    ids=["block_diffusion", "sliding", "full", "window_16k_no_norm",
+         "global_16k_no_norm"])
 def test_the_attention_inputs_kernels_compile_for_a_v5e(
-        one_chip, monkeypatch, batch, seq, heads, rotary):
+        one_chip, monkeypatch, batch, seq, heads, rotary, normed):
     """Mosaic takes the pass that prepares attention's inputs
     (``ops/attention_inputs.py``), forward and backward, at the cells'
     shapes: the projections as the products write them, rotary by position
-    ids, from the sequence's start, or none. A compile, not a run; the
+    ids, from the sequence's start, or none; with the q/k norms or, at
+    groups of 7 and 16,384 positions, without. A compile, not a run; the
     chip's backend is named in the test, as a chip would name it."""
     from tpu_resnet.ops.attention_inputs import attention_inputs
 
@@ -286,8 +293,8 @@ def test_the_attention_inputs_kernels_compile_for_a_v5e(
                None: None}[rotary]
         outs = attention_inputs(
             q.reshape(batch, seq, h, 128), k.reshape(batch, seq, kv, 128),
-            v.reshape(batch, seq, kv, 128), q_scale, k_scale, rot,
-            jnp.bfloat16, 1e-6)
+            v.reshape(batch, seq, kv, 128), q_scale if normed else None,
+            k_scale if normed else None, rot, jnp.bfloat16, 1e-6)
         return sum(jnp.sum(o.astype(jnp.float32)) for o in outs)
 
     text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4))).lower(

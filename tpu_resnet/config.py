@@ -165,7 +165,7 @@ class ModelConfig:
 
     # resnet | mlp | afmoe (its fields: AfmoeConfig) | sdar_moe
     # (SdarMoeConfig) | lfm2_moe (Lfm2MoeConfig) | qwen3_next
-    # (Qwen3NextConfig)
+    # (Qwen3NextConfig) | smallthinker (SmallThinkerConfig)
     name: str = "resnet"
     resnet_size: int = 50
     width_multiplier: int = 1
@@ -328,6 +328,31 @@ class Qwen3NextConfig:
     experts_held: int = 32     # experts_first .. experts_first + held
     top_k: int = 10
     rope_theta: float = 1e7
+    rms_eps: float = 1e-6
+
+
+@dataclasses.dataclass
+class SmallThinkerConfig:
+    """The fields of ``model.name=smallthinker`` (the family's module has
+    the equations, at ``Arch``): a decoder whose layers route each token
+    before attention, attend over a window with rotary or globally with no
+    position, and sum ReGLU experts, as one chip of an expert-parallel
+    group holds it. The defaults are the published widths of the preset's
+    source; the rows of the vocabulary held here are ``data.vocab_size``."""
+
+    # each layer's attention, in order: window (rotary) | global (NoPE)
+    layers: tuple = ("global", "window", "window", "window")
+    hidden: int = 2560
+    heads: int = 28
+    kv_heads: int = 4
+    head_dim: int = 128
+    window: int = 4096         # sliding_window_size
+    expert_width: int = 768    # moe_ffn_hidden_size
+    experts_total: int = 64    # the router's width
+    experts_first: int = 0     # the routed experts this chip holds:
+    experts_held: int = 8      # experts_first .. experts_first + held
+    top_k: int = 6             # moe_num_active_primary_experts
+    rope_theta: float = 1.5e6
     rms_eps: float = 1e-6
 
 
@@ -847,6 +872,8 @@ class RunConfig:
         default_factory=Lfm2MoeConfig)
     qwen3_next: Qwen3NextConfig = dataclasses.field(
         default_factory=Qwen3NextConfig)
+    smallthinker: SmallThinkerConfig = dataclasses.field(
+        default_factory=SmallThinkerConfig)
     optim: OptimConfig = dataclasses.field(default_factory=OptimConfig)
     mesh: MeshConfig = dataclasses.field(default_factory=MeshConfig)
     train: TrainConfig = dataclasses.field(default_factory=TrainConfig)
@@ -1042,6 +1069,25 @@ def _qwen3_next_80b_a3b_ep16() -> RunConfig:
     return cfg
 
 
+def _smallthinker_21b_a3b_ep8() -> RunConfig:
+    """SmallThinker-21BA3B-Instruct (PowerInfer, ``smallthinker``) as one
+    of eight chips that share each layer: 8 of the 64 routed experts and an
+    eighth of the vocabulary held here, published layers 0-3 (global, then
+    three window layers: one period), one sequence of its whole context of
+    16,384 positions a step; next-token cross-entropy, AdamW as the other
+    token presets."""
+    cfg = _trinity_mini_ep16()
+    cfg.data.seq_len = 16_384
+    cfg.data.vocab_size = 18_992
+    cfg.model.name = "smallthinker"
+    cfg.train.global_batch_size = 1
+    # the expert half of every layer computed again backward but for its
+    # products' outputs: the step does not fit the chip's 16 GB at 16,384
+    # positions without it (PERF.md section 4)
+    cfg.model.remat = True
+    return cfg
+
+
 # The supported config space (these presets × mesh/dtype/fused/remat/
 # engine variations) is certified statically: tpu_resnet/analysis/
 # configmatrix.py traces the compiled train/eval program of every
@@ -1059,6 +1105,7 @@ PRESETS = {
     "sdar_30b_a3b_chat": _sdar_30b_a3b_chat,
     "lfm2_24b_a2b_ep8": _lfm2_24b_a2b_ep8,
     "qwen3_next_80b_a3b_ep16": _qwen3_next_80b_a3b_ep16,
+    "smallthinker_21b_a3b_ep8": _smallthinker_21b_a3b_ep8,
 }
 
 

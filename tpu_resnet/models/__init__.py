@@ -22,12 +22,13 @@ from typing import Callable, Dict, Optional, Tuple
 import jax.numpy as jnp
 
 from tpu_resnet.models import (afmoe, lfm2_moe, mlp, qwen3_next, resnet,
-                               sdar_moe, transformer)
+                               sdar_moe, smallthinker, transformer)
 from tpu_resnet.models.afmoe import Afmoe
 from tpu_resnet.models.lfm2_moe import Lfm2Moe
 from tpu_resnet.models.mlp import MLP
 from tpu_resnet.models.qwen3_next import Qwen3Next
 from tpu_resnet.models.sdar_moe import SdarMoe
+from tpu_resnet.models.smallthinker import SmallThinker
 from tpu_resnet.models.resnet import (
     ResNetV2,
     cifar_resnet_v2,
@@ -41,6 +42,7 @@ __all__ = [
     "Qwen3Next",
     "ResNetV2",
     "SdarMoe",
+    "SmallThinker",
     "cifar_resnet_v2",
     "imagenet_resnet_v2",
     "Family",
@@ -189,3 +191,8 @@ register(Family("qwen3_next", "tokens", Qwen3Next, qwen3_next.build,
                 train_flops_per_example=qwen3_next.train_flops_per_example,
                 counters=qwen3_next.COUNTERS, refuses=qwen3_next.refuses,
                 startup_events=qwen3_next.startup_events))
+register(Family("smallthinker", "tokens", SmallThinker, smallthinker.build,
+                smallthinker.spell,
+                train_flops_per_example=smallthinker.train_flops_per_example,
+                counters=smallthinker.COUNTERS, refuses=transformer.refuses,
+                startup_events=smallthinker.startup_events))

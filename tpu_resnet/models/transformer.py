@@ -1,15 +1,16 @@
 """What the token families share (``afmoe``, ``sdar_moe``, ``lfm2_moe``,
-``qwen3_next``): the numerics of a matrix product, RMSNorm, the SwiGLU MLP,
-the rotary embedding by position ids (over a whole head or its first
-columns), attention by whichever path the backend and the shapes give (with
-an output gate taken from the query's product where a family asks), the
-causal short convolution within documents, the sigmoid router with its bias
-and the softmax router, and the dispatch of an expert layer's assignments
-to the experts this chip holds. A family keeps what is its own: its token
-mixers, its norms' places, its masks, its objective.
+``qwen3_next``, ``smallthinker``): the numerics of a matrix product,
+RMSNorm, the SwiGLU MLP, the rotary embedding by position ids (over a whole
+head or its first columns), attention by whichever path the backend and the
+shapes give (with an output gate taken from the query's product where a
+family asks), the causal short convolution within documents, the sigmoid
+router with its bias and the softmax router, and the dispatch of an expert
+layer's assignments to the experts this chip holds. A family keeps what is
+its own: its token mixers, its norms' places, its masks, its objective.
 
-**The softmax router** (``softmax_router``; ``sdar_moe`` and
-``qwen3_next``): ``s = softmax(x Wr)`` over all experts, top-k, the chosen
+**The softmax router** (``softmax_router``; ``sdar_moe``, ``qwen3_next``
+and ``smallthinker``, which runs it on the layer's input before
+attention): ``s = softmax(x Wr)`` over all experts, top-k, the chosen
 renormalised to 1. **The sigmoid router** (``sigmoid_router``,
 ``balanced_bias``; ``afmoe`` and ``lfm2_moe`` call it, each with its two
 numbers): ``s = sigmoid(x
@@ -20,53 +21,54 @@ training, after the step's routing, ``b += c - mean(c)`` with ``c = coeff
 * sign(mean(n) - n)``, ``n`` the assignments per expert (the
 auxiliary-loss-free balancing of Wang et al. 2024, arXiv:2408.15664).
 
-**The dispatch** (``dispatch_experts``). A family's router hands over
-``(x, chosen, weight)``: for each of ``N`` tokens the ``top_k`` experts
-chosen of ``experts_total`` and their weights. No token is dropped, and
-the layer does the work its routing fills: one stable sort of the ``N *
-top_k`` assignments by their expert here puts those that fall on a held
-expert (``experts_held = (first, count)``) first, grouped by expert and in
-token order within an expert. The first ``rows`` of them (``rows_slack`` x
-what an even routing sends to all held experts together, in whole tiles)
-are one buffer: a gather of the tokens by the sorted order, three grouped
-products whose work follows the groups' sizes (``ops/grouped.py``, which
-also says how its path is chosen and what its kernel never writes: the
-buffer's rows past the last assignment come back 0, in the result and in
-every gradient), the weighting, and the sum of each token's rows back into
-its token. The gather and that sum are one transposed pair
-(``ops/rows_to_tokens.py``, which also says how their path is chosen): on
-one TPU chip the sum, forward in the combine and backward in the
-dispatch, is a kernel that reads only the rows an assignment filled, in
-the runs an expert's rows make within a tile of tokens; elsewhere it is
-the scatter-add. ``expert_paths`` names both paths, and each family says
-them once, as the event ``expert_path``.
-Whatever sorted positions lie beyond the buffer go through the same code a
-tier of ``rows`` at a time, only the tiers that hold an assignment, under
-a ``lax.cond`` that is false while the held experts together take no more
-than the buffer. What the absent experts would add is left out and that
-partial result goes on. The counters say what happened
-(``moe_dropped_frac`` reads 0 by that construction and is counted from the
-groups' sizes all the same; ``moe_overflow_frac`` says whether the tiers
-beyond ran, ``moe_rows_filled_frac`` how much of the buffer carried an
-assignment).
+**The dispatch** (``dispatch_experts``). A family's router hands over ``(x,
+chosen, weight)``: for each of ``N`` tokens the ``top_k`` experts chosen of
+``experts_total`` and their weights, routed on ``x`` or on another tensor
+of the layer; an expert is ``(activation(x W_gate) * (x W_up)) W_down``,
+SwiGLU by default and ReGLU where a family passes ReLU. No token is
+dropped, and the layer does the work its routing fills: one stable sort of
+the ``N * top_k`` assignments by their expert here puts those that fall on
+a held expert (``experts_held = (first, count)``) first, grouped by expert
+and in token order within an expert. The first ``rows`` of them
+(``rows_slack`` x what an even routing sends to all held experts together,
+in whole tiles) are one buffer: a gather of the tokens by the sorted order,
+three grouped products whose work follows the groups' sizes
+(``ops/grouped.py``, which also says how its path is chosen and what its
+kernel never writes: the buffer's rows past the last assignment come back
+0, in the result and in every gradient), the weighting, and the sum of each
+token's rows back into its token. The gather and that sum are one
+transposed pair (``ops/rows_to_tokens.py``, which also says how their path
+is chosen): on one TPU chip the sum, forward in the combine and backward in
+the dispatch, is a kernel that reads only the rows an assignment filled, in
+the runs an expert's rows make within a tile of tokens; elsewhere it is the
+scatter-add. ``expert_paths`` names both paths, and each family says them
+once, as the event ``expert_path``. Whatever sorted positions lie beyond
+the buffer go through the same code a tier of ``rows`` at a time, only the
+tiers that hold an assignment, under a ``lax.cond`` that is false while the
+held experts together take no more than the buffer. What the absent experts
+would add is left out and that partial result goes on. The counters say
+what happened (``moe_dropped_frac`` reads 0 by that construction and is
+counted from the groups' sizes all the same; ``moe_overflow_frac`` says
+whether the tiers beyond ran, ``moe_rows_filled_frac`` how much of the
+buffer carried an assignment).
 
-**Attention** (``self_attention``, which all four families call: the
-q/k/v projections, the q/k norms, rotary where a family asks for it, and
-attention) never builds a ``(B, H, S, S)`` score tensor. On one TPU chip,
-at heads of a multiple of 128, or of 64, and sequences its blocks divide,
-it is one fused kernel that keeps each tile of scores on the chip
+**Attention** (``self_attention``, which all five families call: the q/k/v
+projections, the q/k norms where a family has them, rotary where it asks
+for it, and attention) never builds a ``(B, H, S, S)`` score tensor. On one
+TPU chip, at heads of a multiple of 128, or of 64, and sequences its blocks
+divide, it is one fused kernel that keeps each tile of scores on the chip
 (``ops/attention.py``, which also says how the path is chosen and what a
 mask is), fed by one pass that norms, rotates, scales, casts and lays out
 its inputs, with a backward pass of its own (``ops/attention_inputs.py``);
 everywhere else the norms and rotary as composed here and a block of
-queries at a time against the keys its mask can reach, as a scan whose
-body is under ``jax.checkpoint`` (``blocked_attention``).
+queries at a time against the keys its mask can reach, as a scan whose body
+is under ``jax.checkpoint`` (``blocked_attention``).
 """
 
 from __future__ import annotations
 
 import math
-from typing import Any, Dict, Tuple
+from typing import Any, Callable, Dict, Tuple
 
 import flax.linen as nn
 import jax
@@ -279,7 +281,8 @@ INPUTS = {"kernel": "fused", "scan": "composed"}
 def self_attention(module: nn.Module, x, doc, mask, *, heads: int,
                    kv_heads: int, head_dim: int, eps: float, rotary_of,
                    block: int, dtype, rotary_dim: int = 0,
-                   zero_centred: bool = False, gated: bool = False):
+                   zero_centred: bool = False, gated: bool = False,
+                   qk_norm: bool = True):
     """Attention of ``x`` ``(B, S, d)`` over itself within documents
     ``doc`` under ``mask`` (``blocked_attention``) by whichever path
     ``attention_path`` gives here, before the output's product: ``q, k, v
@@ -291,7 +294,8 @@ def self_attention(module: nn.Module, x, doc, mask, *, heads: int,
     ``rotary_dim`` columns of a head (0: all). Returns ``(B, S, H * D)``
     in ``dtype``, named ``attention`` for a ``remat`` policy.
 
-    ``zero_centred`` norms scale by ``1 + w`` (``NormScale``). ``gated``:
+    ``zero_centred`` norms scale by ``1 + w`` (``NormScale``); without
+    ``qk_norm`` there are no q/k norms and no weights for them. ``gated``:
     ``Wq`` gives each head its query and then a gate of as many columns,
     ``(d, H * 2D)``, and the result is ``attention * sigmoid(gate)``
     (scope ``gate_out``).
@@ -318,14 +322,18 @@ def self_attention(module: nn.Module, x, doc, mask, *, heads: int,
                      dtype).reshape(b, s, kv, hd)
             v = _dot(x, module.param("wv", _init, (d, kv * hd), _f32),
                      dtype).reshape(b, s, kv, hd)
-        q_scale = NormScale(zero_centred, name="q_norm")(hd)
-        k_scale = NormScale(zero_centred, name="k_norm")(hd)
+        q_scale = k_scale = None
+        if qk_norm:
+            q_scale = NormScale(zero_centred, name="q_norm")(hd)
+            k_scale = NormScale(zero_centred, name="k_norm")(hd)
         with jax.named_scope("prepare"):
             if kernel:
                 q, k, v = attention_inputs(q, k, v, q_scale, k_scale,
                                            rotary_of, dtype, eps, rotary_dim)
             else:
-                q, k = rms_norm(q, q_scale, eps), rms_norm(k, k_scale, eps)
+                if qk_norm:
+                    q, k = (rms_norm(q, q_scale, eps),
+                            rms_norm(k, k_scale, eps))
                 if rotary_of is not None:
                     q, k = (rotary(x, *rotary_of, dims=rotary_dim)
                             for x in (q, k))
@@ -400,13 +408,16 @@ def expert_paths(backend: str, devices: int) -> Dict[str, str]:
 
 def dispatch_experts(x, chosen, weight, w_gate, w_up, w_down, *,
                      experts_total: int, experts_held: Tuple[int, int],
-                     rows_slack: float, dtype
+                     rows_slack: float, dtype,
+                     activation: Callable = jax.nn.silu
                      ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """The partial result of the experts held here and what the routing
     did (``COUNTERS``), see the module docstring. ``x`` is ``(N, d)``
-    float32, ``chosen`` and ``weight`` ``(N, top_k)``; the three weights
-    are ``(count, d, width)``, ``(count, d, width)`` and ``(count, width,
-    d)`` float32."""
+    float32, ``chosen`` and ``weight`` ``(N, top_k)`` from a router the
+    caller ran, on ``x`` or on another tensor; the three weights are
+    ``(count, d, width)``, ``(count, d, width)`` and ``(count, width, d)``
+    float32. An expert is ``(activation(x W_gate) * (x W_up)) W_down``:
+    SwiGLU by default, ReGLU with ``jax.nn.relu``."""
     n, d = x.shape
     k = chosen.shape[1]
     first, count = experts_held
@@ -450,7 +461,7 @@ def dispatch_experts(x, chosen, weight, w_gate, w_up, w_down, *,
 
             hidden = (checkpoint_name(mm(xs, w_gate), "experts"),
                       checkpoint_name(mm(xs, w_up), "experts"))
-            y = mm(jax.nn.silu(hidden[0]) * hidden[1], w_down, _f32)
+            y = mm(activation(hidden[0]) * hidden[1], w_down, _f32)
         with jax.named_scope("combine"):
             y = y * jnp.take(weight.reshape(-1), at)[:, None]
             return rows_to_tokens.combine(y, plan), jnp.sum(sizes)

@@ -6,7 +6,7 @@ The composed chain it stands for (``models/transformer.py``: ``rms_norm``,
 then ``rotary``, then ``ops/attention.py::_heads_first``) is, a row of
 ``D`` columns of each head, in float32 from the bf16 projection:
 
-    y = x * rsqrt(mean(x * x) + eps) * scale      q/k norm
+    y = x * rsqrt(mean(x * x) + eps) * scale      q/k norm, where given
     y = y * cos + half_turn(y) * sin_signed       rotary, where given
     y = y * (1 / sqrt(D))                         q only
     out = cast(y, dtype), heads first             the kernel's layout
@@ -19,7 +19,10 @@ layer, outside both passes. A partial rotary (``rotary_dim`` under ``D``)
 rotates the row's first ``rotary_dim`` columns and passes the rest: the
 tables hold 1 and 0 past them, and the turn (``part_turn``) swaps the two
 halves of those columns and gives 0 past them, a permutation that is its
-own transpose.
+own transpose. A family that declares no q/k norm (``scale`` None) gets
+the chain without it: the row is the projection in float32, and nothing
+is normed, not even by a weight of 1, whose ``rsqrt`` would change the
+numbers.
 
 Forward, each projection is read once and written once, ``q`` as ``(B,
 KV, G, S, D)`` and ``k`` as ``(B, KV, S, D)``: the two rounding points are
@@ -124,9 +127,13 @@ def _norm(x, eps: float):
 
 
 def _prepare(x, scale, cos, sin, turn, eps: float, factor: float):
-    """The forward chain of a row block in float32 (the docstring)."""
-    x, r = _norm(x, eps)
-    y = x * r * scale
+    """The forward chain of a row block in float32 (the docstring); no
+    norm where ``scale`` is None."""
+    if scale is None:
+        y = x.astype(_f32)
+    else:
+        x, r = _norm(x, eps)
+        y = x * r * scale
     if cos is not None:
         y = y * cos + turn(y) * sin
     return y * factor if factor != 1.0 else y
@@ -134,13 +141,15 @@ def _prepare(x, scale, cos, sin, turn, eps: float, factor: float):
 
 def _transposed(x, g, scale, cos, sin, turn, eps: float, factor: float):
     """The projection's cotangent (float32) and the scale's gradient
-    summed over the block's rows, from the prepared rows' cotangent
-    ``g``."""
+    summed over the block's rows (None where there is no norm), from the
+    prepared rows' cotangent ``g``."""
     g = g.astype(_f32)
     if factor != 1.0:
         g = g * factor
     if cos is not None:
         g = g * cos + turn(g * sin)          # rotary transposed
+    if scale is None:
+        return g, None
     x, r = _norm(x, eps)
     n = x * r
     dn = g * scale
@@ -163,22 +172,27 @@ def _turn(rot: int, whole, roll):
     return functools.partial(part_turn, roll=roll, rot=rot) if rot else whole
 
 
-def _fwd_kernel(*refs, eps, factor, rotary, rot):
-    x_ref, scale_ref, *table, out_ref = refs
-    cos, sin = (t[0] for t in table) if rotary else (None, None)
-    out_ref[0, 0] = _prepare(x_ref[0], scale_ref[...], cos, sin,
-                             _turn(rot, _roll_half, _lane_roll), eps,
-                             factor).astype(out_ref.dtype)
+def _fwd_kernel(*refs, eps, factor, rotary, rot, norm):
+    x_ref, *rest, out_ref = refs
+    scale_ref = rest.pop(0) if norm else None
+    cos, sin = (t[0] for t in rest) if rotary else (None, None)
+    out_ref[0, 0] = _prepare(x_ref[0], scale_ref[...] if norm else None,
+                             cos, sin, _turn(rot, _roll_half, _lane_roll),
+                             eps, factor).astype(out_ref.dtype)
 
 
-def _bwd_kernel(*refs, eps, factor, rotary, rot):
-    x_ref, g_ref, scale_ref, *table, dx_ref, d_scale_ref = refs
+def _bwd_kernel(*refs, eps, factor, rotary, rot, norm):
+    x_ref, g_ref, *rest = refs
+    scale_ref = rest.pop(0) if norm else None
+    *table, dx_ref, d_scale_ref = rest if norm else rest + [None]
     cos, sin = (t[0] for t in table) if rotary else (None, None)
-    dx, d_scale = _transposed(x_ref[0], g_ref[0, 0], scale_ref[...], cos,
-                              sin, _turn(rot, _roll_half, _lane_roll), eps,
+    dx, d_scale = _transposed(x_ref[0], g_ref[0, 0],
+                              scale_ref[...] if norm else None, cos, sin,
+                              _turn(rot, _roll_half, _lane_roll), eps,
                               factor)
     dx_ref[0] = dx.astype(dx_ref.dtype)
-    d_scale_ref[...] = d_scale.reshape(d_scale_ref.shape)
+    if norm:
+        d_scale_ref[...] = d_scale.reshape(d_scale_ref.shape)
 
 
 def _specs(x, table):
@@ -202,34 +216,42 @@ def _specs(x, table):
 def _kernel_forward(x, scale, table, *, eps, factor, rot, dtype, interpret):
     b, s, h, d = x.shape
     grid, rows, heads, scale_spec, tables = _specs(x, table)
+    norm = scale is not None
     return pl.pallas_call(
         functools.partial(_fwd_kernel, eps=eps, factor=factor,
-                          rotary=table is not None, rot=rot),
-        grid=grid, in_specs=[rows, scale_spec, *tables], out_specs=heads,
+                          rotary=table is not None, rot=rot, norm=norm),
+        grid=grid, in_specs=[rows, *[scale_spec] * norm, *tables],
+        out_specs=heads,
         out_shape=jax.ShapeDtypeStruct((b, h, s, d), dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",) * 3),
         name="attention_inputs_fwd", interpret=interpret,
-    )(x.reshape(b, s, h * d), scale.reshape(1, d),
+    )(x.reshape(b, s, h * d), *([scale.reshape(1, d)] if norm else []),
       *(table if table is not None else ()))
 
 
 def _kernel_backward(x, scale, table, g, *, eps, factor, rot, interpret):
     b, s, h, d = x.shape
     grid, rows, heads, scale_spec, tables = _specs(x, table)
-    dx, d_scale = pl.pallas_call(
+    norm = scale is not None
+    out = pl.pallas_call(
         functools.partial(_bwd_kernel, eps=eps, factor=factor,
-                          rotary=table is not None, rot=rot),
-        grid=grid, in_specs=[rows, heads, scale_spec, *tables],
+                          rotary=table is not None, rot=rot, norm=norm),
+        grid=grid, in_specs=[rows, heads, *[scale_spec] * norm, *tables],
         out_specs=[rows, pl.BlockSpec((1, 1, 1, 1, d),
-                                      lambda i, j, k: (i, j, k, 0, 0))],
+                                      lambda i, j, k: (i, j, k, 0, 0))
+                   ][:1 + norm],
         out_shape=[jax.ShapeDtypeStruct((b, s, h * d), x.dtype),
-                   jax.ShapeDtypeStruct((*grid, 1, d), _f32)],
+                   jax.ShapeDtypeStruct((*grid, 1, d), _f32)][:1 + norm],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",) * 3),
         name="attention_inputs_bwd", interpret=interpret,
-    )(x.reshape(b, s, h * d), g.reshape(b, h, s, d), scale.reshape(1, d),
+    )(x.reshape(b, s, h * d), g.reshape(b, h, s, d),
+      *([scale.reshape(1, d)] if norm else []),
       *(table if table is not None else ()))
+    if not norm:
+        return out[0].reshape(x.shape), None
+    dx, d_scale = out
     return dx.reshape(x.shape), jnp.sum(d_scale, axis=(0, 1, 2, 3))
 
 
@@ -305,7 +327,8 @@ def attention_inputs(q, k, v, q_scale, k_scale, rotary, dtype, eps: float,
     scaled by ``1 / sqrt(D)`` and cast to ``dtype`` as ``(B, KV, H / KV,
     S, D)``; ``k`` normed, rotated and cast as ``(B, KV, S, D)``; ``v``
     cast as ``(B, KV, S, D)`` (the module docstring). ``q_scale`` and
-    ``k_scale`` are the norms' ``(D,)`` float32 weights, ``rotary`` None or
+    ``k_scale`` are the norms' ``(D,)`` float32 weights (both None: no
+    norm), ``rotary`` None or
     ``(theta, positions)`` with ``positions`` ``(P, S)`` or None, over the
     first ``rotary_dim`` columns of a head (0: all)."""
     b, s, h, d = q.shape
